@@ -191,85 +191,6 @@ def worker_rank(default=0):
     return default
 
 
-def ensure_jax_compat():
-    """Forward-compat shims for older jax releases (same role as the
-    jax.distributed.is_initialized probe below): this codebase writes
-    the modern ``jax.shard_map(f, mesh=..., in_specs=..., out_specs=...,
-    check_vma=..., axis_names=...)`` spelling, which older jax only
-    offers as ``jax.experimental.shard_map.shard_map(f, mesh, in_specs,
-    out_specs, check_rep=..., auto=...)``, and the modern
-    ``jax.lax.axis_size(name)``, which older jax only exposes through
-    the tracing-internal axis env. Install adapters so the
-    collectives/pipeline/ring-attention layers run on either."""
-    import jax
-    _ensure_shard_map(jax)
-    _ensure_axis_size(jax)
-
-
-def _ensure_shard_map(jax):
-    if hasattr(jax, "shard_map"):
-        return
-    try:
-        from jax.experimental.shard_map import shard_map as _esm
-    except Exception:
-        return
-
-    def shard_map(f, *, mesh=None, in_specs=None, out_specs=None,
-                  check_vma=None, check_rep=None, axis_names=None):
-        kwargs = {}
-        rep = check_rep if check_rep is not None else check_vma
-        if rep is not None:
-            kwargs["check_rep"] = rep
-        if axis_names is not None:
-            if mesh is None:
-                raise NotImplementedError(
-                    "axis_names without an explicit mesh (nested "
-                    "partial-manual shard_map) needs jax.shard_map; "
-                    "this jax release only has the experimental API")
-            # modern axis_names = MANUAL axes; legacy auto = the rest
-            kwargs["auto"] = frozenset(mesh.axis_names) - \
-                frozenset(axis_names)
-        return _esm(f, mesh, in_specs, out_specs, **kwargs)
-
-    jax.shard_map = shard_map
-
-
-def _ensure_axis_size(jax):
-    """``jax.lax.axis_size`` adapter. The callers here (ring attention's
-    ppermute ring, the pipe stage collectives, the moe_mesh example)
-    need a CONCRETE Python int — it bounds ``range()`` loops and builds
-    ppermute permutations — so ``psum(jnp.ones(()), name)`` (a traced
-    value) is not a substitute. Old jax keeps the bound size in the
-    trace-time axis env: ``jax._src.core.axis_frame(name)`` returns the
-    size directly (an int on 0.4.x; a frame object carrying ``.size``
-    on some releases). Outside any binding of the name this raises
-    NameError, matching modern jax's behaviour."""
-    if hasattr(jax.lax, "axis_size"):
-        return
-
-    def axis_size(axis_name):
-        from jax._src import core as _core
-        frame = _core.axis_frame(axis_name)
-        if isinstance(frame, int):
-            return frame
-        return int(getattr(frame, "size"))
-
-    jax.lax.axis_size = axis_size
-
-
-def _distributed_is_initialized(jax_mod) -> bool:
-    """`jax.distributed.is_initialized` only exists on newer jax; older
-    releases expose the same fact via the global distributed state."""
-    probe = getattr(jax_mod.distributed, "is_initialized", None)
-    if probe is not None:
-        return bool(probe())
-    try:
-        from jax._src import distributed as _dist
-        return getattr(_dist.global_state, "client", None) is not None
-    except Exception:
-        return False
-
-
 def initialize_distributed(coordinator_address=None, num_processes=None,
                            process_id=None, **kwargs):
     """Wire this process into a multi-worker jax.distributed job.
@@ -284,7 +205,7 @@ def initialize_distributed(coordinator_address=None, num_processes=None,
     Idempotent; no-op when no coordinator is known."""
     import os
     import jax
-    if _distributed_is_initialized(jax):
+    if jax.distributed.is_initialized():
         return
     if coordinator_address is None:
         coordinator_address = os.environ.get("MX_COORDINATOR")

@@ -254,7 +254,9 @@ register_flag(
     "programs — including the fused train step — are written to disk "
     "so warmup survives process restarts. Hits/misses are logged to "
     "the telemetry registry (jax_compile_cache_{hits,misses}_total). "
-    "Empty = cache off.")
+    "Yields to JAX_COMPILATION_CACHE_DIR: when that is set the cache "
+    "stays where jax put it and this flag is ignored. Empty = cache "
+    "off.")
 register_flag(
     "MXNET_EAGER_SYNC", bool, False,
     "Block on device completion after EVERY eager op dispatch "

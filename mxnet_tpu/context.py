@@ -13,6 +13,8 @@ from typing import Optional
 
 import jax
 
+from .base import MXNetError
+
 __all__ = ["Context", "cpu", "gpu", "tpu", "current_context", "num_gpus", "num_tpus"]
 
 
@@ -54,8 +56,10 @@ class Context:
     def jax_device(self) -> Optional[jax.Device]:
         """Resolve to a concrete jax.Device.
 
-        'gpu' and 'tpu' both resolve to the accelerator platform when
-        present (lets reference scripts using mx.gpu() run on TPU); 'cpu'
+        'tpu' resolves to local TPU chip ``device_id`` and 'gpu' is its
+        documented alias (lets reference scripts using mx.gpu() run on
+        TPU); both raise when this process has no TPU or the id is out
+        of range, as the reference's mx.gpu() does without a GPU. 'cpu'
         resolves to a host device.
         """
         # LOCAL devices only: under jax.distributed, jax.devices() is the
@@ -69,12 +73,13 @@ class Context:
                 if not devs:
                     return None
             return devs[min(self.device_id, len(devs) - 1)]
-        # accelerator
-        devs = [d for d in jax.local_devices() if d.platform != "cpu"]
-        if not devs:
-            # fall back to default platform (tests run pure-CPU)
-            devs = jax.local_devices()
-        return devs[min(self.device_id, len(devs) - 1)]
+        devs = [d for d in jax.local_devices() if d.platform == "tpu"]
+        if not 0 <= self.device_id < len(devs):
+            raise MXNetError(
+                f"{self!r}: this process has {len(devs)} local TPU "
+                f"device(s) (jax backend {jax.default_backend()!r}); "
+                "there is no fallback to another device")
+        return devs[self.device_id]
 
     def __enter__(self):
         if not hasattr(Context._default_ctx, "stack"):

@@ -302,8 +302,13 @@ class Trainer:
         """Compile this trainer's whole step into one donated XLA
         computation (mxnet_tpu.step.StepFunction): ``fused.step(x, y)``
         replaces the record/backward/step(batch) triple with a single
-        dispatch, bitwise-equal to the eager loop for optimizers with a
-        functional fused_apply. The trainer keeps owning optimizer
+        dispatch, for optimizers with a functional fused_apply. It
+        agrees with the eager loop bitwise wherever XLA compiles an op
+        the same way inside one program and alone (test-enforced on
+        XLA:CPU with its dot fusions off, tests/conftest.py), and to
+        rounding otherwise — on the TPU the whole-program fusions
+        round differently from op-by-op dispatch (chip_smoke.py states
+        and checks the tolerance). The trainer keeps owning optimizer
         state (save_states/load_states and mxresil checkpoints see the
         post-update values).
 

@@ -208,7 +208,11 @@ def tensor_parallel_shardings(block, model_axis: str = "model"):
     ParallelTrainer(param_shardings=...) — GSPMD inserts the all-reduces
     the reference would have hand-coded."""
     specs = {}
-    for name, p in block._collect_params_with_prefix().items():
+    for key, p in block._collect_params_with_prefix().items():
+        # match on the Parameter's own name: it carries the Dense
+        # prefixes given above ("..._qkv_weight"), where the key is the
+        # attribute path ("layers.0.attn.qkv.weight")
+        name = p.name
         if p.shape is None:
             spec = P()
         elif "qkv_weight" in name or "ffn1_weight" in name:
@@ -217,7 +221,7 @@ def tensor_parallel_shardings(block, model_axis: str = "model"):
             spec = P(model_axis)
         elif "proj_weight" in name or "ffn2_weight" in name:
             spec = P(None, model_axis)
-        elif "head_weight" in name or name.endswith("embed_weight") or \
+        elif "head_weight" in name or \
                 "embedding" in name and name.endswith("weight"):
             spec = P(model_axis, None) if len(p.shape) == 2 else P()
         else:
@@ -226,5 +230,5 @@ def tensor_parallel_shardings(block, model_axis: str = "model"):
             # clobber other sharding helpers' specs — e.g.
             # expert_parallel_shardings — depending on merge order
             continue
-        specs[name] = spec
+        specs[key] = spec
     return specs

@@ -27,16 +27,43 @@ _lib = None
 _build_error = None
 
 
+def _build_if_stale(so: str, srcs, cmd_for, force: bool) -> str:
+    """Build ``so`` unless it was built from exactly these sources by
+    exactly this command. The key is a hash of the source bytes and the
+    command, kept beside the library: ``*.so`` is git-ignored, so a
+    binary left in a checkout from older sources (file times say
+    nothing after a copy) must never be loaded."""
+    import hashlib
+    tmp = f"{so}.tmp{os.getpid()}"
+    h = hashlib.sha256("\0".join(cmd_for("@OUT@")).encode())
+    for src in srcs:
+        with open(src, "rb") as f:
+            h.update(f.read())
+    key, key_path = h.hexdigest(), so + ".srchash"
+    if not force and os.path.exists(so):
+        try:
+            with open(key_path) as f:
+                if f.read().strip() == key:
+                    return so
+        except OSError:
+            pass
+    try:
+        subprocess.run(cmd_for(tmp), check=True, capture_output=True)
+        os.replace(tmp, so)
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
+    with open(key_path, "w") as f:
+        f.write(key + "\n")
+    return so
+
+
 def build(force: bool = False) -> str:
-    """Compile the native library (cached)."""
-    if not force and os.path.exists(_SO) and \
-            all(os.path.getmtime(_SO) >= os.path.getmtime(s)
-                for s in _SRCS):
-        return _SO
-    cmd = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-pthread",
-           *_SRCS, "-o", _SO, "-ljpeg"]
-    subprocess.run(cmd, check=True, capture_output=True)
-    return _SO
+    """Compile the native library (cached by source hash)."""
+    return _build_if_stale(
+        _SO, _SRCS,
+        lambda out: ["g++", "-O3", "-std=c++17", "-shared", "-fPIC",
+                     "-pthread", *_SRCS, "-o", out, "-ljpeg"], force)
 
 
 def lib():
@@ -116,6 +143,16 @@ def lib():
 
 def available() -> bool:
     return lib() is not None
+
+
+def status() -> dict:
+    """Whether the native library built and loaded in this process, and
+    the build/load error when it did not (callers then run the
+    pure-Python implementations)."""
+    loaded = available()
+    return {"loaded": loaded, "path": _SO,
+            "error": None if loaded else
+            f"{type(_build_error).__name__}: {_build_error}"}
 
 
 class NativeRecordIO:
@@ -247,21 +284,17 @@ _CAPI_HDR = os.path.join(_HERE, "mxtpu_predict.h")
 
 
 def build_capi(force: bool = False) -> str:
-    """Compile libmxtpu_capi.so (cached by source+header mtime)."""
-    src_mtime = max(os.path.getmtime(_CAPI_SRC),
-                    os.path.getmtime(_CAPI_HDR))
-    if not force and os.path.exists(_CAPI_SO) and \
-            os.path.getmtime(_CAPI_SO) >= src_mtime:
-        return _CAPI_SO
+    """Compile libmxtpu_capi.so (cached by source+header hash)."""
     import sysconfig
     inc = sysconfig.get_path("include")
     libdir = sysconfig.get_config_var("LIBDIR")
     ldver = sysconfig.get_config_var("LDVERSION")
-    cmd = ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", _CAPI_SRC,
-           f"-I{inc}", f"-L{libdir}", f"-lpython{ldver}",
-           f"-Wl,-rpath,{libdir}", "-o", _CAPI_SO]
-    subprocess.run(cmd, check=True, capture_output=True)
-    return _CAPI_SO
+    return _build_if_stale(
+        _CAPI_SO, [_CAPI_SRC, _CAPI_HDR],
+        lambda out: ["g++", "-O2", "-std=c++17", "-shared", "-fPIC",
+                     _CAPI_SRC, f"-I{inc}", f"-L{libdir}",
+                     f"-lpython{ldver}", f"-Wl,-rpath,{libdir}",
+                     "-o", out], force)
 
 
 class NativeImagePipeline:

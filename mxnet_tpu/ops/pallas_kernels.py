@@ -19,36 +19,52 @@ tests validate the kernels in interpret mode.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
+import threading
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # pallas TPU backend is importable even on CPU builds
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
-
-__all__ = ["flash_attention", "flash_attention_available"]
+__all__ = ["flash_attention", "flash_attention_available",
+           "gspmd_partitioned"]
 
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
 NEG_INF = -1e30
 
+_scope = threading.local()
+
+
+@contextlib.contextmanager
+def gspmd_partitioned():
+    """Scope for tracing a program that GSPMD will partition over more
+    than one device (shard/stepfn.py). Mosaic kernels cannot be
+    partitioned automatically — lowering refuses them outside a
+    shard_map — so inside this scope :func:`flash_attention_available`
+    is False and callers trace the XLA composition, which GSPMD can
+    shard."""
+    was = getattr(_scope, "partitioned", False)
+    _scope.partitioned = True
+    try:
+        yield
+    finally:
+        _scope.partitioned = was
+
 
 def flash_attention_available(q_len: int, k_len: int, head_dim: int) -> bool:
     """True when the tiled kernel path handles these shapes.
 
-    Since round 4 the kernels pad/mask internally (sequence lengths to
-    the block size, head_dim 96 -> 128, etc. — VERDICT r3 item 2: BERT
-    shapes must not silently fall back), so the only hard requirements
-    are the TPU pallas backend and a head_dim the MXU can tile after
-    padding. Very short sequences still fall back: padding 16 tokens to
-    a 128 block would waste >8x the FLOPs of the dense composition."""
-    if not _HAS_PLTPU:
+    The kernels pad/mask internally (sequence lengths to the block
+    size, head_dim 96 -> 128, etc.: BERT shapes must not silently fall
+    back), so the only hard requirement is a head_dim the MXU can tile
+    after padding. Very short sequences still fall back: padding 16
+    tokens to a 128 block would waste >8x the FLOPs of the dense
+    composition. False while tracing for GSPMD partitioning
+    (:func:`gspmd_partitioned`)."""
+    if getattr(_scope, "partitioned", False):
         return False
     return ((head_dim <= 256 or head_dim % 128 == 0)
             and min(q_len, k_len) >= DEFAULT_BLOCK_Q // 2)
@@ -131,7 +147,7 @@ def _flash_fwd(q, k, v, causal, s, bq, bk, interpret, kv_len=None):
     kernel = functools.partial(_fwd_kernel, causal=causal, scale=s,
                                bq=bq, bk=bk, nk=nk, kv_len=kv_len)
     compiler_params = None
-    if _HAS_PLTPU and not interpret:
+    if not interpret:
         compiler_params = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"))
     return pl.pallas_call(
@@ -251,7 +267,7 @@ def _flash_bwd(q, k, v, out, lse, g, causal, s, bq, bk, interpret,
                     axis=-1, keepdims=True)               # (BH, Tq, 1)
     row_spec_q = pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0))
     compiler_params = None
-    if _HAS_PLTPU and not interpret:
+    if not interpret:
         compiler_params = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"))
     dq = pl.pallas_call(
@@ -329,13 +345,9 @@ def _plan_blocks(q, k, block_q, block_k):
     sequence, no padding). Everything else pads: sequences up to block
     multiples (the tail K blocks masked via kv_len), head_dim 96 -> 128
     etc. (zero-padding the contraction is numerically exact; the padded
-    output/grad columns are sliced off). VERDICT r3 item 2: BERT-shaped
-    configs (T=384, D=96 per head after 12x64 splits, ...) must run the
-    kernel, not silently fall back."""
-    if not _HAS_PLTPU:
-        # no pltpu -> kernels can't build their VMEM scratch even in
-        # interpret mode
-        return None
+    output/grad columns are sliced off). BERT-shaped configs (T=384,
+    D=96 per head after 12x64 splits, ...) must run the kernel, not
+    silently fall back."""
     Tq, Tk, D = q.shape[2], k.shape[2], q.shape[3]
     bq, bk = min(block_q, Tq), min(block_k, Tk)
     if Tq % bq == 0 and Tk % bk == 0 and (D % 128 == 0

@@ -31,13 +31,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # importable on CPU builds; actual TPU lowering needs a TPU
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
-
 __all__ = ["mp_sgd_mom_update_pallas", "pallas_kernels_active",
            "fused_attention_available"]
 
@@ -49,7 +42,7 @@ def pallas_kernels_active() -> bool:
     """True when Pallas lowering is allowed AND a TPU backend is
     present (the Mosaic compile path; interpret mode bypasses this)."""
     from ..base import get_env
-    if not _HAS_PLTPU or not get_env("MXNET_GRAPH_OPT_PALLAS", True):
+    if not get_env("MXNET_GRAPH_OPT_PALLAS", True):
         return False
     return any(d.platform == "tpu" for d in jax.devices())
 

@@ -75,7 +75,11 @@ def init_pipeline_lm(seed: int, *, vocab: int, d_model: int,
         "layers": {
             "ln1": jnp.ones((L, D), jnp.float32),
             "ln2": jnp.ones((L, D), jnp.float32),
-            "wqkv": w(L, 3, D, H, K),
+            # fan-in is D: the default would read it off shape[-2] = H,
+            # giving q.k logits a std of ~D/H (64 at 768/12) — attention
+            # so close to one-hot that a bf16 rounding decorrelates the
+            # logits, and no parity check on the chip means anything
+            "wqkv": w(L, 3, D, H, K, scale=1.0 / onp.sqrt(D)),
             "wo": w(L, H, K, D, scale=1.0 / onp.sqrt(H * K)),
             "gate": w(L, D, E),
             "w1": w(L, E, D, F),

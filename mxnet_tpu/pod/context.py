@@ -165,8 +165,7 @@ class PodContext:
         global jax device ids under an initialized ``jax.distributed``
         job, else the rank itself (CPU CI: one logical slot per host)."""
         import jax
-        from ..base import _distributed_is_initialized
-        if _distributed_is_initialized(jax):
+        if jax.distributed.is_initialized():
             return tuple(d.id for d in jax.local_devices())
         return (self.rank,)
 
@@ -178,9 +177,8 @@ class PodContext:
         exchange rides the ElasticKVStore socket transport instead
         (same fenced-round protocol either way)."""
         import jax
-        from ..base import (_distributed_is_initialized,
-                            initialize_distributed)
-        if _distributed_is_initialized(jax):
+        from ..base import initialize_distributed
+        if jax.distributed.is_initialized():
             return True
         if jax.default_backend() == "cpu":
             _log.info(
@@ -190,7 +188,7 @@ class PodContext:
             return False
         initialize_distributed(num_processes=self.nprocs,
                                process_id=self.rank)
-        return _distributed_is_initialized(jax)
+        return jax.distributed.is_initialized()
 
     # ------------------------------------------------------------------
     def group(self):

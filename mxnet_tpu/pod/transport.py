@@ -73,12 +73,11 @@ def _ensure_session(timeout_s: float = 120.0):
         if _SESSION is not None:
             return _SESSION
         import jax
-        from ..base import _distributed_is_initialized
         from ..elastic.session import ElasticSession
         from ..kvstore_server import ensure_server
         from .group import PodGroup
         n = _num_workers()
-        rank = jax.process_index() if _distributed_is_initialized(jax) \
+        rank = jax.process_index() if jax.distributed.is_initialized() \
             else worker_rank()
         addr = ensure_server(n, rank)
         ses = ElasticSession(PodGroup(addr), f"hostred-{rank}",

@@ -36,9 +36,8 @@ from typing import Dict, Optional
 import jax
 
 from ..base import MXNetError
-from ..ndarray.ndarray import NDArray
 from ..optimizer import _state_rebind, _state_values
-from ..step.stepfn import StepFunction
+from ..step.stepfn import StepFunction, _raw
 from .plan import ShardPlan
 
 __all__ = ["ShardedStepFunction"]
@@ -187,20 +186,33 @@ class ShardedStepFunction(StepFunction):
         # reads the same digest of the same global gradients).
         out_shardings = (pspec, sspec, None) + \
             ((rep,) if guard else ())
+        if plan.n_devices > 1:
+            # GSPMD partitions this program: trace it where the model
+            # code can see that (a Pallas kernel cannot be partitioned)
+            from ..ops.pallas_kernels import gspmd_partitioned
+            single = pure
+
+            def pure(*args):
+                with gspmd_partitioned():
+                    return single(*args)
         return jax.jit(pure,
                        in_shardings=in_shardings,
                        out_shardings=out_shardings,
                        donate_argnums=(0, 1) if self._donate else ())
 
     def step(self, x, *labels, batch_size=None):
-        xv = x._data if isinstance(x, NDArray) else x
+        raw = [_raw(a) for a in (x,) + labels]
         n = self._plan.n_batch
-        if getattr(xv, "ndim", 0) and xv.shape[0] % n:
+        if raw[0].ndim and raw[0].shape[0] % n:
             raise MXNetError(
-                f"sharded step: global batch {xv.shape[0]} does not "
+                f"sharded step: global batch {raw[0].shape[0]} does not "
                 f"divide by the '{self._plan.batch_axis}' axis size "
                 f"{n} (mesh {self._plan.axes})")
-        return super().step(x, *labels, batch_size=batch_size)
+        # place the batch on the mesh here: jit refuses an argument
+        # that is committed elsewhere (nd.array(..., ctx=mx.tpu(0)), the
+        # reference idiom, commits to one chip) instead of moving it
+        placed = [jax.device_put(v, self._plan.data_spec(v)) for v in raw]
+        return super().step(*placed, batch_size=batch_size)
 
     __call__ = step
 
@@ -249,18 +261,8 @@ class ShardedStepFunction(StepFunction):
         evidence the ``shardlint`` pass verifies: post-SPMD HLO text,
         the compiled input/output shardings, the mesh and the plan.
         A persistent-cache hit when the step already ran."""
-        import jax.numpy as jnp
-        if self._last is None:
-            raise MXNetError("no compiled step yet — call step() first")
-        fn, _ = self._last
-        inputs = tuple(a._data if isinstance(a, NDArray)
-                       else jnp.asarray(a) for a in (x,) + labels)
-        lrs = tuple(jnp.asarray(0.0) for _ in self._indices)
-        wds = tuple(jnp.asarray(0.0) for _ in self._indices)
+        compiled = self.compiled(x, *labels)
         pvals, svals = self._gather()
-        rng = jax.random.key_data(jax.random.key(0))
-        compiled = fn.lower(pvals, svals, lrs, wds, inputs,
-                            rng).compile()
         return {"hlo": compiled.as_text(),
                 "input_shardings": compiled.input_shardings,
                 "output_shardings": compiled.output_shardings,

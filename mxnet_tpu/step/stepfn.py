@@ -26,9 +26,12 @@ one dispatch per step instead of O(params):
   the Updater states in place, so checkpoints, kvstore updaters and
   ``mxresil`` preemption guards observe the post-update values.
 
-The fused step is **bitwise-identical** to the eager loop
-(test-enforced for SGD/Adam/AdamW in tests/test_step.py). Two
-mechanisms make that hold: the eager per-param path dispatches each
+The fused step is **bitwise-identical** to the eager loop wherever XLA
+compiles an op the same way inside one program and alone
+(test-enforced for SGD/Adam/AdamW in tests/test_step.py on XLA:CPU
+with its dot fusions off, tests/conftest.py); elsewhere — the TPU's
+whole-program fusions — it agrees to rounding. Two mechanisms keep the
+update itself exact: the eager per-param path dispatches each
 optimizer kernel as one jitted program (optimizer._jk — the same
 expression DAG XLA sees inside the fused step, so FMA contraction
 applies equally to both), and an ``optimization_barrier`` pins the
@@ -80,8 +83,9 @@ class StepFunction:
         loss.backward()
         trainer.step(batch_size)
 
-    and bitwise-equal to it for every optimizer with a functional
-    ``fused_apply`` (SGD/NAG/Adam/AdamW/RMSProp). Without a trainer,
+    and equal to it (bitwise under the condition in the module
+    docstring) for every optimizer with a functional ``fused_apply``
+    (SGD/NAG/Adam/AdamW/RMSProp). Without a trainer,
     pass ``optimizer=``/``optimizer_params=`` and the StepFunction owns
     its own Updater (state lives in ``self.updater.states`` — the same
     structure ``Trainer.save_states`` snapshots).
@@ -776,23 +780,31 @@ class StepFunction:
                 "fused_step_cache_misses_total").value(),
         }
 
-    def cost_analysis(self, x, *labels):
-        """XLA cost analysis of the compiled step (bench roofline,
-        mxtune cost-model features): a stable, JSON-serializable dict —
-        sorted keys, plain floats only, always containing ``flops`` and
-        ``bytes accessed``. Lowers with the CURRENT buffers (a
-        persistent-cache hit when the step already ran); does not
-        execute or donate."""
+    def compiled(self, x, *labels):
+        """The newest step program as a jax ``Compiled``: lowered with
+        the CURRENT buffers (a persistent-cache hit when the step
+        already ran); does not execute or donate. ``as_text()`` is the
+        post-optimization HLO, ``memory_analysis()`` and
+        ``cost_analysis()`` XLA's own accounting."""
         if self._last is None:
             raise MXNetError("no compiled step yet — call step() first")
         fn, _ = self._last
-        inputs = tuple(_raw(a) for a in (x,) + labels)
+        # the batch by shape only: where it is placed is the step's
+        # business (the sharded step moves it onto its mesh)
+        inputs = tuple(jax.ShapeDtypeStruct(v.shape, v.dtype)
+                       for v in map(_raw, (x,) + labels))
         lrs = tuple(jnp.asarray(0.0) for _ in self._indices)
         wds = tuple(jnp.asarray(0.0) for _ in self._indices)
         pvals, svals = self._gather()
         rng = jax.random.key_data(jax.random.key(0))
-        cost = fn.lower(pvals, svals, lrs, wds, inputs,
-                        rng).compile().cost_analysis()
+        return fn.lower(pvals, svals, lrs, wds, inputs, rng).compile()
+
+    def cost_analysis(self, x, *labels):
+        """XLA cost analysis of the compiled step (bench roofline,
+        mxtune cost-model features): a stable, JSON-serializable dict —
+        sorted keys, plain floats only, always containing ``flops`` and
+        ``bytes accessed``."""
+        cost = self.compiled(x, *labels).cost_analysis()
         if isinstance(cost, (list, tuple)):
             cost = cost[0] if cost else {}
         # backend cost dicts leak device objects and odd scalar types;

@@ -61,9 +61,7 @@ def test_ssd_synthetic_voc_map_gate():
 # round-4 full-recipe gates (VERDICT r3 item 4). These reproduce the
 # REFERENCE recipe shapes, not thumbnails: run them with
 # MXTPU_FULL_GATES=1 (word-LM ~50 min, SSD ~25 min on CPU — too long
-# for the default suite, which keeps the scaled pins above). The
-# measured values and the honest gap to the reference numbers live in
-# ROUND4_NOTES.md.
+# for the default suite, which keeps the scaled pins above).
 # ---------------------------------------------------------------------------
 
 # pinned IN THE SUITE ENVIRONMENT (conftest: 8 virtual CPU devices):

@@ -4,7 +4,9 @@ Contracts under test:
 - the fused step (one donated XLA computation: forward + backward +
   exchange + optimizer) is BITWISE-equal to the eager per-param loop
   for SGD/Adam/AdamW over several steps, momentum/weight-decay state
-  included;
+  included — where XLA compiles an op the same way inside one program
+  and alone, which on jax 0.9's XLA:CPU needs its dot fusions off
+  (tests/conftest.py sets --xla_cpu_experimental_ynn_fusion_type=);
 - steady-state shapes never recompile (tier-1 smoke: >=2 post-warmup
   steps with zero recompiles);
 - donation safety: old weight buffers are not aliased into the new

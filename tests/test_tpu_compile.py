@@ -1,0 +1,118 @@
+"""The main path's kernels and programs, compiled for a TPU v5e that is
+described and not attached (on-chip-measurement guide, section 2.3).
+
+Nothing runs here: a compile that passes says the chip's compiler takes
+the program at its real widths — tiling, fast-memory use, device memory
+— and that the Pallas kernel is in it (``tpu_custom_call``). It is not
+a chip run and gives no results or times; ``chip_smoke.py`` is the run.
+
+All of these live in ONE file and describe the topology inside a
+module-scoped fixture: only one process may load the TPU library, so
+nothing touches it at import, in a ``skipif``, in ``parametrize``
+arguments or in conftest.py, and only the xdist worker that is handed
+this file loads it.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """Sharding on the first device of a described v5e 2x2 host, with
+    jax's persistent compilation cache switched off around the module:
+    an executable compiled for a described device is written to the
+    cache but cannot be read back without a chip."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+def _shapes(sharding, *specs):
+    return [jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+            for shape, dtype in specs]
+
+
+@pytest.mark.parametrize("shape,dtype,causal", [
+    ((8, 16, 1024, 64), jnp.bfloat16, True),    # GPT-style LM block
+    ((4, 12, 384, 64), jnp.bfloat16, False),    # BERT fine-tune length
+    ((16, 12, 512, 64), jnp.float32, False),    # BERT-base, chip_smoke.py
+    ((2, 8, 4096, 128), jnp.bfloat16, True),    # long context, wide head
+    ((4, 8, 512, 96), jnp.float32, False),      # head_dim padded 96 -> 128
+    ((2, 12, 400, 64), jnp.float32, True),      # odd T: tail block masked
+], ids=["lm1024-bf16-causal", "bert384-bf16", "bert512-f32",
+        "long4096x128-bf16-causal", "pad-d96-f32", "odd-t400-f32-causal"])
+def test_flash_attention_forward_and_backward_compile(one_chip, shape,
+                                                      dtype, causal):
+    from mxnet_tpu.ops.pallas_kernels import flash_attention
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=causal)
+
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(jnp.float32).sum()
+
+    qkv = _shapes(one_chip, *[(shape, dtype)] * 3)
+    for fn in (fwd, jax.grad(loss, argnums=(0, 1, 2))):
+        text = jax.jit(fn).lower(*qkv).compile().as_text()
+        assert "tpu_custom_call" in text, \
+            "the dense composition was compiled, not the Pallas kernel"
+
+
+@pytest.mark.parametrize("shape", [
+    (64, 3, 7, 7), (256, 64, 1, 1), (512, 512, 3, 3), (1000, 2048),
+], ids=["stem7x7", "bottleneck1x1", "conv3x3-512", "fc"])
+def test_fused_mp_sgd_kernel_compiles(one_chip, shape):
+    """opt.kernels' fused mixed-precision SGD + cast, at ResNet-50
+    weight shapes with bf16 gradients."""
+    from mxnet_tpu.opt.kernels import _mp_sgd_call
+    grad, mom, w32 = _shapes(one_chip, (shape, jnp.bfloat16),
+                             (shape, jnp.float32), (shape, jnp.float32))
+    lr, wd, rescale = _shapes(one_chip, *[((), jnp.float32)] * 3)
+    text = _mp_sgd_call.lower(
+        grad, mom, w32, lr, wd, rescale, out_dtype="bfloat16",
+        momentum=0.9, clip=None, interpret=False).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+def test_serve2_scan_decode_and_prefill_compile(one_chip):
+    """PagedLM's chip-only formulation — scan paged attention over
+    donated pools — at the serving widths of chip_smoke.py (depth, vocab
+    and pool cut: they change the program's size, not its tiling)."""
+    from mxnet_tpu.parallel.pipeline_lm import init_pipeline_lm
+    from mxnet_tpu.serve2.decode import PagedLM
+    params = init_pipeline_lm(0, vocab=1024, d_model=768, n_layers=2,
+                              n_heads=12, d_head=64, d_ff=3072,
+                              n_experts=1)
+    lm = PagedLM(params, page_size=16, num_pages=64, max_pages_per_seq=8,
+                 donate="on", attention="scan", decode_steps=4)
+
+    def sds(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    i32 = jnp.int32
+    bt, vec = _shapes(one_chip, ((8, 8), i32), ((8,), i32))
+    decode = jax.jit(lm._decode_fn, donate_argnums=(1,)).lower(
+        sds(lm.params), sds(lm.pools), bt, vec, vec, vec).compile()
+    bt_row, length, tokens = _shapes(one_chip, ((8,), i32), ((), i32),
+                                     ((128,), i32))
+    prefill = jax.jit(lm._prefill_fn, donate_argnums=(1,)).lower(
+        sds(lm.params), sds(lm.pools), bt_row, length, tokens).compile()
+    pool_bytes = sum(int(p.nbytes) for p in lm.pools.values())
+    for prog in (decode, prefill):
+        # the donated pools are updated in place: the program's outputs
+        # alias them instead of being allocated beside them
+        assert prog.memory_analysis().alias_size_in_bytes >= pool_bytes
