@@ -1,0 +1,10 @@
+"""Trainer host work: wall time of the program's ``step.writeback``
+spans (rebinding parameters and optimizer state to the step's outputs)
+over the window's steps, in ms a step. Source: the program's own span
+trees (``program_span``)."""
+from benchmark import span_reduce
+
+
+def read(run):
+    return span_reduce.span_ms_per_step(span_reduce.step_trees(run),
+                                        "step.writeback")

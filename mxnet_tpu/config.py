@@ -675,8 +675,11 @@ register_flag(
     "verify) and the training path (step -> exchange -> guard vote -> "
     "elastic rebuild), feed the per-phase latency histograms "
     "(mxtrace_phase_*_seconds) and the crash flight recorder. On by "
-    "default: a span is two monotonic clock reads and a deque append "
-    "(<2% at default sampling, bench.py --trace-overhead enforces); "
+    "default: a span is two monotonic clock reads, a deque append and "
+    "one check whether a profile is being taken; on one TPU v5e its "
+    "cost in a fused train step was not resolvable (step_ms +0.2% and "
+    "-0.9% with MXTRACE on against off, inside a run-to-run spread of "
+    "0.6-2.6%: PERF.md section 5); "
     "tracing never touches jit cache keys, so it can never recompile.")
 register_flag(
     "MXTRACE_SAMPLE", float, 1.0,

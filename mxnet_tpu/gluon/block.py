@@ -18,6 +18,7 @@ compiled function's vjp — the analog of CachedOp::Backward (:1128).
 """
 from __future__ import annotations
 
+import contextlib
 import re
 import threading
 import warnings
@@ -105,6 +106,7 @@ class _TraceCtx(threading.local):
 
 
 _trace_ctx = _TraceCtx()
+_NO_SCOPE = contextlib.nullcontext()
 
 
 class nn_trace_ctx:
@@ -185,6 +187,10 @@ def functional_call(block, pvals: Dict[str, Any], args, training=False,
 class Block:
     """ref: block.py:131."""
 
+    # the name this block is registered under in its parent: what
+    # names it in a traced program's metadata (_traced_scope)
+    _child_name: Optional[str] = None
+
     def __init__(self, prefix=None, params=None):
         self._empty_prefix = prefix == ""
         self._prefix, self._params = _BlockScope.create(
@@ -233,6 +239,7 @@ class Block:
             existing = self.__dict__.get("_children")
             if existing is not None:
                 existing[name] = value
+                value._child_name = name
         elif isinstance(value, Parameter):
             reg = self.__dict__.get("_reg_params")
             if reg is not None:
@@ -240,7 +247,20 @@ class Block:
         super().__setattr__(name, value)
 
     def register_child(self, block, name=None):
-        self._children[name or str(len(self._children))] = block
+        name = name or str(len(self._children))
+        self._children[name] = block
+        block._child_name = name
+
+    def _traced_scope(self):
+        """While a parent's ``forward`` runs under a trace
+        (``nn_trace_ctx``: a fused step, a hybridized block), the
+        ``jax.named_scope`` of this block's name in its parent, so that
+        an operation's path in the program's metadata reads
+        ``forward/layers/0/attn/...``; no scope otherwise, and none for
+        the root, which its caller names."""
+        if _trace_ctx.active and self._child_name is not None:
+            return jax.named_scope(self._child_name)
+        return _NO_SCOPE
 
     def register_forward_hook(self, hook):
         self._forward_hooks.append(hook)
@@ -271,7 +291,8 @@ class Block:
     def __call__(self, *args):
         for hook in self._forward_pre_hooks:
             hook(self, args)
-        out = self.forward(*args)
+        with self._traced_scope():
+            out = self.forward(*args)
         for hook in self._forward_hooks:
             hook(self, args, out)
         return out
@@ -393,7 +414,8 @@ class HybridBlock(Block):
             return self.forward(*args)
         if not self._active:
             return super().__call__(*args)
-        return self._call_cached(*args)
+        with self._traced_scope():
+            return self._call_cached(*args)
 
     def optimize_for(self, x, *args, backend=None, **kwargs):
         """ref: block.py optimize_for — subgraph backend hook. On TPU the

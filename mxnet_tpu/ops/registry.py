@@ -197,15 +197,19 @@ def make_nd_function(name: str) -> Callable:
             # raw uint32 key data: vjp-safe (int cotangents are float0)
             inputs.append(_w(_jax.random.key_data(next_key())))
         # op-level tracing (telemetry pillar 1): when the profiler is
-        # running, the op body executes under jax.named_scope +
-        # TraceAnnotation so the MXNet op name lands in XProf, the HLO
-        # metadata of any enclosing jit trace, and the chrome-trace
-        # dump; maybe_instrument is the identity when the profiler is
-        # off (one branch on the hot path)
+        # running, the op body executes under a TraceAnnotation so the
+        # MXNet op name lands in XProf and the chrome-trace dump;
+        # maybe_instrument is the identity when the profiler is off
+        # (one branch on the hot path). Under an enclosing jit trace
+        # (a fused step, a hybridized block) the op name goes into the
+        # program's HLO metadata, profiler or not
         from ..telemetry.tracing import maybe_instrument as _instr
+        from ..telemetry.tracing import trace_scope as _scope
         use_fn = _instr(name, use_fn)
-        out = invoke(use_fn, inputs, n_out=n_out,
-                     differentiable=info.differentiable, **rest_params)
+        with _scope(name, [i._data for i in inputs]):
+            out = invoke(use_fn, inputs, n_out=n_out,
+                         differentiable=info.differentiable,
+                         **rest_params)
         # Hide non-visible outputs in eager mode too (ref:
         # FNumVisibleOutputs applies to imperative invoke). Ops with
         # aux_updates are exempt: their hidden outputs are the new aux

@@ -26,8 +26,7 @@ from typing import List, Optional
 import jax
 
 __all__ = ["set_config", "set_state", "dump", "dumps", "pause", "resume",
-           "is_running", "is_paused", "Scope", "scope", "Task", "Frame",
-           "Event", "Marker", "Domain"]
+           "is_running", "is_paused", "Scope", "scope", "Task", "Domain"]
 
 _state = threading.local()
 _config = {"filename": "profile.json", "profile_all": False,
@@ -298,38 +297,10 @@ class Domain:
     def new_task(self, name):
         return Task(name, self)
 
-    def new_frame(self, name):
-        return Frame(name, self)
-
-    def new_event(self, name):
-        return Event(name, self)
-
-    def new_marker(self, name):
-        return Marker(name, self)
 
 
 class Task(_Named):
     pass
-
-
-class Frame(_Named):
-    pass
-
-
-class Event(_Named):
-    pass
-
-
-class Marker:
-    def __init__(self, name, domain=None):
-        self.name = name
-
-    def mark(self, scope_name="process"):
-        if _domain_enabled("api"):
-            _append_event({"name": self.name, "ph": "i", "cat": "api",
-                           "pid": os.getpid(),
-                           "ts": time.perf_counter_ns() / 1000.0,
-                           "s": scope_name[0]})
 
 
 # MXNET_PROFILER_AUTOSTART / MXNET_PROFILER_MODE (ref: env_var.md): start

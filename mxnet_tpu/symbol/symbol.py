@@ -614,9 +614,9 @@ def eval_graph(symbol: Symbol, value_map: Dict[str, "jax.Array"],
     values: Dict[Tuple[int, int], object] = {}
     aux_updates: Dict[str, object] = {}
     # symbolic-domain op tracing (telemetry pillar 1): under jit this
-    # trace runs ONCE, so the named_scope stamps each node's op name
-    # into the compiled HLO permanently; trace_ops is False when the
-    # profiler is off and the loop below pays nothing
+    # trace runs ONCE, and trace_scope stamps each node's op name into
+    # the compiled HLO permanently, profiler or not; trace_ops is False
+    # when the profiler is off and the loop below pays no op span
     trace_ops = _tracing.active("symbolic")
 
     def run():
@@ -634,12 +634,13 @@ def eval_graph(symbol: Symbol, value_map: Dict[str, "jax.Array"],
                 params["_training"] = training
             if info.needs_rng:
                 ins.append(jax.random.key_data(_random.next_key()))
-            if trace_ops:
-                with _tracing.op_span(info.name, "symbolic",
-                                      node=node.name):
+            with _tracing.trace_scope(info.name, ins):
+                if trace_ops:
+                    with _tracing.op_span(info.name, "symbolic",
+                                          node=node.name):
+                        out = info.fn(*ins, **params)
+                else:
                     out = info.fn(*ins, **params)
-            else:
-                out = info.fn(*ins, **params)
             outs = list(out) if isinstance(out, (tuple, list)) else [out]
             for i, o in enumerate(outs):
                 values[(id(node), i)] = o

@@ -2,10 +2,12 @@
 
 Three pillars (ISSUE 2), one package:
 
-- :mod:`~mxnet_tpu.telemetry.tracing` — op-level tracing: every
-  registered op body runs under ``jax.named_scope`` +
-  ``jax.profiler.TraceAnnotation`` when the profiler is on, so MXNet op
-  names survive into XProf and the chrome-trace dump;
+- :mod:`~mxnet_tpu.telemetry.tracing` — op-level tracing: a registered
+  op traced into a program runs under ``jax.named_scope`` (its name
+  survives into the compiled HLO, profiler or not), and while the
+  profiler is on its execution is a ``jax.profiler.TraceAnnotation``
+  plus a chrome-trace event, so MXNet op names reach XProf and the
+  chrome-trace dump;
 - :mod:`~mxnet_tpu.telemetry.recompile` /
   :mod:`~mxnet_tpu.telemetry.memory` — recompile & memory accounting:
   every jit-cache miss is counted and classified ("why did we

@@ -2,22 +2,26 @@
 
 The attribution problem on TPU (ISSUE 2; arXiv:2008.01040, 2301.13062):
 XLA fuses and renames, so a raw XProf trace shows ``fusion.123`` and the
-user cannot tell which MXNet op it came from. The fix is to run every
-registered op body under
+user cannot tell which MXNet op it came from. Two mechanisms, each where
+the thing it names happens:
 
-- :func:`jax.named_scope` — stamps the op name into the jaxpr/HLO
-  metadata, so the name survives INTO the compiled program and XProf
-  attributes fused kernels back to framework ops;
-- :class:`jax.profiler.TraceAnnotation` — emits a host-side trace event
-  into the jax profiler (XProf timeline) for eager dispatch;
-
-plus a chrome-trace duration event + aggregate-table update in our own
-profiler, so ``profiler.dump()`` carries op names too.
-
-All of it is gated on profiler state: :func:`active` is a couple of
-attribute reads when the profiler is off, and :func:`maybe_instrument`
-returns the raw function unchanged, so the eager hot path pays one
-predictable branch.
+- :func:`trace_scope` — an op that runs **under a jax trace** (a fused
+  step, a hybridized block, a bound executor) runs under
+  :func:`jax.named_scope`, which stamps the op name into the jaxpr/HLO
+  metadata: the name survives INTO the compiled program, so a profile
+  of it, and the benchmark's per-phase readers, attribute fused kernels
+  back to framework ops. Trace time only, whether or not any profiler
+  runs; an eager dispatch has no tracer among its inputs and gets the
+  shared null context;
+- :func:`op_span` / :func:`maybe_instrument` — while the MXNet
+  profiler is on, an op's **execution** is a
+  :class:`jax.profiler.TraceAnnotation` (a host-side event of the jax
+  profiler's timeline) plus a chrome-trace duration event and an
+  aggregate-table update in our own profiler, so ``profiler.dump()``
+  carries op names too. Gated on profiler state: :func:`active` is a
+  couple of attribute reads when the profiler is off, and
+  :func:`maybe_instrument` returns the raw function unchanged, so the
+  eager hot path pays one predictable branch.
 
 Domains mirror the reference's profiler config: ``imperative`` (eager /
 nd dispatch, including under a CachedOp jit trace), ``symbolic``
@@ -34,7 +38,19 @@ from typing import Callable, Optional
 
 import jax
 
-__all__ = ["active", "maybe_instrument", "op_span"]
+__all__ = ["active", "maybe_instrument", "op_span", "trace_scope"]
+
+_NO_SCOPE = contextlib.nullcontext()
+
+
+def trace_scope(name: str, values):
+    """``jax.named_scope(name)`` where one of ``values`` (raw arrays) is
+    a jax tracer, that is, where the op is being traced into a program;
+    the shared null context for an eager dispatch."""
+    for v in values:
+        if isinstance(v, jax.core.Tracer):
+            return jax.named_scope(name)
+    return _NO_SCOPE
 
 
 def active(domain: str = "imperative") -> bool:
@@ -48,12 +64,12 @@ def op_span(name: str, domain: str = "imperative", node: Optional[str] = None):
     """Context manager tracing one op execution, or a no-op when the
     profiler is off / the domain is filtered out."""
     if not active(domain):
-        return contextlib.nullcontext()
+        return _NO_SCOPE
     return _OpSpan(name, domain, node)
 
 
 class _OpSpan:
-    __slots__ = ("name", "domain", "node", "_t0", "_jscope", "_jannot")
+    __slots__ = ("name", "domain", "node", "_t0", "_jannot")
 
     def __init__(self, name, domain, node=None):
         self.name = name
@@ -61,8 +77,6 @@ class _OpSpan:
         self.node = node
 
     def __enter__(self):
-        self._jscope = jax.named_scope(self.name)
-        self._jscope.__enter__()
         self._jannot = jax.profiler.TraceAnnotation(self.name)
         self._jannot.__enter__()
         self._t0 = time.perf_counter_ns()
@@ -71,7 +85,6 @@ class _OpSpan:
     def __exit__(self, *exc):
         t1 = time.perf_counter_ns()
         self._jannot.__exit__(*exc)
-        self._jscope.__exit__(*exc)
         from .. import profiler as _prof
         if _prof._active():  # state may have flipped mid-span
             dur_us = (t1 - self._t0) / 1000.0

@@ -20,15 +20,30 @@ One process-wide tracer for BOTH hot paths (docs/observability.md):
 - completed spans land in **bounded per-thread buffers** (drained by
   exporters/tests), the flight-recorder rings
   (:mod:`~mxnet_tpu.trace.recorder`), and — when ``MXTRACE_EXPORT``
-  names a file — one JSON line per span.
+  names a file — one JSON line per span;
+- while anyone takes a **profile** (``jax.profiler.trace``,
+  ``mx.profiler``, a benchmark's traced run) a recorded ``span()`` is
+  also a ``jax.profiler.TraceAnnotation`` of the same name (a root
+  with a ``step`` attribute a ``StepTraceAnnotation``), so the
+  program's spans are events of the profile's ``/host:CPU`` plane, on
+  the clock of the device's ``XLA Ops`` and with no conversion.
+  Retroactive ``emit()`` spans have no live interval and stay out;
+- a span opened with ``cpu=True``, and every span under it, also
+  records the thread's CPU time over its interval (attribute
+  ``cpu_ns``): wall less ``cpu_ns`` is the time the thread was parked
+  (inside the runtime, or off the core).
 
 Cost model: tracing is ON by default (``MXTRACE``) because a span is
-two clock reads, one small dict and a deque append — the <2% overhead
-contract ``bench.py --trace-overhead`` enforces. ``MXTRACE_SAMPLE``
-drops whole traces (the decision is made once at the root and
-inherited), so high-QPS serving can run at 0.1 sampling and still pay
-~nothing on the untraced requests. Nothing here touches jit cache
-keys: tracing can never cause a recompile.
+two clock reads, one small dict, a deque append and, with no profile
+being taken, one check that none is. Measured on one TPU v5e (PERF.md
+section 5, PR 25): a fused train step's ``step_ms`` with ``MXTRACE=1``
+against ``MXTRACE=0`` read +0.2% (ResNet-50, 252 ms) and -0.9%
+(BERT-base, 221 ms) over six runs a side on the same seeds, inside the
+runs' own spread of 0.6-2.6%: not resolvable.
+``MXTRACE_SAMPLE`` drops whole traces (the decision is made once at
+the root and inherited), so high-QPS serving can run at 0.1 sampling
+and still pay ~nothing on the untraced requests. Nothing here touches
+jit cache keys: tracing can never cause a recompile.
 """
 from __future__ import annotations
 
@@ -41,6 +56,8 @@ import threading
 import time
 from collections import deque
 from typing import Dict, List, Optional
+
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from ..san.runtime import make_lock
 
@@ -121,14 +138,18 @@ def _new_span_id() -> str:
 class SpanContext:
     """The propagated identity of an in-flight span: enough to parent
     a child from another thread. ``sampled=False`` contexts still
-    propagate (children inherit the drop decision)."""
+    propagate (children inherit the drop decision), and so does
+    ``cpu`` (children of a span that counts its thread's CPU time
+    count theirs)."""
 
-    __slots__ = ("trace_id", "span_id", "sampled")
+    __slots__ = ("trace_id", "span_id", "sampled", "cpu")
 
-    def __init__(self, trace_id: str, span_id: str, sampled: bool):
+    def __init__(self, trace_id: str, span_id: str, sampled: bool,
+                 cpu: bool = False):
         self.trace_id = trace_id
         self.span_id = span_id
         self.sampled = sampled
+        self.cpu = cpu
 
     def __repr__(self):
         return (f"SpanContext({self.trace_id}, {self.span_id}, "
@@ -144,11 +165,12 @@ class Span:
 
     __slots__ = ("trace_id", "span_id", "parent_id", "name",
                  "subsystem", "t0_ns", "t1_ns", "attrs", "thread",
-                 "status", "sampled")
+                 "status", "sampled", "cpu")
 
     def __init__(self, name: str, subsystem: str, trace_id: str,
                  span_id: str, parent_id: Optional[str],
-                 t0_ns: Optional[int] = None, sampled: bool = True):
+                 t0_ns: Optional[int] = None, sampled: bool = True,
+                 cpu: bool = False):
         self.name = name
         self.subsystem = subsystem
         self.trace_id = trace_id
@@ -160,6 +182,7 @@ class Span:
         self.thread = threading.get_ident()
         self.status = "ok"
         self.sampled = sampled
+        self.cpu = cpu
 
     def set(self, **attrs) -> "Span":
         """Attach typed attributes (JSON-serializable values)."""
@@ -167,7 +190,8 @@ class Span:
         return self
 
     def context(self) -> SpanContext:
-        return SpanContext(self.trace_id, self.span_id, self.sampled)
+        return SpanContext(self.trace_id, self.span_id, self.sampled,
+                           self.cpu)
 
     @property
     def duration_s(self) -> Optional[float]:
@@ -314,20 +338,42 @@ class _SpanCm:
     """The ``with span(...)`` context manager: opens a child of the
     ambient context (or a new sampled-or-not root), publishes itself
     as the ambient context, and records on exit — error status and
-    exception type attached when the block raised."""
+    exception type attached when the block raised. While a profile is
+    being taken the block is also an annotation of the profile (a root
+    that carries a ``step`` attribute a step annotation, which the
+    profiler's own per-step analysis reads); with none, that costs the
+    one ``is_enabled`` check."""
 
-    __slots__ = ("span", "_token")
+    __slots__ = ("span", "_token", "_annot", "_cpu0")
 
     def __init__(self, sp: Span):
         self.span = sp
         self._token = None
+        self._annot = None
 
     def __enter__(self) -> Span:
-        self._token = _CURRENT.set(self.span.context())
-        return self.span
+        sp = self.span
+        self._token = _CURRENT.set(sp.context())
+        if TraceAnnotation.is_enabled():
+            if sp.parent_id is None and "step" in sp.attrs:
+                self._annot = StepTraceAnnotation(
+                    sp.name, step_num=sp.attrs["step"])
+            else:
+                self._annot = TraceAnnotation(sp.name)
+            self._annot.__enter__()
+        if sp.cpu:
+            # read inside the wall interval at both ends, so wall >=
+            # cpu up to the kernel's accounting (10 ms ticks on the
+            # chip's machine)
+            self._cpu0 = time.thread_time_ns()
+        return sp
 
     def __exit__(self, exc_type, exc, tb):
         sp = self.span
+        if sp.cpu:
+            sp.attrs["cpu_ns"] = time.thread_time_ns() - self._cpu0
+        if self._annot is not None:
+            self._annot.__exit__(exc_type, exc, tb)
         sp.t1_ns = time.perf_counter_ns()
         if exc_type is not None:
             sp.status = "error"
@@ -360,11 +406,16 @@ class _CtxOnlyCm:
         return False
 
 
-def span(name: str, subsystem: str = "app", **attrs):
+def span(name: str, subsystem: str = "app", cpu: bool = False,
+         **attrs):
     """``with trace.span("serve.request", "serve", model=m) as sp:`` —
     the one instrumentation primitive. Child of the ambient context;
     a new root (with the ``MXTRACE_SAMPLE`` decision) when there is
-    none. Returns a no-op span when tracing is off."""
+    none. ``cpu=True`` also records the thread's CPU time over the
+    block as the attribute ``cpu_ns``, here and in every span under
+    this one (two ``time.thread_time_ns()`` reads each, which a span
+    that does not ask pays nothing of). Returns a no-op span when
+    tracing is off."""
     gen, on, sample = _flags()
     if not on:
         return _NULL
@@ -375,13 +426,14 @@ def span(name: str, subsystem: str = "app", **attrs):
             return _CtxOnlyCm(SpanContext(_new_trace_id(),
                                           _new_span_id(), False))
         sp = Span(name, subsystem, _new_trace_id(), _new_span_id(),
-                  None, sampled=True)
+                  None, sampled=True, cpu=cpu)
     else:
         if not parent.sampled:
             return _CtxOnlyCm(SpanContext(parent.trace_id,
                                           _new_span_id(), False))
         sp = Span(name, subsystem, parent.trace_id, _new_span_id(),
-                  parent.span_id, sampled=True)
+                  parent.span_id, sampled=True,
+                  cpu=cpu or parent.cpu)
     if attrs:
         sp.attrs.update(attrs)
     return _SpanCm(sp)

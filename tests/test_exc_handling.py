@@ -181,6 +181,9 @@ def test_server_profiling_commands(tmp_path, monkeypatch):
     addr = f"127.0.0.1:{_free_port()}"
     server = KVServer(addr, num_workers=1)
     monkeypatch.setenv("MX_KV_SERVER", addr)
+    # the server role runs in this process: its profile goes beside the
+    # configured file, not into the checkout's root
+    profiler.set_config(filename=str(tmp_path / "profile.json"))
     try:
         assert not profiler.is_running()
         profiler.set_state("run", profile_process="server")
@@ -191,4 +194,5 @@ def test_server_profiling_commands(tmp_path, monkeypatch):
         assert not profiler.is_running()
     finally:
         profiler.set_state("stop")
+        profiler.set_config(filename="profile.json")
         server.stop()

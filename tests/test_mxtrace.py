@@ -526,6 +526,208 @@ def test_plain_fused_step_trace():
 
 
 # ---------------------------------------------------------------------------
+# the fused step's spans on the profile's clock, its CPU time, and the
+# scope names inside the step program (ISSUE 25)
+# ---------------------------------------------------------------------------
+
+STEP_TREE = {"train.step": None, "step.prep": "train.step",
+             "step.prep.hyper": "step.prep",
+             "step.prep.gather": "step.prep",
+             "step.prep.rng": "step.prep",
+             "step.dispatch": "train.step",
+             "step.writeback": "train.step"}
+
+
+def _dense_step():
+    mx.random.seed(0)
+    net = gluon.nn.HybridSequential()
+    net.add(gluon.nn.Dense(8, in_units=8, activation="relu"),
+            gluon.nn.Dense(4, in_units=8))
+    net.initialize()
+    trainer = gluon.Trainer(net.collect_params(), "sgd",
+                            {"learning_rate": 0.05})
+    fused = trainer.fuse_step(net, gluon.loss.L2Loss())
+    rng = onp.random.RandomState(0)
+    x = nd.array(rng.uniform(-1, 1, (4, 8)).astype("float32"))
+    y = nd.array(onp.zeros((4, 4), "float32"))
+    return fused, x, y
+
+
+def _bert_step():
+    from mxnet_tpu import models
+    mx.random.seed(0)
+    net = models.BERTModel(vocab_size=32, units=16, num_layers=2,
+                           num_heads=2, hidden_size=32, max_len=8,
+                           dropout=0.1)
+    net.initialize()
+    trainer = gluon.Trainer(net.collect_params(), "adam",
+                            {"learning_rate": 1e-3})
+    fused = trainer.fuse_step(net,
+                              gluon.loss.SoftmaxCrossEntropyLoss())
+    x = nd.array(onp.ones((2, 8), "int32"))
+    y = nd.array(onp.zeros((2, 8), "float32"))
+    return fused, x, y
+
+
+@pytest.fixture(scope="module")
+def warm_step_spans():
+    """The span dicts of one steady fused step (the second: no
+    compile)."""
+    fused, x, y = _dense_step()
+    fused.step(x, y)
+    trace.drain()
+    fused.step(x, y)
+    return trace.drain()
+
+
+@pytest.mark.parametrize("name", sorted(STEP_TREE))
+def test_fused_step_tree_node_parent_and_cpu(warm_step_spans, name):
+    by_name = {s["name"]: s for s in warm_step_spans}
+    assert set(by_name) == set(STEP_TREE)  # the names docs/tools read
+    node = by_name[name]
+    parent = STEP_TREE[name]
+    assert node["parent_id"] == (by_name[parent]["span_id"]
+                                 if parent else None)
+    # the thread's CPU time is inside the wall interval; a kernel that
+    # accounts it by scheduler ticks (the chip's machine: 10 ms) may
+    # round it up by one
+    cpu_ns = node["attrs"]["cpu_ns"]
+    assert node["dur_us"] * 1e3 + 10e6 >= cpu_ns >= 0
+
+
+def test_fused_step_prep_children_count_their_work(warm_step_spans):
+    by_name = {s["name"]: s for s in warm_step_spans}
+    # a rate and a weight decay for each of the 4 trainable leaves
+    assert by_name["step.prep.hyper"]["attrs"]["scalars"] == 8
+    assert by_name["step.prep.gather"]["attrs"]["leaves"] >= 4
+    prep = by_name["step.prep"]
+    inside = sum(by_name[n]["dur_us"] for n in STEP_TREE
+                 if STEP_TREE[n] == "step.prep")
+    assert inside <= prep["dur_us"]
+
+
+def test_cpu_time_is_asked_for_and_inherited():
+    with trace.span("plain", "app"):
+        with trace.span("plain.child", "app"):
+            pass
+    with trace.span("timed", "app", cpu=True):
+        with trace.span("timed.child", "app"):
+            pass
+    spans = {s["name"]: s for s in trace.drain()}
+    assert "cpu_ns" not in spans["plain"]["attrs"]
+    assert "cpu_ns" not in spans["plain.child"]["attrs"]
+    assert spans["timed"]["attrs"]["cpu_ns"] >= \
+        spans["timed.child"]["attrs"]["cpu_ns"] >= 0
+
+
+def _host_events(logdir):
+    """name -> [stats dict, ...] of the profile's /host:CPU plane."""
+    import glob
+    import jax
+    (path,) = glob.glob(os.path.join(str(logdir), "plugins", "profile",
+                                     "*", "*.xplane.pb"))
+    found = {}
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if plane.name == "/host:CPU":
+            for line in plane.lines:
+                for e in line.events:
+                    found.setdefault(e.name, []).append(
+                        (e.start_ns, e.duration_ns, dict(e.stats)))
+    return found
+
+
+@pytest.fixture(scope="module")
+def profiled_step(tmp_path_factory):
+    """(events of the profile's host plane, the program's spans) of two
+    fused steps run under ``jax.profiler.trace``, with one retroactive
+    ``emit`` among them."""
+    import jax
+    fused, x, y = _dense_step()
+    fused.step(x, y)
+    trace.drain()
+    logdir = tmp_path_factory.mktemp("profile")
+    with jax.profiler.trace(str(logdir)):
+        for _ in range(2):
+            fused.step(x, y)._data.block_until_ready()
+        with trace.span("outer", "app") as sp:
+            t0 = time.perf_counter_ns()
+            trace.emit("retroactive", "app", t0 - 1000, t0,
+                       parent=sp.context())
+    return _host_events(logdir), trace.drain()
+
+
+@pytest.mark.parametrize("name", sorted(STEP_TREE))
+def test_profile_host_plane_holds_the_programs_spans(profiled_step,
+                                                     name):
+    events, spans = profiled_step
+    assert len(events[name]) == 2 == sum(s["name"] == name
+                                         for s in spans)
+    # on the profile's clock the children lie inside the step's root
+    roots = events["train.step"]
+    for start, dur, _ in events[name]:
+        assert any(r0 <= start and start + dur <= r0 + rd
+                   for r0, rd, _ in roots)
+
+
+def test_profile_root_is_a_step_annotation_and_emit_stays_out(
+        profiled_step):
+    events, spans = profiled_step
+    steps = sorted(st["step_num"] for _, _, st in events["train.step"])
+    assert steps == sorted(s["attrs"]["step"] for s in spans
+                           if s["name"] == "train.step")
+    assert "outer" in events
+    assert "retroactive" not in events
+    assert any(s["name"] == "retroactive" for s in spans)
+
+
+def test_mxtrace_off_leaves_no_annotation_and_no_span(tmp_path):
+    import jax
+    fused, x, y = _dense_step()
+    fused.step(x, y)
+    trace.drain()
+    config.set_flag("MXTRACE", False)
+    try:
+        with jax.profiler.trace(str(tmp_path)):
+            fused.step(x, y)._data.block_until_ready()
+    finally:
+        config.unset_flag("MXTRACE")
+    assert trace.drain() == []
+    assert not set(STEP_TREE) & set(_host_events(tmp_path))
+
+
+@pytest.fixture(scope="module", params=["dense", "bert"])
+def step_op_paths(request):
+    """(which net, the ``op_name`` paths of the compiled step's HLO),
+    with the MXNet profiler never started."""
+    import re
+    from mxnet_tpu import profiler
+    assert not profiler.is_running()
+    fused, x, y = {"dense": _dense_step, "bert": _bert_step}[
+        request.param]()
+    fused.step(x, y)
+    text = fused.compiled(x, y).as_text()
+    return request.param, set(re.findall(r'op_name="([^"]*)"', text))
+
+
+@pytest.mark.parametrize("what", ["forward", "backward", "optimizer",
+                                  "child_block", "op"])
+def test_step_hlo_carries_scope_names_without_the_profiler(
+        step_op_paths, what):
+    net, paths = step_op_paths
+    block, op = {"dense": ("1", "FullyConnected"),
+                 "bert": ("attn", "LayerNorm")}[net]
+    want = {
+        "forward": lambda p: "/jvp(forward)/" in p,
+        "backward": lambda p: "/transpose(jvp(forward))/" in p,
+        "optimizer": lambda p: "/optimizer/" in p,
+        "child_block": lambda p: f"/jvp(forward)/{block}/" in p
+        or f"/jvp(forward)/layers/0/{block}/" in p,
+        "op": lambda p: f"/{op}/" in p and "forward" in p,
+    }[what]
+    assert any(want(p) for p in paths), sorted(paths)[:20]
+
+
+# ---------------------------------------------------------------------------
 # recompile auditor kinds (satellite: fused_step / serving2 /
 # plan-fingerprint keys each classify a forced miss with its shapes)
 # ---------------------------------------------------------------------------

@@ -525,7 +525,7 @@ def test_symbol_mode_trains():
 # eager-sync gating (MXNET_EAGER_SYNC)
 # ---------------------------------------------------------------------------
 
-def test_eager_sync_flag_gates_engine():
+def test_eager_sync_flag_gates_engine(tmp_path):
     from mxnet_tpu import engine
     assert not engine.eager_sync()  # default async
     config.set_flag("MXNET_EAGER_SYNC", True)
@@ -536,13 +536,17 @@ def test_eager_sync_flag_gates_engine():
     assert not engine.eager_sync()
     # profiler imperative domain forces sync while recording
     from mxnet_tpu import profiler
-    profiler.set_config(profile_imperative=True, aggregate_stats=False)
+    # the profile goes beside the configured file: keep it out of the
+    # checkout's root
+    profiler.set_config(filename=str(tmp_path / "profile.json"),
+                        profile_imperative=True, aggregate_stats=False)
     profiler.set_state("run")
     try:
         assert engine.eager_sync()
     finally:
         profiler.set_state("stop")
         profiler.reset()
+        profiler.set_config(filename="profile.json")
     assert not engine.eager_sync()
 
 
