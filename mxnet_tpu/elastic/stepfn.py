@@ -121,17 +121,13 @@ class ElasticStepFunction(StepFunction):
             _recompile.record_recompile(
                 f"ElasticStepFunction:{self._name}", sig,
                 kind="fused_step")
-            trainable = self._trainable
-            indices = self._indices
 
-            def pure_update(tvals, svals, grads, lrs, wds):
-                # the barrier pins the exchange/update boundary for
-                # the same bitwise-contraction reason as the fused
-                # one-program step
-                grads = jax.lax.optimization_barrier(grads)
-                return self._optimizer.fused_apply(
-                    indices, [tvals[n] for n in trainable],
-                    [grads[n] for n in trainable], svals, lrs, wds)
+            def pure_update(tvals, svals, grads, hyper):
+                # the one-program step's own update segment (its
+                # barrier pins the exchange/update boundary for the
+                # same bitwise-contraction reason); the exchange
+                # already happened on the host (_exchange is identity)
+                return self._apply(tvals, grads, svals, hyper)
 
             fn = jax.jit(pure_update,
                          donate_argnums=(0, 1) if self._donate else ())
@@ -378,7 +374,7 @@ class ElasticStepFunction(StepFunction):
 
             with _trace.span("step.prep", "train"):
                 grads_fn = self._grad_fn(inputs, guard)
-                lrs, wds = self._hyper()
+                hyper = self._hyper()
                 pvals, svals = self._gather()
                 from .. import random as _random
                 import jax.numpy as jnp
@@ -432,8 +428,7 @@ class ElasticStepFunction(StepFunction):
             with _trace.span("step.update", "train"):
                 update_fn = self._update_fn()
                 tvals = {n: pvals[n] for n in self._trainable}
-                new_w, new_s = update_fn(tvals, svals, reduced, lrs,
-                                         wds)
+                new_w, new_s = update_fn(tvals, svals, reduced, hyper)
                 new_params = dict(zip(self._trainable, new_w))
                 new_params.update(extras)
                 self._writeback(new_params, new_s)

@@ -128,9 +128,13 @@ class Optimizer:
         safe to call under a jit trace (the whole-train-step compiler)
         or eagerly (the aggregated update path). ``states`` entries are
         raw arrays / tuples of raw arrays / None, matching
-        ``create_state``'s structure. ``lrs``/``wds`` may be python
-        floats (eager) or weakly-typed f32 scalars (traced) — both
-        promote exactly like the eager per-param kernels."""
+        ``create_state``'s structure. ``lrs``/``wds`` are python floats
+        (eager), weakly-typed f32 scalars (the aggregated eager chunks)
+        or, under the fused step's trace, scalars of each weight's own
+        dtype sliced out of the step's one ``(2, leaves)`` array
+        (``step/stepfn.py _unpack_hyper``) — all promote exactly like
+        the eager per-param kernels as long as a kernel uses them only
+        as scalar x array of the weight's dtype."""
         raise NotImplementedError(
             f"{type(self).__name__} has no functional fused_apply; the "
             "fused step and aggregated update paths fall back to the "

@@ -90,10 +90,7 @@ def lint_shard_report(report: Dict[str, object]) -> List[Finding]:
     # full global batch; batch-axis collective counts can't catch it
     # since the ZeRO update emits batch-axis all-reduces regardless).
     if n_batch > 1:
-        try:
-            input_shardings = report["input_shardings"][0][4]
-        except (KeyError, IndexError, TypeError):
-            input_shardings = None
+        input_shardings = report.get("data_shardings")
         if input_shardings is not None:
             for i, got in enumerate(_leaf_list(input_shardings)):
                 if getattr(got, "is_fully_replicated", False):

@@ -172,12 +172,11 @@ class ShardedStepFunction(StepFunction):
         pspec = self._pspec_tree(pvals)
         sspec = self._sspec_tree(svals)
         rep = plan.replicated()
-        lspec = tuple(rep for _ in self._indices)
+        # the per-step rates and weight decays: one replicated array.
         # data_spec as a pytree prefix: every input (x and labels)
         # shards its batch dim — THE data-parallel annotation; each
         # replica computes only its slice of the global batch
-        in_shardings = (pspec, sspec, lspec, lspec, plan.data_spec(),
-                        rep)
+        in_shardings = (pspec, sspec, rep, plan.data_spec(), rep)
         # loss sharding unconstrained: per-sample losses stay sharded
         # by batch through propagation, scalar losses replicate. The
         # mxguard fingerprint output is REPLICATED: its gradient
@@ -263,8 +262,11 @@ class ShardedStepFunction(StepFunction):
         A persistent-cache hit when the step already ran."""
         compiled = self.compiled(x, *labels)
         pvals, svals = self._gather()
+        # pure_step(pvals, svals, hyper, inputs, rng): the batch is the
+        # fourth argument, and only here is that known
         return {"hlo": compiled.as_text(),
                 "input_shardings": compiled.input_shardings,
+                "data_shardings": compiled.input_shardings[0][3],
                 "output_shardings": compiled.output_shardings,
                 "mesh": self._plan.mesh,
                 "plan": self._plan,

@@ -20,7 +20,9 @@ one dispatch per step instead of O(params):
   :meth:`~mxnet_tpu.optimizer.Optimizer.fused_apply` kernels. Per-step
   scalars (lr, wd, Adam bias correction) are computed on the host in
   float64 — the exact arithmetic of the eager per-param loop — and
-  passed as weakly-typed f32 scalars so schedulers never retrace;
+  passed as ONE host ``float32`` array of shape ``(2, leaves)`` (no
+  device program per scalar; its shape never changes, so schedulers
+  never retrace), unpacked inside the trace into each leaf's dtype;
 - weight and optimizer-state buffers **donated** to XLA (buffer
   reuse); the post-step write-back rebinds the gluon Parameters and
   the Updater states in place, so checkpoints, kvstore updaters and
@@ -52,6 +54,7 @@ from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as onp
 
 from .. import optimizer as opt_mod
 from ..base import MXNetError
@@ -64,6 +67,19 @@ __all__ = ["StepFunction"]
 
 def _raw(a):
     return a._data if isinstance(a, NDArray) else jnp.asarray(a)
+
+
+def _unpack_hyper(hyper, weights):
+    """The ``(2, leaves)`` array of :meth:`StepFunction._hyper`, inside
+    the trace, as the two lists ``fused_apply`` takes. Each leaf's pair
+    is cast to that leaf's dtype: a scalar sliced out of an f32 array is
+    strongly typed, and ``lr * g`` with a bf16 ``g`` would come out f32
+    where the eager loop's python float keeps it bf16 — the cast makes
+    the product the one the eager kernels compute, bit for bit (they
+    use ``lr``/``wd`` only as scalar x array of the weight's dtype)."""
+    lrs = [hyper[0, k].astype(w.dtype) for k, w in enumerate(weights)]
+    wds = [hyper[1, k].astype(w.dtype) for k, w in enumerate(weights)]
+    return lrs, wds
 
 
 class StepFunction:
@@ -307,18 +323,20 @@ class StepFunction:
             return jax.tree.map(
                 lambda g: jax.lax.psum(g, self._psum_axis), grads)
 
-    def _apply(self, trainable_vals, grads, svals, lrs, wds):
+    def _apply(self, trainable_vals, grads, svals, hyper):
         """The in-jit update segment: exchange + fused multi-tensor
-        optimizer. The barrier pins the gradient/update boundary so
-        XLA's producer-consumer fusion cannot clone gradient
-        expressions into the update kernels with different FMA
-        contraction — the bitwise-parity contract with the eager loop
-        (whose per-param kernels jit the same expression DAG)."""
+        optimizer (``hyper``: :meth:`_hyper`'s array). The barrier pins
+        the gradient/update boundary so XLA's producer-consumer fusion
+        cannot clone gradient expressions into the update kernels with
+        different FMA contraction — the bitwise-parity contract with
+        the eager loop (whose per-param kernels jit the same expression
+        DAG)."""
         grads = jax.lax.optimization_barrier(grads)
         grads = self._exchange(grads)
+        weights = [trainable_vals[n] for n in self._trainable]
+        lrs, wds = _unpack_hyper(hyper, weights)
         return self._optimizer.fused_apply(
-            self._indices,
-            [trainable_vals[n] for n in self._trainable],
+            self._indices, weights,
             [grads[n] for n in self._trainable], svals, lrs, wds)
 
     def _build_grads(self, taps=False):
@@ -428,13 +446,12 @@ class StepFunction:
         grads_fn = self._build_grads(taps=guard)
         trainable = self._trainable
 
-        def pure_step(pvals, svals, lrs, wds, inputs, rng):
+        def pure_step(pvals, svals, hyper, inputs, rng):
             out = grads_fn(pvals, inputs, rng)
             grads, extras, lout = out[:3]
             tvals = {n: pvals[n] for n in trainable}
             with jax.named_scope("optimizer"):
-                new_w, new_s = self._apply(tvals, grads, svals, lrs,
-                                           wds)
+                new_w, new_s = self._apply(tvals, grads, svals, hyper)
             new_params = dict(pvals)
             new_params.update(zip(trainable, new_w))
             new_params.update(extras)
@@ -464,15 +481,16 @@ class StepFunction:
 
     def _hyper(self):
         """Per-step scalar hyperparameters, host-computed (float64 —
-        the eager loop's arithmetic), shipped as weakly-typed f32
-        scalars so value changes (schedulers, Adam's t) never
+        the eager loop's arithmetic, advancing the update counts), as
+        ONE host ``float32`` array ``(2, leaves)``: row 0 the rates,
+        row 1 the weight decays. jit moves it in one transfer; a device
+        scalar a leaf would be a program a leaf. Shape and dtype never
+        change, so value changes (schedulers, Adam's t) never
         retrace."""
-        lrs, wds = [], []
-        for i in self._indices:
-            lr, wd = self._optimizer.fused_hyper(i)
-            lrs.append(jnp.asarray(lr))
-            wds.append(jnp.asarray(wd))
-        return tuple(lrs), tuple(wds)
+        hyper = onp.zeros((2, len(self._indices)), onp.float32)
+        for k, i in enumerate(self._indices):
+            hyper[:, k] = self._optimizer.fused_hyper(i)
+        return hyper
 
     def _gather(self):
         if self._symbol_mode:
@@ -594,8 +612,8 @@ class StepFunction:
 
             with _trace.span("step.prep", "train"):
                 with _trace.span("step.prep.hyper", "train",
-                                 scalars=2 * len(self._indices)):
-                    lrs, wds = self._hyper()
+                                 leaves=len(self._indices)):
+                    hyper = self._hyper()
                 with _trace.span("step.prep.gather", "train") as sp:
                     pvals, svals = self._gather()
                     if sp.sampled:
@@ -607,7 +625,7 @@ class StepFunction:
             t1 = time.perf_counter()
             with _trace.span("step.dispatch", "train",
                              batch=batch_size):
-                out = fn(pvals, svals, lrs, wds, inputs, rng)
+                out = fn(pvals, svals, hyper, inputs, rng)
             new_params, new_states, loss = out[:3]
             t2 = time.perf_counter()
             with _trace.span("step.writeback", "train"):
@@ -814,11 +832,10 @@ class StepFunction:
         # business (the sharded step moves it onto its mesh)
         inputs = tuple(jax.ShapeDtypeStruct(v.shape, v.dtype)
                        for v in map(_raw, (x,) + labels))
-        lrs = tuple(jnp.asarray(0.0) for _ in self._indices)
-        wds = tuple(jnp.asarray(0.0) for _ in self._indices)
+        hyper = onp.zeros((2, len(self._indices)), onp.float32)
         pvals, svals = self._gather()
         rng = jax.random.key_data(jax.random.key(0))
-        return fn.lower(pvals, svals, lrs, wds, inputs, rng).compile()
+        return fn.lower(pvals, svals, hyper, inputs, rng).compile()
 
     def cost_analysis(self, x, *labels):
         """XLA cost analysis of the compiled step (bench roofline,

@@ -59,3 +59,26 @@ def _seed():
     _amp.TARGET_DTYPE_OPS.update(_saved_target)
     _amp.FP32_OPS.clear()
     _amp.FP32_OPS.update(_saved_fp32)
+
+
+@pytest.fixture
+def host_array_calls(monkeypatch):
+    """``host_array_calls(fn)`` runs ``fn()`` and returns how often it
+    called ``jnp.asarray`` / ``jnp.array`` / ``jax.device_put``: each a
+    device array made on the host path (a program or a transfer of its
+    own on the chip). The fused-step tests hold that count independent
+    of the number of trainable leaves."""
+    import jax.numpy as jnp
+
+    def count(fn):
+        calls = []
+        with monkeypatch.context() as m:
+            for mod, name in ((jnp, "asarray"), (jnp, "array"),
+                              (jax, "device_put")):
+                real = getattr(mod, name)
+                m.setattr(mod, name, lambda *a, _r=real, _n=name, **k:
+                          (calls.append(_n), _r(*a, **k))[1])
+            fn()
+        return len(calls)
+
+    return count

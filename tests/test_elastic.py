@@ -672,6 +672,50 @@ def test_inprocess_kill_and_rejoin_drill():
     assert rep["final_loss"] is not None
 
 
+def test_elastic_step_takes_its_rates_as_one_host_array():
+    """The split-phase step shares the one-program step's ``_hyper()``
+    and update segment: one host f32 ``(2, leaves)`` array into the
+    update program, bitwise the plain fused step at a world of one,
+    in f32 and with bf16 parameters and momentum."""
+    import mxnet_tpu as mx
+    from mxnet_tpu import gluon, nd
+    from mxnet_tpu.elastic import ElasticKVStore
+    from mxnet_tpu.elastic.stepfn import ElasticStepFunction
+
+    for dtype in ("float32", "bfloat16"):
+        x = nd.array(onp.linspace(-1, 1, 24).reshape(4, 6)
+                     .astype("float32")).astype(dtype)
+        y = nd.array(onp.ones((4, 3), "float32")).astype(dtype)
+        steps = {}
+        for kind in ("plain", "elastic"):
+            mx.random.seed(11)
+            net = gluon.nn.Dense(3, in_units=6)
+            net.initialize()
+            net.cast(dtype)
+            kv = {}
+            if kind == "elastic":
+                kv = {"kvstore": ElasticKVStore(
+                    group=_coordinator(FakeClock()), worker_id="a"),
+                    "update_on_kvstore": False}
+            trainer = gluon.Trainer(
+                net.collect_params(), "sgd",
+                {"learning_rate": 0.1, "momentum": 0.9, "wd": 0.01}, **kv)
+            fused = trainer.fuse_step(net, gluon.loss.L2Loss())
+            for _ in range(3):
+                fused.step(x, y)
+            steps[kind] = (net, fused)
+        fused = steps["elastic"][1]
+        assert isinstance(fused, ElasticStepFunction)
+        hyper = fused._hyper()
+        assert type(hyper) is onp.ndarray
+        assert hyper.shape == (2, 2) and hyper.dtype == onp.float32
+        for a, b in zip(steps["plain"][0].collect_params().values(),
+                        steps["elastic"][0].collect_params().values()):
+            assert str(b.data().dtype) == dtype
+            assert onp.array_equal(a.data().asnumpy(),
+                                   b.data().asnumpy())
+
+
 def test_trainer_eager_path_absorbs_membership_change():
     """Zero-user-code contract on the EAGER path: a gluon Trainer over
     an ElasticKVStore keeps training straight through a peer's leave —

@@ -597,8 +597,9 @@ def test_fused_step_tree_node_parent_and_cpu(warm_step_spans, name):
 
 def test_fused_step_prep_children_count_their_work(warm_step_spans):
     by_name = {s["name"]: s for s in warm_step_spans}
-    # a rate and a weight decay for each of the 4 trainable leaves
-    assert by_name["step.prep.hyper"]["attrs"]["scalars"] == 8
+    # a rate and a weight decay for each of the 4 trainable leaves, in
+    # one host array
+    assert by_name["step.prep.hyper"]["attrs"]["leaves"] == 4
     assert by_name["step.prep.gather"]["attrs"]["leaves"] >= 4
     prep = by_name["step.prep"]
     inside = sum(by_name[n]["dur_us"] for n in STEP_TREE

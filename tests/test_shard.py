@@ -209,6 +209,39 @@ def test_zero_steady_state_recompiles():
     assert len(fused._cache) == 2
 
 
+def test_sharded_step_takes_its_rates_as_one_replicated_array(
+        host_array_calls):
+    """The per-step rates and weight decays enter the sharded program
+    as ONE replicated f32 ``(2, leaves)`` array made on the host, and a
+    net with twice the leaves makes no more device arrays on the host
+    path of a warm step."""
+    x, y = _data()
+    made = []
+    for layers in (2, 4):
+        net = nn.HybridSequential(prefix=f"deep{layers}_")
+        with net.name_scope():
+            for _ in range(layers):
+                net.add(nn.Dense(8, flatten=False))
+        net.initialize(mx.initializer.Xavier())
+        net(x)
+        tr = _trainer(net, "adam", {"learning_rate": 0.01, "wd": 0.1})
+        fused = tr.fuse_step(net, gluon.loss.L2Loss(),
+                             shard_plan=ShardPlan())
+        fused.step(x, y)
+        fused.step(x, y)
+        made.append(host_array_calls(lambda: fused.step(x, y)))
+        hyper = fused._hyper()
+        assert type(hyper) is onp.ndarray
+        assert hyper.shape == (2, 2 * layers)
+        assert hyper.dtype == onp.float32
+    # the two batch inputs are placed on the mesh, nothing a leaf
+    assert made[1] <= made[0] <= 4
+    args = fused.compiled(x, y).input_shardings[0]
+    assert len(args) == 5
+    assert args[2].is_fully_replicated
+    assert len(args[2].device_set) == fused.plan.n_devices
+
+
 # ---------------------------------------------------------------------------
 # DP x TP composition
 # ---------------------------------------------------------------------------
@@ -332,7 +365,8 @@ def test_shardlint_clean_on_good_step():
                for ci in infos)
     # ... and the data inputs really compiled batch-sharded (the
     # data-parallel annotation itself, not just its collectives)
-    for got in report["input_shardings"][0][4]:
+    assert len(report["data_shardings"]) == 2  # x and y
+    for got in report["data_shardings"]:
         assert not got.is_fully_replicated, got
 
 
@@ -373,10 +407,8 @@ def test_shardlint_catches_replicated_data_input():
     fused.step(x, y)
     report = dict(fused.shard_report(x, y))
     rep = fused.plan.replicated()
-    args = list(report["input_shardings"][0])
-    args[4] = tuple(rep for _ in args[4])
-    report["input_shardings"] = (tuple(args),
-                                 report["input_shardings"][1])
+    report["data_shardings"] = tuple(
+        rep for _ in report["data_shardings"])
     findings = lint_shard_report(report)
     assert any(f.check == "data-input-replicated"
                and f.severity == "error" for f in findings), findings

@@ -72,30 +72,16 @@ def _state_leaves(updater):
 # bitwise parity: fused step vs eager per-param loop
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("opt_name,opt_kwargs", [
-    ("sgd", {"learning_rate": 0.05, "momentum": 0.9, "wd": 0.01}),
-    ("sgd", {"learning_rate": 0.05}),
-    ("adam", {"learning_rate": 0.01, "wd": 0.001}),
-    ("adamw", {"learning_rate": 0.01, "wd": 0.01}),
-    ("rmsprop", {"learning_rate": 0.01}),
-    ("nag", {"learning_rate": 0.05, "momentum": 0.9}),
-])
-def test_fused_step_bitwise_equals_eager(opt_name, opt_kwargs):
-    """The acceptance contract: >=3 steps, params AND optimizer state
-    bitwise-equal between the fused step and the eager loop."""
-    x, y = _data()
+def _parity_run(tr_a, tr_b, net_a, net_b, x, y, steps=4):
+    """Eager loop on ``net_a`` against the fused step on ``net_b``:
+    losses, parameters and optimizer state bitwise equal after every
+    step, dtypes unchanged. Returns the fused step."""
     loss_fn = gluon.loss.L2Loss()
-    net_a, net_b = _make_net(), _make_net()
-    net_a(x), net_b(x)
-    _clone_into(net_a, net_b)
-    tr_a = gluon.Trainer(net_a.collect_params(), opt_name,
-                         dict(opt_kwargs))
-    tr_b = gluon.Trainer(net_b.collect_params(), opt_name,
-                         dict(opt_kwargs))
     fused = tr_b.fuse_step(net_b, loss_fn)
     pa = net_a._collect_params_with_prefix()
     pb = net_b._collect_params_with_prefix()
-    for step in range(4):
+    dtypes = {k: p.data().dtype for k, p in pb.items()}
+    for step in range(steps):
         with autograd.record():
             loss_a = loss_fn(net_a(x), y)
         loss_a.backward()
@@ -104,13 +90,81 @@ def test_fused_step_bitwise_equals_eager(opt_name, opt_kwargs):
         assert onp.array_equal(loss_a.asnumpy(), loss_b.asnumpy()), \
             f"loss diverged at step {step}"
         for k in pa:
+            assert pb[k].data().dtype == dtypes[k], k
             assert onp.array_equal(pa[k].data().asnumpy(),
                                    pb[k].data().asnumpy()), \
                 f"param {k} diverged at step {step}"
-    for sa, sb in zip(_state_leaves(tr_a._updaters[0]),
-                      _state_leaves(tr_b._updaters[0])):
+    leaves_a = _state_leaves(tr_a._updaters[0])
+    leaves_b = _state_leaves(tr_b._updaters[0])
+    assert len(leaves_a) == len(leaves_b) > 0
+    for sa, sb in zip(leaves_a, leaves_b):
         for a, b in zip(sa, sb):
+            assert a.dtype == b.dtype
             assert onp.array_equal(a, b), "optimizer state diverged"
+    return fused
+
+
+def _net_pair(dtype):
+    """Two nets with the same weights, cast to ``dtype``, and a batch
+    of that dtype."""
+    x, y = _data()
+    net_a, net_b = _make_net(), _make_net()
+    net_a(x), net_b(x)
+    _clone_into(net_a, net_b)
+    net_a.cast(dtype), net_b.cast(dtype)
+    return net_a, net_b, x.astype(dtype), y.astype(dtype)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("opt_name,opt_kwargs", [
+    ("sgd", {"learning_rate": 0.05, "momentum": 0.9, "wd": 0.01}),
+    ("sgd", {"learning_rate": 0.05}),
+    ("adam", {"learning_rate": 0.01, "wd": 0.001}),
+    ("adamw", {"learning_rate": 0.01, "wd": 0.01}),
+    ("rmsprop", {"learning_rate": 0.01}),
+    ("nag", {"learning_rate": 0.05, "momentum": 0.9}),
+])
+def test_fused_step_bitwise_equals_eager(opt_name, opt_kwargs, dtype):
+    """The acceptance contract: >=3 steps, params AND optimizer state
+    bitwise-equal between the fused step and the eager loop. In
+    bfloat16 (the ResNet cell's policy: parameters, gradients and state)
+    it holds because each leaf's rate and weight decay, which reach the
+    trace in one f32 array, are cast to the leaf's dtype: ``lr * g``
+    then rounds as the eager kernels' weak python float does, and the
+    state keeps its dtype (an f32 scalar would promote it)."""
+    net_a, net_b, x, y = _net_pair(dtype)
+    tr_a = gluon.Trainer(net_a.collect_params(), opt_name,
+                         dict(opt_kwargs))
+    tr_b = gluon.Trainer(net_b.collect_params(), opt_name,
+                         dict(opt_kwargs))
+    _parity_run(tr_a, tr_b, net_a, net_b, x, y)
+    for leaves in _state_leaves(tr_b._updaters[0]):
+        assert all(str(v.dtype) == dtype for v in leaves)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_fused_step_scheduler_and_multipliers_bitwise_no_retrace(dtype):
+    """A rate that changes every step and per-parameter lr_mult /
+    wd_mult: every leaf's own pair reaches its update (bitwise equal to
+    the eager loop), and the values never retrace."""
+    net_a, net_b, x, y = _net_pair(dtype)
+    trainers = []
+    for net in (net_a, net_b):
+        for k, (_, p) in enumerate(
+                sorted(net._collect_params_with_prefix().items())):
+            p.lr_mult = 1.0 + 0.37 * k
+            p.wd_mult = 0.5 * k
+        sched = mx.lr_scheduler.FactorScheduler(step=1, factor=0.83)
+        trainers.append(gluon.Trainer(
+            net.collect_params(), "sgd",
+            {"learning_rate": 0.07, "momentum": 0.9, "wd": 0.013,
+             "lr_scheduler": sched}))
+    misses0 = telemetry.metrics.counter(
+        "fused_step_cache_misses_total").value()
+    fused = _parity_run(*trainers, net_a, net_b, x, y)
+    assert fused.cache_info()["misses"] == misses0 + 1
+    rates = fused._hyper()[0]
+    assert len(set(rates.tolist())) == len(rates)  # a rate a leaf
 
 
 def test_fused_step_standalone_optimizer():
@@ -178,6 +232,102 @@ def test_fused_step_scalar_changes_do_not_recompile():
     tr.set_learning_rate(0.002)
     fused.step(x, y)
     assert fused.cache_info()["misses"] == misses0
+
+
+# ---------------------------------------------------------------------------
+# the per-step rates: one host array, no device array a leaf
+# ---------------------------------------------------------------------------
+
+def _deep_net(layers):
+    net = nn.HybridSequential()
+    with net.name_scope():
+        for _ in range(layers):
+            net.add(nn.Dense(8, flatten=False))
+    net.initialize(mx.initializer.Xavier())
+    return net
+
+
+def test_hyper_is_one_host_array():
+    x, y = _data()
+    net = _make_net()
+    net(x)
+    tr = gluon.Trainer(net.collect_params(), "adam",
+                       {"learning_rate": 0.01, "wd": 0.25})
+    fused = tr.fuse_step(net, gluon.loss.L2Loss())
+    fused.step(x, y)
+    counts = dict(fused._optimizer._index_update_count)
+    hyper = fused._hyper()
+    assert type(hyper) is onp.ndarray
+    assert hyper.shape == (2, 4) and hyper.dtype == onp.float32
+    # it still advances every leaf's update count, as the eager loop
+    for i in fused._indices:
+        assert fused._optimizer._index_update_count[i] == counts[i] + 1
+    # row 0 the rates (Adam's bias correction folded in, so not the
+    # base rate), row 1 the weight decays
+    assert onp.all(hyper[0] != onp.float32(0.01)) and onp.all(hyper[0] > 0)
+    assert onp.array_equal(hyper[1], onp.full(4, 0.25, onp.float32))
+
+
+def test_warm_step_makes_no_device_array_a_leaf(host_array_calls):
+    """Twice the trainable leaves, the same host path: nothing in
+    step() makes a device array (a program, on the chip) a leaf."""
+    x, y = _data(out=8)
+    made = []
+    for layers in (2, 4):
+        net = _deep_net(layers)
+        net(x)
+        tr = gluon.Trainer(net.collect_params(), "sgd",
+                           {"learning_rate": 0.05, "momentum": 0.9})
+        fused = tr.fuse_step(net, gluon.loss.L2Loss())
+        fused.step(x, y)
+        fused.step(x, y)
+        made.append(host_array_calls(lambda: fused.step(x, y)))
+        assert len(fused._indices) == 2 * layers
+    assert made[1] <= made[0] <= 2
+
+
+def test_compiled_lowers_the_signature_that_ran(tmp_path):
+    """``compiled()`` hands out the program that ran: with a
+    persistent cache on, lowering it compiles no second program, and
+    its arguments are the step's five with ONE array for the rates
+    (subprocess: jax's cache configuration is process-global)."""
+    cache_dir = str(tmp_path / "xla_cache")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    code = (
+        "import jax, numpy as onp\n"
+        "import mxnet_tpu as mx\n"
+        "from mxnet_tpu import gluon, nd\n"
+        "from mxnet_tpu.gluon import nn\n"
+        "from mxnet_tpu.step.cache import enable_compile_cache\n"
+        "from mxnet_tpu.telemetry import metrics\n"
+        "assert enable_compile_cache(%r, min_compile_time_secs=0.0)\n"
+        "net = nn.HybridSequential()\n"
+        "net.add(nn.Dense(16, activation='relu'), nn.Dense(4))\n"
+        "net.initialize()\n"
+        "x, y = nd.ones((8, 10)), nd.ones((8, 4))\n"
+        "net(x)\n"
+        "tr = gluon.Trainer(net.collect_params(), 'sgd',\n"
+        "                   {'learning_rate': 0.05, 'momentum': 0.9})\n"
+        "fused = tr.fuse_step(net, gluon.loss.L2Loss())\n"
+        # twice: a step's outputs are committed to their device, which
+        # fresh parameters are not, and jax keys a program on that
+        "fused.step(x, y).asnumpy()\n"
+        "fused.step(x, y).asnumpy()\n"
+        "miss = metrics.counter('jax_compile_cache_misses_total')\n"
+        "m0 = miss.value()\n"
+        "assert m0 >= 1\n"
+        "c = fused.compiled(x, y)\n"
+        "assert miss.value() == m0, (m0, miss.value())\n"
+        "args = c.input_shardings[0]\n"
+        "assert len(args) == 5\n"
+        "assert len(jax.tree.leaves(args[2])) == 1\n"
+        "hyper = c.in_avals[0][2]\n"
+        "assert hyper.shape == (2, 4) and str(hyper.dtype) == 'float32'\n"
+        % cache_dir)
+    r = subprocess.run([sys.executable, "-c", code], env=env, cwd=ROOT,
+                       capture_output=True, text=True, timeout=300)
+    assert r.returncode == 0, r.stderr[-1500:]
 
 
 # ---------------------------------------------------------------------------
