@@ -116,3 +116,49 @@ def test_serve2_scan_decode_and_prefill_compile(one_chip):
         # the donated pools are updated in place: the program's outputs
         # alias them instead of being allocated beside them
         assert prog.memory_analysis().alias_size_in_bytes >= pool_bytes
+
+
+@pytest.mark.parametrize("heads,window", [(64, 512), (48, None)],
+                         ids=["sliding-64-heads", "full-48-heads"])
+def test_banded_attention_compiles_as_a_kernel(one_chip, heads, window):
+    """The Laguna cell's two attention shapes (8192 tokens, 8 key/value
+    heads of 128): the splash kernel, forward and backward, not the XLA
+    composition."""
+    from mxnet_tpu.ops.banded_attention import banded_attention
+
+    def loss(q, k, v):
+        return banded_attention(q, k, v, window=window, backend="splash") \
+            .astype(jnp.float32).sum()
+
+    q, k, v = _shapes(one_chip, ((1, heads, 8192, 128), jnp.bfloat16),
+                      ((1, 8, 8192, 128), jnp.bfloat16),
+                      ((1, 8, 8192, 128), jnp.bfloat16))
+    text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, k, v).compile() \
+        .as_text()
+    assert text.count("tpu_custom_call") >= 2
+
+
+def test_routed_experts_compile_without_a_dense_product(one_chip):
+    """The Laguna cell's expert layer (8192 tokens, 8 of 256 experts a
+    token, 32 held): the grouped products are kernels over the sorted
+    rows, and no product of rows x experts x width is in the program."""
+    from mxnet_tpu.parallel.moe import routed_experts
+
+    def loss(x, r, g, u, d):
+        return routed_experts(x, r, g, u, d, k=8, held_start=0,
+                              num_held=32, scale=2.5) \
+            .astype(jnp.float32).sum()
+
+    args = _shapes(one_chip, ((8192, 2048), jnp.bfloat16),
+                   ((256, 2048), jnp.float32),
+                   ((32, 2048, 512), jnp.bfloat16),
+                   ((32, 2048, 512), jnp.bfloat16),
+                   ((32, 512, 2048), jnp.bfloat16))
+    compiled = jax.jit(jax.grad(loss, (0, 1, 2, 3, 4))).lower(*args) \
+        .compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    # the whole worst-case buffer (65536 rows) through the three
+    # products, forward and backward, is 9 x 2 x 65536 x 2048 x 512;
+    # every row through every held expert would be 32 times that
+    cost = compiled.cost_analysis()
+    assert cost["flops"] < 2 * 9 * 2 * 65536 * 2048 * 512
