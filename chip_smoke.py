@@ -367,53 +367,30 @@ def phase_resnet50(cfg, ctx, device, seed):
 
 
 def phase_bert(cfg, ctx, device, seed, on_tpu):
-    from mxnet_tpu import config, operator_tune
+    from mxnet_tpu import telemetry
 
     net, loss_fn, tokens, labels = build_bert(cfg, ctx, seed)
     t0 = time.perf_counter()
-    net(tokens[:1]).wait_to_read()  # deferred shapes; attention tuned
+    net(tokens[:1]).wait_to_read()  # deferred shapes
     init_s = time.perf_counter() - t0
-    tuned = tuner_summary()
-    attention = dict(tuned=next(iter(tuned["wins"].get("attention", {})),
-                                None), pinned=False)
-    if on_tpu:
-        flash_failed = [k for k in tuned["failed"]
-                        if k.startswith("attention[flash]")]
-        check(not flash_failed, "the Pallas flash candidate failed on "
-              "BERT's shape: " + "; ".join(
-                  operator_tune.candidate_failures()[k][:800]
-                  for k in flash_failed))
-        check(attention["tuned"] is not None,
-              "operator_tune measured no attention candidate")
-        if attention["tuned"] != "flash":
-            # the smoke proves the kernel: where the tuner's batch-1
-            # measurement prefers the dense composition, pin the kernel
-            # with the documented override, and say so
-            config.set_flag("MXNET_OPTUNE_CHOICE_ATTENTION", "flash")
-            attention["pinned"] = True
 
     def after_compile(fused):
         text = fused.compiled(tokens, labels).as_text()
         n_calls = text.count("tpu_custom_call")
         check(n_calls > 0 or not on_tpu,
               "no tpu_custom_call in BERT's compiled step: the dense "
-              "composition ran, not the Pallas flash kernel")
+              "composition ran, not the fused attention kernel")
         return {"tpu_custom_calls_in_step_hlo": n_calls}
 
-    try:
-        out = train_phase(cfg, device, net, loss_fn, tokens,
-                          labels, "adam", {"learning_rate": 1e-4},
-                          "float32", seed, batch_coupled=False,
-                          after_compile=after_compile)
-    finally:
-        config.unset_flag("MXNET_OPTUNE_CHOICE_ATTENTION")
+    out = train_phase(cfg, device, net, loss_fn, tokens, labels, "adam",
+                      {"learning_rate": 1e-4}, "float32", seed,
+                      batch_coupled=False, after_compile=after_compile)
+    traced = {label: telemetry.counter(
+        f"attention_traced_total.{label}").value()
+        for label in ("kernel", "dense")}
     return dict(model="BERTModel", batch=cfg["batch"], seq=cfg["seq"],
                 dtype="float32", init_forward_seconds=round(init_s, 2),
-                attention=attention,
-                attention_costs={
-                    k.split("|")[0]: v
-                    for k, v in operator_tune.cost_table().items()
-                    if k.startswith("attention[")}, **out)
+                attention_traced=traced, **out)
 
 
 def phase_serve2(cfg, device, seed, on_tpu):

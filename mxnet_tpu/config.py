@@ -305,7 +305,10 @@ register_flag(
 register_flag(
     "MXNET_USE_OPERATOR_TUNING", str, "1",
     "Measure-and-cache selection between equivalent op implementations "
-    "(Pallas flash vs dense attention, ...; operator_tune.autotune — "
+    "(conv NCHW vs NHWC layout, RNN scan vs unrolled, int8 vs f32 "
+    "dispatch; operator_tune.autotune — attention is not among them: "
+    "kernel or dense is a rule on the call's shape, ops.pallas_kernels."
+    "flash_attention_available — "
     "the TPU reinterpretation of the reference's OMP tuning, "
     "operator_tune.h:165). 0/false/off = always take the default "
     "candidate; any other value (1, float32, ... — the reference's "
@@ -314,9 +317,10 @@ register_flag(
     "MXNET_OPTUNE_CHOICE_<NAME>", str, "",
     "Wildcard override: pin a tuned choice by candidate label, "
     "trumping measurement and cache — e.g. "
-    "MXNET_OPTUNE_CHOICE_ATTENTION=dense forces XLA dense attention "
-    "over the Pallas flash kernel (operator_tune.choose). An unknown "
-    "label raises, listing the candidates.")
+    "MXNET_OPTUNE_CHOICE_CONV_LAYOUT=nchw keeps every convolution in "
+    "the direct layout (operator_tune.choose). An unknown label "
+    "raises, listing the candidates. No attention site reads one: "
+    "MXNET_OPTUNE_CHOICE_ATTENTION is gone with the measurement.")
 register_flag(
     "MXNET_GRAPH_OPT", int, 0,
     "Graph-optimizer level for Symbol binds (mxnet_tpu/opt/, "
@@ -338,7 +342,8 @@ register_flag(
 register_flag(
     "MXNET_GRAPH_OPT_PALLAS", bool, True,
     "Allow Pallas kernel lowerings for fused patterns (_fused_"
-    "attention flash kernel, the fused optimizer+cast mp_sgd step). "
+    "attention's kernel where flash_attention_available takes the "
+    "shape, the fused optimizer+cast mp_sgd step). "
     "Only takes effect on a TPU backend; everywhere else — and when "
     "set to 0 — the automatic XLA fallback composition runs "
     "(bitwise-identical to the unfused graph).")

@@ -25,7 +25,8 @@ import numpy as onp
 from ..gluon import nn
 from ..gluon.block import HybridBlock
 from ..ndarray.ndarray import NDArray, invoke
-from ..ops.pallas_kernels import flash_attention_available as _fa_available
+from ..ops.pallas_kernels import (count_traced, flash_attention,
+                                  flash_attention_available)
 from ..parallel.ring_attention import local_attention
 from ..parallel.mesh import P
 
@@ -36,6 +37,17 @@ __all__ = ["MultiHeadAttention", "TransformerEncoderLayer", "TransformerLM",
 def _on_tpu() -> bool:
     import jax
     return any(d.platform == "tpu" for d in jax.devices())
+
+
+def _in_scope(name, fn):
+    """``fn`` under ``jax.named_scope(name)`` wherever it is traced: the
+    forward, and the tape's replay for the backward pass."""
+    import jax
+
+    def scoped(*args):
+        with jax.named_scope(name):
+            return fn(*args)
+    return scoped
 
 
 class MultiHeadAttention(HybridBlock):
@@ -85,24 +97,16 @@ class MultiHeadAttention(HybridBlock):
                          seq_axis=self._cp_axis, causal=causal,
                          strategy=self._cp_strategy,
                          block_size=getattr(self, "_cp_block_size", None))
-        elif _on_tpu() and _fa_available(T, T, self._head_dim):
-            # two valid backends on TPU: the Pallas flash kernel (O(T)
-            # memory) and XLA dense attention. Which is faster depends
-            # on T/D/dtype — measured once on the eager warm-up forward
-            # (operator_tune cache), flash as the default under a trace
-            from .. import operator_tune as _otune
-            from ..ops.pallas_kernels import flash_attention
-            _, fn = _otune.choose(
-                "attention",
-                [("flash", partial(flash_attention, causal=causal)),
-                 ("dense", partial(local_attention, causal=causal))],
-                q, k, v,
-                key=(f"attention|T={T}|D={self._head_dim}"
-                     f"|H={self._num_heads}|causal={causal}"
-                     f"|{getattr(q, 'dtype', '?')}"))
         else:
-            fn = partial(local_attention, causal=causal)
-        out = invoke(fn, [q, k, v])  # (B, H, T, D)
+            # one rule on what this call can see (T, D, dtype, whether
+            # GSPMD partitions the trace): the fused kernel where it
+            # beats the dense composition, dense elsewhere
+            kernel = _on_tpu() and flash_attention_available(
+                T, T, self._head_dim, x.dtype)
+            count_traced("kernel" if kernel else "dense")
+            fn = partial(flash_attention if kernel else local_attention,
+                         causal=causal)
+        out = invoke(_in_scope("products", fn), [q, k, v])  # (B, H, T, D)
         out = out.transpose((0, 2, 1, 3)).reshape((B, T, C))
         return self.drop(self.proj(out))
 

@@ -7,8 +7,11 @@ op's serial cost at startup to decide per-op OMP parallelization
 *within* a compiled program (tiling, fusion, layout of intermediates),
 so the TPU reinterpretation tunes the one thing XLA cannot: the choice
 BETWEEN semantically-equal implementations the framework itself offers —
-e.g. direct-layout vs transpose-to-NHWC convolution, Pallas flash vs
-dense XLA attention. `autotune` times the candidates on the real device
+e.g. direct-layout vs transpose-to-NHWC convolution, scan vs unrolled
+RNN cells. (Attention is not tuned here: kernel or dense is a rule on
+the call's shape, ops/pallas_kernels.flash_attention_available; a
+forward-only timing of batch 1 would flip between two close candidates
+from run to run.) `autotune` times the candidates on the real device
 once per (op, shape/dtype signature), caches the winner in-process and
 on disk (MXNET_HOME/op_tune.json), and honors the reference's modes:
   auto   use cached winners, measure on first sight   (kAuto)

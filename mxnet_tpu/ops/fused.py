@@ -10,11 +10,11 @@ them; the level-2 pipeline partitions matched patterns into them:
   over the whole region (the explicit partitioning "Operator Fusion in
   XLA" shows XLA won't always discover on its own);
 - ``_fused_attention`` — softmax(QKᵀ·scale)·V collapsed from its
-  4-node graph spelling; lowers to the Pallas flash-attention kernel
-  (MXU-tiled, O(T) memory) when the backend supports it and falls back
-  to the exact op-by-op composition of the unfused graph otherwise —
-  same functions, so the fallback is bitwise-identical to the graph it
-  replaced;
+  4-node graph spelling; lowers to the Pallas attention kernel (no
+  T x T array in HBM) where ``flash_attention_available`` says so and
+  falls back to the exact op-by-op composition of the unfused graph
+  otherwise — same functions, so the fallback is bitwise-identical to
+  the graph it replaced;
 - ``_nhwc_conv``       — Convolution evaluated in NHWC with the weight
   kept in the frozen OIHW parameter layout (transposed in-kernel; XLA
   folds it). Emitted by the layout-selection pass inside NHWC regions.
@@ -88,9 +88,11 @@ def fused_group(*inputs, graph="", pattern="", num_outputs=1,
     return tuple(outs)  # n_out=-1 contract: always a tuple
 
 
-def pallas_attention_active(q_len: int, k_len: int, head_dim: int) -> bool:
-    """True when ``_fused_attention`` will lower to the Pallas flash
-    kernel: a TPU backend is present, the shapes tile, and the
+def pallas_attention_active(q_len: int, k_len: int, head_dim: int,
+                            dtype=jnp.float32) -> bool:
+    """True when ``_fused_attention`` will lower to the Pallas attention
+    kernel: a TPU backend is present, the kernel's rule takes the shape
+    and dtype (``flash_attention_available``), and the
     MXNET_GRAPH_OPT_PALLAS escape hatch is on (default). Everything
     else takes the XLA fallback — the bitwise op-by-op composition."""
     from ..base import get_env
@@ -99,19 +101,23 @@ def pallas_attention_active(q_len: int, k_len: int, head_dim: int) -> bool:
         return False
     if not any(d.platform == "tpu" for d in jax.devices()):
         return False
-    return flash_attention_available(q_len, k_len, head_dim)
+    return flash_attention_available(q_len, k_len, head_dim, dtype)
 
 
 @register_op("_fused_attention", input_names=("q", "k", "v"))
 def fused_attention(q, k, v, scale=1.0, causal=False):
     """Fused scaled-dot-product attention over (B, H, T, D) operands.
 
-    Pallas flash kernel on TPU (tolerance class "fusion": online
-    softmax reorders the contraction), exact unfused composition
-    everywhere else (bitwise with the graph it replaced — the same
-    registered softmax/batch_dot functions run in the same order)."""
-    if pallas_attention_active(q.shape[-2], k.shape[-2], q.shape[-1]):
-        from .pallas_kernels import flash_attention
+    Pallas attention kernel on TPU where its rule takes the call
+    (tolerance class "fusion": the softmax is normalised after the
+    weighted sum), exact unfused composition everywhere else (bitwise
+    with the graph it replaced — the same registered softmax/batch_dot
+    functions run in the same order)."""
+    from .pallas_kernels import count_traced, flash_attention
+    kernel = pallas_attention_active(q.shape[-2], k.shape[-2], q.shape[-1],
+                                     q.dtype)
+    count_traced("kernel" if kernel else "dense")
+    if kernel:
         return flash_attention(q, k, v, causal=causal, scale=float(scale))
     # XLA fallback: literally the ops the fusion pass collapsed
     from .nn import softmax as _softmax

@@ -149,7 +149,7 @@ def _run_layer(x, h0, c0, wi, wh, bi, bh, mode, reverse=False):
     step) and full unrolling (T inlined bodies; slower compile, lets
     XLA fuse/pipeline across steps — often faster for short T). The
     winner is measured-and-cached per (mode, T, B, H) signature by
-    operator_tune, the same machinery that picks the attention backend
+    operator_tune, the same machinery that picks a convolution's layout
     (ref role: operator_tune.h's measured-cost corpus tuning)."""
     H = wh.shape[1]
     gin_x = jnp.einsum("tbi,gi->tbg", x, wi) + bi + (

@@ -47,11 +47,11 @@ def pallas_kernels_active() -> bool:
     return any(d.platform == "tpu" for d in jax.devices())
 
 
-def fused_attention_available(q_len: int, k_len: int,
-                              head_dim: int) -> bool:
-    """Will ``_fused_attention`` lower to the flash kernel here?"""
+def fused_attention_available(q_len: int, k_len: int, head_dim: int,
+                              dtype=jnp.float32) -> bool:
+    """Will ``_fused_attention`` lower to the attention kernel here?"""
     from ..ops.fused import pallas_attention_active
-    return pallas_attention_active(q_len, k_len, head_dim)
+    return pallas_attention_active(q_len, k_len, head_dim, dtype)
 
 
 # ---------------------------------------------------------------------------

@@ -68,6 +68,12 @@ def test_flash_attention_forward_and_backward_compile(one_chip, shape,
         text = jax.jit(fn).lower(*qkv).compile().as_text()
         assert "tpu_custom_call" in text, \
             "the dense composition was compiled, not the Pallas kernel"
+        # no T x T array outside the kernels: scores and probabilities
+        # never reach HBM, forward or backward
+        assert f",{shape[2]},{shape[2]}]" not in text
+        if dtype == jnp.float32:
+            # nor a whole-array rounding of an operand or a result
+            assert "bf16[" not in text
 
 
 @pytest.mark.parametrize("shape", [

@@ -1,0 +1,152 @@
+#!/usr/bin/env python3
+"""Kernel against dense attention on the attached chip, forward plus
+backward: the measurement behind ``flash_attention_available``'s rule.
+
+    python tools/attention_table.py [--bh 192] [--block 1] [--causal 1]
+
+One JSON line a case: ``{"t", "d", "dtype", "causal", "kernel_ms",
+"dense_ms"}`` (a side that does not fit the device reads null), the
+attention alone or, with ``--block 1``, inside one block of a model
+(projection, heads, attention, output projection: the difference of the
+two sides is attention's, laid out as a step lays it out); for
+``--check 1`` both sides' gaps to dense at ``highest`` precision.
+Times are the device's own, from a profile of the calls; a CPU run
+refuses to start (its times would say nothing about the chip).
+"""
+import argparse
+import functools
+import glob
+import json
+import os
+import shutil
+import sys
+import tempfile
+
+import jax
+import jax.numpy as jnp
+
+sys.path.insert(0, __file__.rsplit("/", 2)[0])
+from mxnet_tpu.ops.pallas_kernels import flash_attention  # noqa: E402
+from mxnet_tpu.parallel.ring_attention import local_attention  # noqa: E402
+
+
+def time_ms(fn, args, reps):
+    """Device time of one call in ms: the program's runs on the chip's
+    ``XLA Modules`` line of a profile over ``reps`` calls (a host clock
+    around calls this short reads the dispatch, 0.6 ms, not the chip)."""
+    trace_dir = tempfile.mkdtemp(prefix="attention_table_")
+    try:
+        step = jax.jit(fn)
+        for _ in range(2):
+            jax.block_until_ready(step(*args))
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = options.host_tracer_level = 0
+        jax.profiler.start_trace(trace_dir, profiler_options=options)
+        try:
+            for _ in range(reps):
+                out = step(*args)
+            jax.block_until_ready(out)
+        finally:
+            jax.profiler.stop_trace()
+        found = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                       "*.xplane.pb"))
+        profile = jax.profiler.ProfileData.from_file(found[0])
+        ns = sum(e.duration_ns for plane in profile.planes
+                 if plane.name.startswith("/device:TPU:0")
+                 for line in plane.lines if line.name == "XLA Modules"
+                 for e in line.events)
+        return ns / reps / 1e6
+    except Exception as e:  # does not fit, or the compiler refuses it
+        print(f"# {type(e).__name__}: {str(e)[:200]}", file=sys.stderr)
+        return None
+    finally:
+        shutil.rmtree(trace_dir, ignore_errors=True)
+
+
+def fwd_bwd(fn):
+    """``fn``'s result and its gradients for the cotangent given last."""
+    def run(*args):
+        out, vjp = jax.vjp(fn, *args[:-1])
+        return (out,) + vjp(args[-1])
+    return run
+
+
+def in_block(attn, heads, d):
+    """``attn`` where a model has it: between the q/k/v projection and
+    the output projection of one block, so that XLA lays the heads out
+    as it does inside a step (a 64-wide head is kept with T minor
+    there, which the kernel takes as it is)."""
+    def block(x, w, wo):
+        b, t, c = x.shape
+        qkv = (x @ w).reshape(b, t, 3, heads, d).transpose(2, 0, 3, 1, 4)
+        out = attn(qkv[0], qkv[1], qkv[2])
+        return out.transpose(0, 2, 1, 3).reshape(b, t, c) @ wo
+    return block
+
+
+def block_operands(bh, t, d, dtype):
+    c = 12 * d
+    keys = jax.random.split(jax.random.key(t * 1000 + d), 4)
+    shapes = ((bh // 12, t, c), (c, 3 * c), (c, c), (bh // 12, t, c))
+    return [(jax.random.normal(key, shape, jnp.float32)
+             * (1.0 if i in (0, 3) else c ** -0.5)).astype(dtype)
+            for i, (key, shape) in enumerate(zip(keys, shapes))]
+
+
+def operands(bh, t, d, dtype):
+    keys = jax.random.split(jax.random.key(t * 1000 + d), 4)
+    return [jax.random.normal(key, (bh // 12, 12, t, d), jnp.float32)
+            .astype(dtype) for key in keys]
+
+
+def errors(bh, causal):
+    """Kernel and dense against dense at ``highest`` precision, BERT's
+    head shape in float32: the largest gap of the result and of each
+    gradient over the reference's largest entry."""
+    ops = operands(bh, 512, 64, jnp.float32)
+    sides = {"kernel": lambda q, k, v: flash_attention(q, k, v, causal),
+             "dense": lambda q, k, v: local_attention(q, k, v,
+                                                      causal=causal)}
+    with jax.default_matmul_precision("highest"):
+        ref = jax.jit(fwd_bwd(sides["dense"]))(*ops)
+    for name, attn in sides.items():
+        got = jax.jit(fwd_bwd(attn))(*ops)
+        gaps = [float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
+                for a, b in zip(got, ref)]
+        print(json.dumps({"side": name, "gap_o_dq_dk_dv": gaps}),
+              flush=True)
+
+
+def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--bh", type=int, default=192)
+    ap.add_argument("--reps", type=int, default=10)
+    ap.add_argument("--causal", type=int, default=0)
+    ap.add_argument("--check", type=int, default=0)
+    ap.add_argument("--block", type=int, default=0)
+    args = ap.parse_args()
+    if jax.devices()[0].platform != "tpu":
+        sys.exit("attention_table.py measures a chip; none is attached")
+    causal = bool(args.causal)
+    if args.check:
+        return errors(args.bh, causal)
+    for d in (64, 128):
+        for t in (128, 256, 384, 512, 1024, 2048):
+            for dtype in (jnp.float32, jnp.bfloat16):
+                row = {"t": t, "d": d, "dtype": dtype.__name__,
+                       "causal": causal, "in_block": bool(args.block)}
+                make = block_operands if args.block else operands
+                ops = make(args.bh, t, d, dtype)
+                sides = {
+                    "kernel_ms": functools.partial(flash_attention,
+                                                   causal=causal),
+                    "dense_ms": functools.partial(local_attention,
+                                                  causal=causal)}
+                for side, attn in sides.items():
+                    fn = in_block(attn, 12, d) if args.block else attn
+                    row[side] = time_ms(fwd_bwd(fn), ops, args.reps)
+                print(json.dumps(row), flush=True)
+
+
+if __name__ == "__main__":
+    main()

@@ -67,8 +67,8 @@ def bench_transformer():
         B, T, L, U, H, V = 2, 128, 2, 64, 128, 512
         steps = 3
 
-    # attention backend (Pallas flash vs XLA dense) is chosen by
-    # operator_tune at warm-up; bench_flash times the kernel directly
+    # attention backend (Pallas kernel vs XLA dense) is a rule on the
+    # call's shape; bench_flash times the kernel directly
     # eager work (init, deferred-shape forward) on the host; the
     # extracted params move to the device once below
     cpu_dev = jax.local_devices(backend="cpu")[0]
