@@ -129,7 +129,7 @@ def mean_loss(loss):
 
 
 def bf16_policy(net):
-    """bench.py's bf16 policy: parameters (and so activations) in bf16,
+    """The bf16 policy: parameters (and so activations) in bf16,
     BatchNorm scale/shift/statistics in f32."""
     keep = ("gamma", "beta", "running_mean", "running_var",
             "moving_mean", "moving_var")

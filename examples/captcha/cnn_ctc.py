@@ -21,7 +21,7 @@ if "--tpu" not in sys.argv:
 
 import numpy as onp
 
-from mxnet_tpu import autograd, gluon, nd
+from mxnet_tpu import gluon, nd
 
 N_DIGIT, N_CLS, G = 4, 5, 8  # digits per strip, classes, glyph size
 
@@ -74,17 +74,16 @@ def main(argv=None):
     trainer = gluon.Trainer(net.collect_params(), "adam",
                             {"learning_rate": 2e-3})
 
+    # one compiled program a step (forward, CTC, backward, Adam). Run
+    # op by op, every step compiles the CTC's scan again: a `lax.scan`
+    # outside a jit is a new program each call. CTCLoss takes the
+    # (B, T, C) logits with the blank at 0
+    fused = trainer.fuse_step(net, gluon.loss.CTCLoss(layout="NTC"))
+
     first = last = None
     for step in range(args.steps):
         x, y = make_batch(rs, glyphs, args.batch)
-        with autograd.record():
-            logits = net(x)                  # (B, T=W/1, N_CLS+1)
-            # CTCLoss wants (T, B, C) alphabet with blank at 0
-            loss = nd.CTCLoss(logits.transpose((1, 0, 2)), y)
-            mean_loss = nd.mean(loss)
-        mean_loss.backward()
-        trainer.step(args.batch)
-        val = float(mean_loss.asscalar())
+        val = float(nd.mean(fused.step(x, y)).asscalar())
         if first is None:
             first = val
         last = val

@@ -42,15 +42,19 @@ def main(argv=None):
                     **({"thumbnail": True}
                        if args.model.startswith("resnet") else {}))
     net.initialize(mx.initializer.Xavier())
+    S = args.image_size
     if args.hybridize:
         net.hybridize()
+        # resolve the deferred shapes outside record(): the first call
+        # runs op by op, and under record() so would its backward pass,
+        # one small program an operator and shape
+        net(nd.zeros((args.batch_size, 3, S, S)))
     trainer = gluon.Trainer(net.collect_params(), "sgd",
                             {"learning_rate": args.lr, "momentum": 0.9})
     loss_fn = gluon.loss.SoftmaxCrossEntropyLoss()
     metric = mx.metric.Accuracy()
 
     rs = onp.random.RandomState(0)
-    S = args.image_size
     # synthetic but learnable: class k brightens a k-dependent stripe
     def batch():
         x = rs.rand(args.batch_size, 3, S, S).astype("float32") * 0.3

@@ -61,9 +61,23 @@ class RPNDemo(gluon.HybridBlock):
             self.rpn_bbox = gluon.nn.Conv2D(4 * N_ANCHOR, 1)
             self.head = gluon.nn.Dense(N_CLS)
 
-    def hybrid_forward(self, F, x):
+    def hybrid_forward(self, F, x, im_info):
+        """Objectness scores of every feature cell, and class logits of
+        the four regions `Proposal` keeps of each image (rows 4*i ..
+        4*i+3 belong to image i)."""
         feat = self.backbone(x)
-        return feat, self.rpn_cls(feat), self.rpn_bbox(feat)
+        rpn_cls, rpn_bbox = self.rpn_cls(feat), self.rpn_bbox(feat)
+        scores = rpn_cls.reshape((0, 2, -1)).transpose((0, 2, 1))
+        # decode proposals from the RPN outputs and pool
+        cls_prob = F.softmax(rpn_cls.reshape((0, 2, FEAT, FEAT)), axis=1)
+        rois = F.Proposal(
+            cls_prob, rpn_bbox, im_info, feature_stride=4,
+            scales=(2,), ratios=(1.0,), rpn_pre_nms_top_n=16,
+            rpn_post_nms_top_n=4, threshold=0.7, rpn_min_size=4)
+        pooled = F.ROIPooling(feat, rois, pooled_size=(4, 4),
+                              spatial_scale=0.25)
+        logits = self.head(pooled.reshape((-1, 8 * 4 * 4)))
+        return scores.reshape((-1, 2)), logits
 
 
 def main(argv=None):
@@ -76,36 +90,24 @@ def main(argv=None):
     rs = onp.random.RandomState(0)
     net = RPNDemo()
     net.initialize()
+    # one program for the forward pass and one for its backward: run op
+    # by op, `Proposal` and `ROIPooling` (a vmap over regions, a Python
+    # loop over pooling bins) are traced and compiled again every step
+    net.hybridize()
     sce = gluon.loss.SoftmaxCrossEntropyLoss()
     trainer = gluon.Trainer(net.collect_params(), "adam",
                             {"learning_rate": 2e-3})
+    im_info = nd.array(onp.tile([S, S, 1.0], (args.batch, 1))
+                       .astype("float32"))
 
     first = last = None
     for step in range(args.steps):
         x, obj, cls = make_batch(rs, args.batch)
         with autograd.record():
-            feat, rpn_cls, rpn_bbox = net(x)
-            B = x.shape[0]
-            # objectness loss over feature cells
-            scores = rpn_cls.reshape((B, 2, -1)).transpose((0, 2, 1))
-            rpn_loss = sce(scores.reshape((-1, 2)), obj.reshape((-1,)))
-
-            # decode proposals from the (fixed) RPN outputs and pool
-            cls_prob = nd.softmax(rpn_cls.reshape((B, 2, FEAT, FEAT)),
-                                  axis=1)
-            im_info = nd.array(onp.tile([S, S, 1.0], (B, 1))
-                               .astype("float32"))
-            rois = nd.Proposal(
-                cls_prob, rpn_bbox, im_info, feature_stride=4,
-                scales=(2,), ratios=(1.0,), rpn_pre_nms_top_n=16,
-                rpn_post_nms_top_n=4, threshold=0.7, rpn_min_size=4)
-            pooled = nd.ROIPooling(feat, rois, pooled_size=(4, 4),
-                                   spatial_scale=0.25)
-            # regions of image i are rows 4*i..4*i+3; classify each
-            logits = net.head(pooled.reshape((B * 4, -1)))
-            region_cls = nd.repeat(cls, repeats=4)
-            cls_loss = sce(logits, region_cls)
-
+            scores, logits = net(x, im_info)
+            # objectness loss over feature cells, class loss over regions
+            rpn_loss = sce(scores, obj.reshape((-1,)))
+            cls_loss = sce(logits, nd.repeat(cls, repeats=4))
             loss = rpn_loss.mean() + cls_loss.mean()
         loss.backward()
         trainer.step(args.batch)

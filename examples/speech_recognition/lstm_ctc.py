@@ -20,7 +20,7 @@ if "--tpu" not in sys.argv:
 
 import numpy as onp
 
-from mxnet_tpu import autograd, gluon, nd
+from mxnet_tpu import gluon, nd
 
 N_PHONE, FRAMES_PER, N_IN, T_LABEL = 6, 3, 12, 4
 
@@ -63,15 +63,15 @@ def main(argv=None):
     trainer = gluon.Trainer(net.collect_params(), "adam",
                             {"learning_rate": 3e-3})
 
+    # one compiled program a step (forward, CTC, backward, Adam). Run
+    # op by op, every step compiles the LSTM's and the CTC's scans
+    # again: a `lax.scan` outside a jit is a new program each call
+    fused = trainer.fuse_step(net, gluon.loss.CTCLoss(layout="NTC"))
+
     first = last = None
     for step in range(args.steps):
         x, y = make_batch(rs, templates, args.batch)
-        with autograd.record():
-            logits = net(x)
-            loss = nd.mean(nd.CTCLoss(logits.transpose((1, 0, 2)), y))
-        loss.backward()
-        trainer.step(args.batch)
-        val = float(loss.asscalar())
+        val = float(nd.mean(fused.step(x, y)).asscalar())
         if first is None:
             first = val
         last = val

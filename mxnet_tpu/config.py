@@ -288,7 +288,7 @@ register_flag(
 register_flag(
     "MXNET_METRICS_EXPORT", str, "",
     "Path of the JSON-lines metrics sink; when set, gluon Trainer.step "
-    "and bench.py append one metrics snapshot line per step "
+    "appends one metrics snapshot line per step "
     "(telemetry.record_step). Empty = export off.")
 register_flag(
     "MXNET_TELEMETRY_MEMORY_INTERVAL", float, 0.0,
@@ -547,8 +547,8 @@ register_flag(
     "Declared relative tolerance for the elastic loss-trajectory "
     "contract: the final loss of a kill/rejoin drill must match the "
     "uninterrupted run within this fraction (tools/mxresil.py "
-    "elastic, bench.py --elastic). The rescaled-batch/LR accounting "
-    "exists to keep runs inside it.")
+    "elastic). The rescaled-batch/LR accounting exists to keep runs "
+    "inside it.")
 register_flag(
     "MXPIPE_SCHEDULE", str, "1f1b",
     "Microbatch schedule for pipelined training (mxnet_tpu/pipe/"
@@ -726,8 +726,8 @@ register_flag(
     "flight-recorder capture over the heartbeat channel. Same "
     "discipline as MXTRACE: structurally zero-cost when off (one "
     "generation-keyed flag-cache read on the hot path, no wire "
-    "fields, no collector state), <2% when on (bench.py "
-    "--obs-overhead enforces), never touches jit cache keys.")
+    "fields, no collector state; tests/test_obs.py), its cost when on "
+    "not measured on a chip, never touches jit cache keys.")
 register_flag(
     "MXOBS_PUSH_INTERVAL_S", float, 2.0,
     "Seconds between a host's metrics-snapshot pushes to the rank-0 "
@@ -742,15 +742,6 @@ register_flag(
     "merged metrics plus per-rank sections. Empty = export off "
     "(merged snapshots still queryable via obs_merged / "
     "tools/diagnose.py).")
-register_flag(
-    "MXOBS_BENCHSTORE", str, "",
-    "Benchstore path override (tools/benchstore.py): the append-only "
-    "JSONL perf-trajectory DB every bench.py metric line lands in, "
-    "keyed by (metric, host fingerprint, mesh, git rev); `mxprof "
-    "regress` gates the newest run against the stored trajectory "
-    "with median/MAD fences. Empty = tools/benchstore.jsonl; "
-    "'0'/'off' = appends disabled (MXTPU_BENCH_STORE=0 is the "
-    "bench-side escape hatch).")
 register_flag(
     "MXFLEET_HEARTBEAT_S", float, 1.0,
     "Seconds between a fleet engine worker's directory heartbeats to "
@@ -826,8 +817,8 @@ register_flag(
     "registry publishes mxsan_lock_* instruments), and a flight-"
     "recorder dump when a waiter blocks past MXSAN_BLOCK_THRESHOLD_MS."
     " Off (default) = the factories return plain threading primitives:"
-    " zero wrappers, zero overhead, no recompiles (bench.py "
-    "--san-overhead enforces). Read at LOCK CONSTRUCTION time — set "
+    " zero wrappers, zero overhead, no recompiles (tests/test_mxsan.py"
+    " holds the identity). Read at LOCK CONSTRUCTION time — set "
     "it before building engines/groups (module-level locks capture it "
     "at import).")
 register_flag(
@@ -869,7 +860,7 @@ register_flag(
 register_flag(
     "MXTUNE_BUDGET", int, 16,
     "Default measurement budget (trials) for tune.run_search and "
-    "`python tools/mxtune.py search` / `bench.py --tune` when no "
+    "`python tools/mxtune.py search` when no "
     "explicit budget is passed. Trial 0 always measures the DEFAULTS "
     "config, so the best entry is never worse than stock; the "
     "learned cost model starts pruning once ~len(space)+2 legal "

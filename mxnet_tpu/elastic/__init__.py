@@ -28,8 +28,7 @@ job *adapt*:
   and an update program whose ``rescale_grad`` re-keys **exactly once**
   per world-size change.
 - :mod:`~mxnet_tpu.elastic.drill` — the deterministic in-process
-  kill/rejoin drill harness behind ``tools/mxresil.py elastic`` and
-  ``bench.py --elastic``.
+  kill/rejoin drill harness behind ``tools/mxresil.py elastic``.
 
 Flags: ``MXELASTIC_HEARTBEAT_S`` / ``MXELASTIC_MISS_LIMIT`` /
 ``MXELASTIC_MIN_WORLD`` / ``MXELASTIC_LR_SCALE`` /

@@ -20,8 +20,7 @@ worker through the group state-sync, and reports:
 
 Faults are scripted, never timed: ``elastic.worker.<rank>:K=kill``
 fires at step K of that worker exactly. Shared by
-``tools/mxresil.py elastic``, ``bench.py --elastic`` and the tier-1
-integration test.
+``tools/mxresil.py elastic`` and the tier-1 integration test.
 """
 from __future__ import annotations
 

@@ -22,7 +22,7 @@ directory), and a FleetController, then drives templated load through
 - ``mode="baseline"`` — no fault, same load (the comparison run).
 
 Faults are request-count scripted, never timed.  Shared by
-tests/test_fleet_drill.py (@slow, 3 modes) and ``bench.py --fleet``.
+tests/test_fleet_drill.py (@slow, 3 modes).
 """
 from __future__ import annotations
 
@@ -99,8 +99,8 @@ def _free_port() -> int:
 
 
 class FleetHarness:
-    """Coordinator + N workers + controller, reusable by the drill
-    and by ``bench.py --fleet``. The parent process plays the
+    """Coordinator + N workers + controller, as the drill runs
+    them. The parent process plays the
     controller host (binds the KVServer carrying the fleet
     directory)."""
 

@@ -10,7 +10,7 @@ the controller's RemoteEngine can hand the Router the exact error
 semantics it already understands.
 
 ``python -m mxnet_tpu.fleet.worker`` — spawned per host by
-fleet/drill.py and ``bench.py --fleet``.  Each process builds the
+fleet/drill.py.  Each process builds the
 SAME seeded pipeline-LM params as its siblings (env-seeded, so every
 decode replica serves the identical model), warms the engine
 (including the pagewire chunk programs), starts an EngineHost,
@@ -106,6 +106,9 @@ class EngineHost:
             try:
                 conn, _ = self._listener.accept()
             except OSError:
+                return
+            if self._stopping:
+                conn.close()
                 return
             t = threading.Thread(target=self._serve, args=(conn,),
                                  daemon=True)
@@ -209,7 +212,16 @@ class EngineHost:
         return out
 
     def stop(self):
+        """Refuse connections from here on. close() alone does not wake
+        a thread blocked in accept() on Linux (the stopped host would
+        serve one more connection); shutdown() does, and the loop ends
+        before close() frees the port."""
         self._stopping = True
+        try:
+            self._listener.shutdown(socket.SHUT_RDWR)
+        except OSError:
+            pass
+        self._accept_thread.join(timeout=5.0)
         try:
             self._listener.close()
         except OSError:

@@ -28,10 +28,10 @@ surfaces):
   after an EWMA anomaly verdict (:mod:`~mxnet_tpu.guard.anomaly`,
   riding the resil Watchdog's probe registry).
 
-``bench.py --guard`` drives the whole arc: a one-element gradient
+tests/test_guard.py drives the whole arc: a one-element gradient
 corruption on 1 of N workers is detected within one step, attributed,
-and quarantined, with taps measured at <3% step overhead and zero
-steady-state recompiles. ``passes/guardlint.py`` audits that gradient
+and quarantined, with zero steady-state recompiles (the taps' cost on
+a chip is not measured). ``passes/guardlint.py`` audits that gradient
 exchanges carry taps and that detection is paired with a recovery
 ring. Architecture: docs/resilience.md, integrity section.
 """

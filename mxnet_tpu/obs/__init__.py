@@ -20,10 +20,9 @@ processes. This package closes the gap between them:
   rank-named dump directory.
 
 Everything is behind ``MXOBS`` with the mxtrace cost discipline:
-structurally zero-cost off, <2% on (``bench.py --obs-overhead``),
-never touches jit cache keys. docs/observability.md has the multi-host
-section; ``tools/benchstore.py`` + ``mxprof regress`` are the
-perf-trajectory half of the plane.
+structurally zero-cost off (tests/test_obs.py), its cost when on not
+measured on a chip, never touches jit cache keys.
+docs/observability.md has the multi-host section.
 """
 from __future__ import annotations
 

@@ -22,8 +22,7 @@ via the executor path), :func:`opt_level`, :func:`build_manager`.
 Every pass rides the PassManager registry with an explicit ``order``
 key, emits Findings ``tools/mxlint.py --opt`` can render, and bumps
 per-pass rewrite counters + time-in-pass histograms in the telemetry
-registry (``tools/mxprof.py opt`` renders the report; ``bench.py
---graph-opt`` proves the win as an ``mxopt_speedup`` line).
+registry (``tools/mxprof.py opt`` renders the report).
 """
 from __future__ import annotations
 

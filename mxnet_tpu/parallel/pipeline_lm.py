@@ -319,9 +319,7 @@ def dense_lm_logits(params, tokens):
     stack — identical math to :func:`dense_lm_loss` without the loss.
     This is the serving oracle: mxnet_tpu/serve2's paged-KV continuous-
     batching decode must reproduce these logits (and their greedy argmax
-    trajectory) within the online-softmax tolerance class, and the PR-3
-    request/response baseline in ``bench.py --serving2`` decodes by
-    re-running this whole forward per generated token."""
+    trajectory) within the online-softmax tolerance class."""
     h = params["embed"][tokens]
 
     def body(hc, lp):

@@ -25,8 +25,8 @@ the mxpipe recovery contract:
   programs re-key once per stage-kind per topology — any extra
   compile fails the drill.
 
-Faults are scripted by step, never timed. Shared by tests/test_pipe.py
-(@slow) and ``bench.py --pipe`` reuses the worker for its socket leg.
+Faults are scripted by step, never timed. Run by tests/test_pipe.py
+(@slow).
 """
 from __future__ import annotations
 

@@ -1,9 +1,8 @@
-"""The mxpipe drill/bench worker (one HOST PROCESS = one-or-more
+"""The mxpipe drill worker (one HOST PROCESS = one-or-more
 pipeline STAGES).
 
 ``python -m mxnet_tpu.pipe.worker`` — spawned N times by the
-lost-stage drill harness (pipe/drill.py) and ``bench.py --pipe``
-(socket leg). Each process:
+lost-stage drill harness (pipe/drill.py). Each process:
 
 - bootstraps a :class:`~mxnet_tpu.pod.context.PodContext` from the
   ``MXPOD_*`` env (the pipe drill IS a pod: same coordinator, same

@@ -17,7 +17,7 @@ actually occur:
   dist_sync / horovod-compat surfaces ride on the CPU backend;
 - :func:`~mxnet_tpu.pod.drill.run_pod_drill` — subprocess N-host
   drills (SIGKILL a host, corrupt a host, kill the coordinator) shared
-  by ``tools/mxresil.py pod``, ``bench.py --pod`` and tests.
+  by ``tools/mxresil.py pod`` and tests.
 
 See docs/resilience.md, multi-host section.
 """

@@ -23,8 +23,8 @@ elastic drill (elastic/drill.py), plus the pod-only verdicts:
   — no orphaned workers, no silent wedge.
 
 Faults are scripted by step, never timed. Shared by
-``tools/mxresil.py pod``, ``bench.py --pod``, tests/test_pod.py (the
-subprocess drills are @slow) and the tier-1 smoke.
+``tools/mxresil.py pod``, tests/test_pod.py (the subprocess drills
+are @slow) and the tier-1 smoke.
 """
 from __future__ import annotations
 

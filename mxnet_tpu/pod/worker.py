@@ -1,8 +1,8 @@
-"""The mxpod drill/bench training worker (one HOST PROCESS).
+"""The mxpod drill training worker (one HOST PROCESS).
 
 ``python -m mxnet_tpu.pod.worker`` — spawned N times by the subprocess
-drill harness (pod/drill.py), ``tools/mxresil.py pod``, ``bench.py
---pod`` and the tier-1 smoke test. Each process:
+drill harness (pod/drill.py), ``tools/mxresil.py pod`` and the tier-1
+smoke test. Each process:
 
 - bootstraps a :class:`PodContext` from the ``MXPOD_*`` env,
 - trains the same seeded regression MLP as the in-process elastic

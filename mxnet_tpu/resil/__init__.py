@@ -23,8 +23,7 @@ its own. Four pillars, one package (ISSUE 4):
   last-heartbeat gauges), emitting findings in the shared mxlint
   ``--json`` schema.
 
-``tools/mxresil.py`` runs fault drills (MTTR / steps-lost reports) and
-``bench.py --chaos`` asserts throughput recovery after injected faults.
+``tools/mxresil.py`` runs fault drills (MTTR / steps-lost reports).
 Architecture: docs/resilience.md.
 """
 from __future__ import annotations

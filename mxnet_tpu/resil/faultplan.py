@@ -53,11 +53,11 @@ Actions:
   voting within the step), ``scale`` multiplies it by ``1 + 2^-10``
   (silent: below the vote threshold, found later by
   ``tools/mxresil.py replay``). The drill trigger for every mxguard
-  test and ``bench.py --guard``.
+  test.
 
 When ``MXRESIL_FAULT_PLAN`` is unset, :func:`inject` is a two-dict-read
 no-op — the hooks cost nothing in production and record zero retries
-(the ``bench.py --chaos`` baseline asserts exactly that).
+(tests/test_resilience.py holds exactly that).
 """
 from __future__ import annotations
 
@@ -247,7 +247,7 @@ class FaultPlan:
         calling worker THREAD: ``kill``/``preempt`` raise the typed
         :class:`WorkerKilled` / :class:`WorkerPreempted` instead of
         signaling the whole process — the in-process elastic drills
-        (``tools/mxresil.py elastic``, ``bench.py --elastic``) run N
+        (``tools/mxresil.py elastic``) run N
         workers in one process and must kill exactly one
         (``elastic.worker.<id>`` sites, docs/resilience.md)."""
         with self._lock:

@@ -27,8 +27,8 @@ inspectable policies:
   default: a typed transient error is an API contract, not a guess.
 
 Every retry/giveup/trip is counted in the telemetry metrics registry
-(``mxresil_*``) — ``bench.py --chaos`` asserts the baseline run records
-ZERO retries, so the wrappers are provably free when nothing fails.
+(``mxresil_*``) — tests/test_resilience.py holds that a clean run
+records ZERO retries, so the wrappers are free when nothing fails.
 """
 from __future__ import annotations
 

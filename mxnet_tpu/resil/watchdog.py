@@ -7,8 +7,8 @@ progressing" from signals that already exist (PR 2 metrics registry):
 
 - **heartbeats** — :meth:`Watchdog.beat` is called from step
   boundaries (TrainGuard) and keeps a step-time EWMA; with no explicit
-  caller it synthesizes beats from ``trainer_step_total`` /
-  ``bench_step_total`` counter progress via :meth:`poll`;
+  caller it synthesizes beats from ``trainer_step_total`` counter
+  progress via :meth:`poll`;
 - **stall detection** — no heartbeat for ``max(MXRESIL_WATCHDOG_STALL_S,
   stall_factor × EWMA)`` ⇒ an ``error`` finding;
 - **queue age** — ``mxserve_queue_depth > 0`` with no
@@ -47,7 +47,7 @@ __all__ = ["Watchdog", "host_liveness_probe"]
 _log = get_logger("mxnet_tpu.resil.watchdog")
 
 # counters whose progress counts as a training heartbeat in poll()
-_STEP_COUNTERS = ("trainer_step_total", "bench_step_total")
+_STEP_COUNTERS = ("trainer_step_total",)
 
 
 def host_liveness_probe(coordinator, dump: bool = True):

@@ -1,7 +1,7 @@
 """Closed- and open-loop load generators over a serving target.
 
-The one implementation behind ``tools/mxserve.py loadgen`` and
-``bench.py --serving/--serving2``: payloads fire at a ``fire(payload)``
+The implementation behind ``tools/mxserve.py loadgen``: payloads
+fire at a ``fire(payload)``
 callable (an in-process engine/router predict, or an HTTP POST), with
 per-request latency recorded. Two arrival disciplines:
 

@@ -32,13 +32,12 @@ on shared writes), **speculative decoding** (a small draft model
 proposes K tokens, :meth:`PagedLM.verify` checks them in ONE batched
 target forward with exact greedy acceptance), and **quantized KV
 pages** (``kv_dtype="int8"/"bf16"`` pools with per-slot dequant
-scales). ``MXSERVE3_*`` flags gate each leg; ``bench.py --serving3``
-measures them per leg.
+scales). ``MXSERVE3_*`` flags gate each leg.
 
 Non-autoregressive (CNN) models keep serving through
 :class:`~mxnet_tpu.serve.engine.ServingEngine`; the router mixes both
 behind one front door. ``tools/mxserve.py route|reload|loadgen --qps``
-are the CLIs; ``bench.py --serving2`` is the mixed-traffic benchmark;
+are the CLIs;
 ``passes/servelint.py`` lints the closed-cache/donation contract;
 docs/serving.md has the v2 architecture and runbook.
 """

@@ -13,7 +13,7 @@ the cache is placed from outside — jax reads that variable itself, and
 this module sets no cache configuration at all, it only listens. When
 it is not set, the library user's ``MXNET_COMPILE_CACHE_DIR`` flag
 (applied at import, config.py) or an entry point's fixed directory
-(``bench.py`` and ``chip_smoke.py`` pass ``<repo>/.jax_cache``) goes
+(``chip_smoke.py`` passes ``<repo>/.jax_cache``) goes
 through :func:`enable_compile_cache`. The directory is part of the
 cache key, so it is never built from a temp name, a pid or the time.
 

@@ -18,7 +18,7 @@ Three pillars (ISSUE 2), one package:
 
 The framework feeds it from its natural boundaries (ops/registry
 dispatch, HybridBlock/Executor compiles, Trainer.step, kvstore
-push/pull, bench.py); ``tools/mxprof.py`` renders the dumps.
+push/pull); ``tools/mxprof.py`` renders the dumps.
 
 The CORRELATED layer on top — per-request/per-step span trees threaded
 across subsystems, plus the crash flight recorder — lives in
@@ -50,8 +50,8 @@ __all__ = ["metrics", "memory", "recompile", "tracing", "counter", "gauge",
 
 
 def record_step(batch_size: int, seconds: float, prefix: str = "trainer"):
-    """The step-boundary hook: called by ``gluon.Trainer.step`` (and
-    bench.py) once per optimization step. Updates the step counters,
+    """The step-boundary hook: called by ``gluon.Trainer.step`` once
+    per optimization step. Updates the step counters,
     takes a throttled memory sample, and appends one JSON line to the
     ``MXNET_METRICS_EXPORT`` sink when configured."""
     metrics.counter(f"{prefix}_step_total", "optimization steps").inc()

@@ -6,7 +6,7 @@ numbers that decide whether a run is healthy (step time, recompiles,
 bytes in flight, kvstore latency) are cheap to count and expensive to
 reconstruct after the fact, so this module keeps one process-wide
 registry that the framework layers (gluon Trainer, kvstore, the
-recompile auditor, bench.py) feed at their natural boundaries.
+recompile auditor) feed at their natural boundaries.
 
 Two exporters:
 

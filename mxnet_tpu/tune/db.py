@@ -1,8 +1,8 @@
 """The persistent tuning DB: measured configs, keyed and provenanced.
 
 A JSONL file (one measurement record per line) following the
-crash-safety idiom of ``guard/replay.py``'s ring and
-``tools/benchstore.py``: every append is a single ``write + flush`` of
+crash-safety idiom of ``guard/replay.py``'s ring: every append is a
+single ``write + flush`` of
 one line (a kill mid-write leaves at most one torn tail line, which
 :meth:`TuneDB.records` skips), and when the file outgrows
 ``2 * capacity`` lines it is compacted **in place** via a tmp-file

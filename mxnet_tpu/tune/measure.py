@@ -139,8 +139,7 @@ def measure_candidate(space: KnobSpace, cfg: Dict[str, object],
 
 def _conv_loss_symbol(batch: int):
     """Small conv+bn+relu net under a regression head — the workload
-    whose level-2 fusion/layout rewrites carry a measurable win (same
-    family as bench.py --graph-opt's conv line)."""
+    whose level-2 fusion/layout rewrites change the program most."""
     from .. import sym
     n = sym.var("data")
     for i, nf in enumerate((16, 32)):

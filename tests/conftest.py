@@ -24,8 +24,13 @@ import jax  # noqa: E402
 # tests run on the CPU backend whatever the process environment says
 jax.config.update("jax_platforms", "cpu")
 
+import importlib.util  # noqa: E402
+
 import numpy as onp  # noqa: E402
 import pytest  # noqa: E402
+
+EXAMPLES = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "examples")
 
 
 def pytest_configure(config):
@@ -59,6 +64,22 @@ def _seed():
     _amp.TARGET_DTYPE_OPS.update(_saved_target)
     _amp.FP32_OPS.clear()
     _amp.FP32_OPS.update(_saved_fp32)
+
+
+@pytest.fixture
+def load_example():
+    """``load_example("gan/dcgan.py")`` imports one script of examples/
+    as a module of its own (the test_examples*.py files call its
+    ``main(argv)``)."""
+    def load(relpath):
+        path = os.path.join(EXAMPLES, relpath)
+        name = "ex_" + os.path.basename(relpath)[:-3]
+        spec = importlib.util.spec_from_file_location(name, path)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        return mod
+
+    return load
 
 
 @pytest.fixture

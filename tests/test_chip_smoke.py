@@ -68,8 +68,8 @@ def test_tiny_rehearsal_passes_and_names_the_platform_it_ran_on():
                          ids=["JAX_COMPILATION_CACHE_DIR", "unset"])
 def test_one_compile_cache_rule(tmp_path, placed_outside):
     """Placed from outside, no code sets jax_compilation_cache_dir —
-    not the library flag at import, not bench's set-up; otherwise the
-    entry points use <repo>/.jax_cache."""
+    not the library flag at import, not the entry point's set-up;
+    otherwise the entry point uses <repo>/.jax_cache."""
     outside = str(tmp_path / "outside")
     extra = {"MXNET_COMPILE_CACHE_DIR": str(tmp_path / "library_flag")}
     if placed_outside:
@@ -77,39 +77,21 @@ def test_one_compile_cache_rule(tmp_path, placed_outside):
     code = (
         "import sys, jax\n"
         f"sys.path.insert(0, {ROOT!r})\n"
-        "import mxnet_tpu, bench\n"
+        "import mxnet_tpu, chip_smoke\n"
+        "from mxnet_tpu.step.cache import enable_compile_cache\n"
         "print('IMPORT', jax.config.jax_compilation_cache_dir)\n"
-        "bench._enable_compile_cache()\n"
-        "print('BENCH', jax.config.jax_compilation_cache_dir)\n")
+        "enable_compile_cache(chip_smoke.CACHE_DIR)\n"
+        "print('ENTRY', jax.config.jax_compilation_cache_dir)\n")
     proc = subprocess.run([sys.executable, "-c", code], env=_env(**extra),
                           capture_output=True, text=True, timeout=300)
     assert proc.returncode == 0, proc.stderr[-1500:]
     seen = dict(ln.split(" ", 1) for ln in proc.stdout.splitlines()
-                if ln.startswith(("IMPORT ", "BENCH ")))
+                if ln.startswith(("IMPORT ", "ENTRY ")))
     if placed_outside:
-        assert seen == {"IMPORT": outside, "BENCH": outside}
+        assert seen == {"IMPORT": outside, "ENTRY": outside}
     else:
         assert seen == {"IMPORT": extra["MXNET_COMPILE_CACHE_DIR"],
-                        "BENCH": os.path.join(ROOT, ".jax_cache")}
-
-
-def test_bench_needs_an_accelerator_and_its_parent_stays_off_jax():
-    """No accelerator and no MXTPU_BENCH_FORCE_CPU=1: an error line and a
-    non-zero exit, not a CPU number. And a chip belongs to one process:
-    the parent that spawns --child must not have imported jax."""
-    bench = os.path.join(ROOT, "bench.py")
-    proc = subprocess.run([sys.executable, bench],
-                          env=_env(MXTPU_BENCH_STORE="0"),
-                          capture_output=True, text=True, timeout=300)
-    assert proc.returncode not in (0, None)
-    (line,) = _json_lines(proc.stdout)
-    assert line["value"] is None and "no accelerator" in line["error"]
-    code = (f"import sys; sys.path.insert(0, {ROOT!r}); import bench; "
-            "bench._selected_mode(); "
-            "assert 'jax' not in sys.modules, 'bench imported jax'")
-    proc = subprocess.run([sys.executable, "-c", code], env=_env(),
-                          capture_output=True, text=True, timeout=60)
-    assert proc.returncode == 0, proc.stderr[-800:]
+                        "ENTRY": os.path.join(ROOT, ".jax_cache")}
 
 
 @pytest.mark.parametrize("kind,device_id", [("tpu", 0), ("gpu", 0),

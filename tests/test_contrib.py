@@ -453,8 +453,8 @@ def test_quantized_conv_chain_one_jit():
     # float convolution may exist anywhere — i.e. the chain never
     # regressed to dequantize-then-conv-in-float. Operand-level s8
     # can't be asserted on CPU (the backend folds the s8->s32 convert
-    # into the operand fusions — it has no int8 conv kernels); on TPU
-    # the bench_suite int8-conv gate asserts the actual MXU speedup.
+    # into the operand fusions — it has no int8 conv kernels); the
+    # int8 product's speed on the TPU is not measured.
     import re
     assert re.search(r"=\s*s32\[[^\]]*\]\S*\s+convolution\(", hlo), \
         "no s32-accumulator convolution in compiled HLO"

@@ -1,6 +1,5 @@
 """Smoke tier for examples/ — every script must run end to end with
 tiny settings (ref: the reference CI's example runs)."""
-import importlib.util
 import os
 import subprocess
 import sys
@@ -11,54 +10,46 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 EX = os.path.join(ROOT, "examples")
 
 
-def _load(relpath):
-    path = os.path.join(EX, relpath)
-    name = os.path.basename(relpath)[:-3]
-    spec = importlib.util.spec_from_file_location(name, path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def test_train_mnist_example():
-    mod = _load("image_classification/train_mnist.py")
+def test_train_mnist_example(load_example):
+    mod = load_example("image_classification/train_mnist.py")
     score = mod.main(["--epochs", "2", "--num-examples", "320",
                       "--batch-size", "32"])
     assert score[0][0] == "accuracy" and 0.0 <= score[0][1] <= 1.0
 
 
-def test_train_gluon_example():
-    mod = _load("image_classification/train_gluon.py")
+def test_train_gluon_example(load_example):
+    mod = load_example("image_classification/train_gluon.py")
     acc = mod.main(["--model", "mobilenetv2_0.25", "--steps", "4",
-                    "--batch-size", "8", "--image-size", "32"])
+                    "--batch-size", "8", "--image-size", "32",
+                    "--hybridize"])
     assert 0.0 <= acc <= 1.0
 
 
-def test_word_lm_example_learns():
-    mod = _load("rnn/word_lm.py")
+def test_word_lm_example_learns(load_example):
+    mod = load_example("rnn/word_lm.py")
     ppl = mod.main(["--epochs", "2"])
     assert ppl < 15.0  # vocab 36; untrained ppl ~36
 
 
-def test_ssd_example_loss_decreases():
-    mod = _load("ssd/train_ssd.py")
+def test_ssd_example_loss_decreases(load_example):
+    mod = load_example("ssd/train_ssd.py")
     first, last, mean_ap = mod.main(["--steps", "12", "--batch-size",
                                      "4", "--image-size", "32"])
     assert last < first
     assert 0.0 <= mean_ap <= 1.0  # VOC07 mAP computed on the decode
 
 
-def test_quantization_example():
-    mod = _load("quantization/quantize_model.py")
+def test_quantization_example(load_example):
+    mod = load_example("quantization/quantize_model.py")
     err, agree = mod.main(["--calib-mode", "naive",
                            "--num-calib-batches", "2"])
     assert err < 0.15 and agree >= 0.75
 
 
-def test_transformer_lm_example_moe_mesh():
+def test_transformer_lm_example_moe_mesh(load_example):
     """The flagship example composes dp x tp x sp with MoE experts on
     the virtual mesh (conftest provides 8 CPU devices)."""
-    mod = _load("transformer/train_lm.py")
+    mod = load_example("transformer/train_lm.py")
     last = mod.main(["--dp", "2", "--tp", "2", "--sp", "2",
                      "--num-experts", "2", "--steps", "50"])
     assert last < 1.0

@@ -207,14 +207,14 @@ def test_estimator_fit_and_early_stopping(tmp_path):
     assert stopper.stopped_epoch is not None and stopper.stopped_epoch < 50
 
 
-def test_model_zoo_inception_and_mobilenetv2_variants():
+@pytest.mark.parametrize("name,size,classes", [
+    ("inceptionv3", 299, 13), ("mobilenetv2_0.75", 224, 7),
+    ("mobilenetv2_0.25", 224, 7)])
+def test_model_zoo_inception_and_mobilenetv2_variants(name, size,
+                                                      classes):
     from mxnet_tpu.gluon.model_zoo.vision import get_model
-    net = get_model("inceptionv3", classes=13)
+    net = get_model(name, classes=classes)
     net.initialize()
     out = net(nd.array(onp.random.RandomState(0)
-                       .rand(1, 3, 299, 299).astype("float32")))
-    assert out.shape == (1, 13)
-    for name in ("mobilenetv2_0.75", "mobilenetv2_0.25"):
-        m = get_model(name, classes=7)
-        m.initialize()
-        assert m(nd.zeros((1, 3, 224, 224))).shape == (1, 7)
+                       .rand(1, 3, size, size).astype("float32")))
+    assert out.shape == (1, classes)

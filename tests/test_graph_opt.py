@@ -7,7 +7,7 @@ tolerance class (bitwise for level 1, tolerance-tagged for level 2 —
 the PR-5 parity discipline), with zero steady-state recompiles after
 warmup. Plus per-pass targeted rewrites, the I/O-contract/verify
 revert rails, Pallas fallback cleanliness on CPU, PassManager ordering
-determinism, and the tools/bench wiring.
+determinism, and the tools wiring.
 """
 import json
 import os

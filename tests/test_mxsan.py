@@ -8,8 +8,8 @@ Coverage contract (the acceptance criteria, test-enforced):
   registry (``mxlint --race`` exits 0) — the tier-1 gate;
 - an injected two-lock cycle is detected at runtime with BOTH
   acquisition stacks named in the finding;
-- MXSAN=0 construction returns the PLAIN threading primitives (the
-  zero-cost half of the bench gate, asserted structurally here);
+- MXSAN=0 construction returns the PLAIN threading primitives (zero
+  cost when off, asserted structurally here);
 - a waiter blocked past MXSAN_BLOCK_THRESHOLD_MS triggers the
   flight-recorder dump and the blocked-waiter finding.
 """

@@ -1,10 +1,9 @@
 """mxobs unit + property tests (ISSUE 17): cross-host trace
 propagation (wire contexts + derived pod.step identity), the exact
 histogram merge behind the pod collector, coordinated dump-epoch
-following, the coordinator's obs surface, obslint, the benchstore
-trajectory gates, and the mxprof --dir stitcher. The 2-process
-end-to-end drill lives in test_dist_kvstore.py
-(test_pod_obs_smoke_two_workers).
+following, the coordinator's obs surface, obslint, and the mxprof
+--dir stitcher. The 2-process end-to-end drill lives in
+test_dist_kvstore.py (test_pod_obs_smoke_two_workers).
 """
 import importlib.util
 import json
@@ -409,138 +408,6 @@ def test_coordinator_obs_collector_not_created_when_off():
     co.register("w0", (0,))
     assert co.obs_collector() is None
     assert co.obs_merged() is None
-
-
-# ---------------------------------------------------------------------------
-# benchstore: the perf-trajectory DB + regression gates
-# ---------------------------------------------------------------------------
-
-def _benchstore():
-    return _load_tool("benchstore")
-
-
-def _seed_store(bs, path, metric, values, newest=None):
-    for i, v in enumerate(values):
-        bs.record(metric, v, unit="s", path=path, rev=f"r{i}")
-    if newest is not None:
-        bs.record(metric, newest, unit="s", path=path, rev="new")
-
-
-def test_benchstore_record_load_trajectory(tmp_path):
-    bs = _benchstore()
-    path = os.path.join(str(tmp_path), "store.jsonl")
-    _seed_store(bs, path, "x_seconds", [1.0, 1.1, 0.9])
-    recs = bs.load(path)
-    assert [r["value"] for r in recs] == [1.0, 1.1, 0.9]
-    r = recs[0]
-    assert r["metric"] == "x_seconds" and r["unit"] == "s"
-    assert r["host"] == bs.host_fingerprint() and len(r["host"]) == 8
-    assert r["rev"] == "r0"
-    traj = bs.trajectory(recs, "x_seconds", host=r["host"],
-                         mesh=r["mesh"])
-    assert len(traj) == 3
-    assert bs.trajectory(recs, "x_seconds", host="ffffffff",
-                         mesh=r["mesh"]) == []
-    # torn trailing line is skipped, not fatal
-    with open(path, "a") as f:
-        f.write('{"metric": "x_seco')
-    assert len(bs.load(path)) == 3
-
-
-def test_benchstore_direction_heuristics():
-    bs = _benchstore()
-    assert bs.direction("mxobs_overhead") == "lower"
-    assert bs.direction("step_latency_ms") == "lower"
-    assert bs.direction("resnet50_train_throughput") == "higher"
-    assert bs.direction("mxopt_speedup") == "higher"
-    assert bs.direction("weird_metric") == "both"
-
-
-def test_benchstore_check_green_on_unchanged_rerun(tmp_path):
-    bs = _benchstore()
-    path = os.path.join(str(tmp_path), "store.jsonl")
-    _seed_store(bs, path, "x_overhead", [1.0] * 5, newest=1.0)
-    (v,) = bs.check("x_overhead", path=path)
-    assert v["severity"] == "info", v
-
-
-def test_benchstore_check_flags_seeded_slowdown(tmp_path):
-    bs = _benchstore()
-    path = os.path.join(str(tmp_path), "store.jsonl")
-    # lower-is-better metric doubling: error
-    _seed_store(bs, path, "x_overhead", [1.0, 1.02, 0.98, 1.01],
-                newest=2.0)
-    (v,) = bs.check("x_overhead", path=path)
-    assert v["severity"] == "error", v
-    assert "x_overhead" in v["message"]
-    # higher-is-better halving: error
-    _seed_store(bs, path, "y_throughput", [10.0, 10.1, 9.9],
-                newest=5.0)
-    vy = [v for v in bs.check("y_throughput", path=path)]
-    assert vy and vy[0]["severity"] == "error", vy
-    # an IMPROVEMENT on a lower-better metric is not flagged
-    _seed_store(bs, path, "z_overhead", [1.0, 1.01, 0.99],
-                newest=0.5)
-    (vz,) = bs.check("z_overhead", path=path)
-    assert vz["severity"] == "info", vz
-
-
-def test_benchstore_check_skips_short_history(tmp_path):
-    bs = _benchstore()
-    path = os.path.join(str(tmp_path), "store.jsonl")
-    _seed_store(bs, path, "x_overhead", [1.0], newest=9.0)
-    (v,) = bs.check("x_overhead", path=path)
-    assert v["severity"] == "skip", v
-
-
-def test_benchstore_ingest_bench_file(tmp_path):
-    bs = _benchstore()
-    path = os.path.join(str(tmp_path), "store.jsonl")
-    bench = os.path.join(str(tmp_path), "BENCH_r07.json")
-    with open(bench, "w") as f:
-        json.dump({"n": 7, "cmd": "python bench.py", "rc": 0,
-                   "parsed": {"metric": "q_throughput", "value": 42.5,
-                              "unit": "img/s", "vs_baseline": 1.2}},
-                  f)
-    assert bs.ingest_bench_file(bench, store=path) == 1
-    (r,) = bs.load(path)
-    assert r["metric"] == "q_throughput" and r["value"] == 42.5
-    assert r["rev"] == "7"
-    # unparsed artifacts (crashed runs) ingest zero records
-    bad = os.path.join(str(tmp_path), "BENCH_r08.json")
-    with open(bad, "w") as f:
-        json.dump({"n": 8, "rc": 1, "parsed": None}, f)
-    assert bs.ingest_bench_file(bad, store=path) == 0
-
-
-def test_benchstore_disabled_paths(tmp_path, monkeypatch):
-    bs = _benchstore()
-    monkeypatch.setenv("MXOBS_BENCHSTORE", "0")
-    assert bs.store_path(None) is None
-    # record() against a disabled store is a silent no-op
-    bs.record("x_overhead", 1.0, unit="s")
-    custom = os.path.join(str(tmp_path), "elsewhere.jsonl")
-    monkeypatch.setenv("MXOBS_BENCHSTORE", custom)
-    assert bs.store_path(None) == custom
-
-
-def test_mxprof_regress_gates_on_store(tmp_path, capsys):
-    bs = _benchstore()
-    mxprof = _load_tool("mxprof")
-    path = os.path.join(str(tmp_path), "store.jsonl")
-    _seed_store(bs, path, "x_overhead", [1.0, 1.01, 0.99], newest=1.0)
-    rc = mxprof.regress_cmd(None, path, 20, as_json=True)
-    assert rc == 0
-    capsys.readouterr()
-    # seed a 2x slowdown: exit 2 + an error finding in the report
-    _seed_store(bs, path, "x_overhead", [], newest=2.0)
-    rc = mxprof.regress_cmd(None, path, 20, as_json=True)
-    assert rc == 2
-    rep = json.loads(capsys.readouterr().out)
-    errs = [f for f in rep["findings"]
-            if f["check"] == "perf-regression"
-            and f["severity"] == "error"]
-    assert errs and "x_overhead" in errs[0]["obj"]
 
 
 # ---------------------------------------------------------------------------

@@ -11,8 +11,7 @@ fire).
 
 The subprocess lost-stage drill (SIGKILL a mid-pipeline host; the
 survivors remap stages, redo from committed state, and land on the
-baseline loss bit-for-bit) is @slow; ``bench.py --pipe`` drives the
-scaling legs with gates.
+baseline loss bit-for-bit) is @slow.
 """
 import json
 import os

@@ -9,8 +9,7 @@ and the kill9/pod.host fault-plan grammar.
 
 The subprocess N-host drills (SIGKILL a host / corrupt a host / kill
 the coordinator) are @slow; their protocol content is what the fast
-tests above pin, and `tools/mxresil.py pod` / `bench.py --pod` drive
-them with gates. The 2-process socket-exchange smoke lives in
+tests above pin, and `tools/mxresil.py pod` drives them with gates. The 2-process socket-exchange smoke lives in
 tests/test_dist_kvstore.py (tier-1).
 """
 import json

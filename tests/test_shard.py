@@ -616,27 +616,3 @@ def test_mxlint_shard_selfcheck():
     assert r.returncode == 0, (r.stdout[-800:], r.stderr[-800:])
     assert "shardlint" in r.stdout
     assert "0 error(s), 0 warning(s)" in r.stdout
-
-
-@pytest.mark.slow
-def test_bench_shard_emits_scaling_line():
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
-    env.update({"MXTPU_BENCH_SHARD": "1",
-                "MXTPU_BENCH_SHARD_STEPS": "2",
-                "MXTPU_BENCH_TIMEOUT": "900"})
-    proc = subprocess.run(
-        [sys.executable, os.path.join(ROOT, "bench.py")],
-        capture_output=True, text=True, timeout=960, env=env)
-    lines = [ln for ln in proc.stdout.strip().splitlines()
-             if ln.startswith("{")]
-    assert lines, f"no JSON line:\n{proc.stdout[-800:]}"
-    data = json.loads(lines[-1])
-    assert data["metric"] == "mxshard_scaling"
-    assert data["value"] == 0.125  # ideal 1/8 at 8 devices
-    devs = [s["devices"] for s in data["series"]]
-    assert devs == [1, 2, 4, 8]
-    for s in data["series"]:
-        assert s["recompiles_after_warmup"] == 0
-        assert s["opt_state_per_replica_bytes"] * s["devices"] == \
-            s["opt_state_total_bytes"]

@@ -595,8 +595,8 @@ def check_mxsan():
 
 def check_obs():
     """Pod observability plane health: MXOBS flag state, the live pod
-    collectors (hosts, pushes, owner tokens), the benchstore
-    trajectory DB, and the trace-propagation gate (mxnet_tpu/obs/;
+    collectors (hosts, pushes, owner tokens) and the
+    trace-propagation gate (mxnet_tpu/obs/;
     docs/observability.md multi-host section)."""
     print("----------Pod observability (mxobs)----------")
     try:
@@ -627,19 +627,6 @@ def check_obs():
               + (" CLOSED" if d.get("closed") else ""))
         for w, h in sorted(hosts.items()):
             print(f"  {w}: rank {h['rank']}, {h['pushes']} push(es)")
-    # the perf-trajectory store (tools/benchstore.py)
-    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-    try:
-        import benchstore
-        path = benchstore.store_path()
-        records = benchstore.load()
-        metrics = sorted({r["metric"] for r in records})
-        print(f"benchstore   : {path or '(disabled)'} — "
-              f"{len(records)} record(s), {len(metrics)} metric(s)")
-        if metrics:
-            print("  gate it with: python tools/mxprof.py regress")
-    except Exception as e:
-        print("benchstore   : unavailable (%s)" % e)
 
 
 def check_fleet():

@@ -800,42 +800,6 @@ def trace_cmd(path, top, as_json, min_coverage):
 
 
 # ---------------------------------------------------------------------------
-# benchstore regression gate (tools/benchstore.py — ISSUE 17)
-# ---------------------------------------------------------------------------
-
-def regress_cmd(metric, store, window, as_json):
-    """``mxprof regress``: gate the latest benchstore record of each
-    metric against its trajectory (median/MAD — see tools/benchstore
-    module docstring). Exit 2 on any regression verdict."""
-    try:
-        import benchstore
-    except ImportError:  # loaded by file path (tests): add tools/
-        sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-        import benchstore
-    from mxnet_tpu.passes import Finding, findings_report, \
-        severity_counts
-    verdicts = benchstore.check(metric, path=store, window=window)
-    findings = [Finding("mxprof", "perf-regression", v["metric"],
-                        "error", v["message"])
-                for v in verdicts if v["severity"] == "error"]
-    if as_json:
-        print(findings_report(
-            "mxprof", findings,
-            extra={"store": benchstore.store_path(store),
-                   "verdicts": verdicts}, as_json=True))
-    else:
-        path = benchstore.store_path(store)
-        print(f"== mxprof regress: {path} "
-              f"({len(verdicts)} metric(s) judged)")
-        for v in verdicts:
-            print(f"  [{v['severity']:<5}] {v['message']}")
-        if not verdicts:
-            print("  (empty store — run bench.py to seed the "
-                  "trajectory)")
-    return 2 if severity_counts(findings)["error"] else 0
-
-
-# ---------------------------------------------------------------------------
 # findings (shared schema with mxlint)
 # ---------------------------------------------------------------------------
 
@@ -1023,33 +987,11 @@ def main(argv=None):
                              "dir): rebase each span onto the epoch "
                              "clock and stitch one cross-host report "
                              "(auto-detected for directory paths)")
-    pregress = sub.add_parser(
-        "regress",
-        help="perf-trajectory regression gate over the benchstore "
-             "(tools/benchstore.jsonl): the latest record of each "
-             "metric vs the median/MAD of its history")
-    pregress.add_argument("--metric", default=None,
-                          help="gate one metric (default: all stored)")
-    pregress.add_argument("--store", default=None,
-                          help="store path (default: "
-                               "MXOBS_BENCHSTORE or "
-                               "tools/benchstore.jsonl)")
-    pregress.add_argument("--window", type=int, default=20,
-                          help="history records per trajectory "
-                               "(default 20)")
-    pregress.add_argument("--json", action="store_true",
-                          dest="as_json",
-                          help="emit the shared machine-readable "
-                               "findings report")
     args = p.parse_args(argv)
-    if args.cmd not in ("summarize", "step", "shard", "opt", "trace",
-                        "regress"):
-        p.error("nothing to do: use the summarize, step, shard, opt, "
-                "trace or regress subcommand")
+    if args.cmd not in ("summarize", "step", "shard", "opt", "trace"):
+        p.error("nothing to do: use the summarize, step, shard, opt "
+                "or trace subcommand")
     try:
-        if args.cmd == "regress":
-            return regress_cmd(args.metric, args.store, args.window,
-                               args.as_json)
         if args.cmd == "step":
             return step_cmd(args.dump, args.as_json)
         if args.cmd == "shard":
