@@ -16,8 +16,14 @@ sorts the (token, expert) rows by expert, computes the held experts'
 SiLU-gated FFNs as grouped products over the sorted rows, and combines
 by routing weight. Rows routed to experts held elsewhere are left out
 (their exchange belongs to the deployment, and nothing here stands in
-for it); the row buffer holds the worst case (tokens x k), so no row is
-ever dropped; shapes are static whatever the routing. A shared expert
+for it). The buffer of sorted rows holds twice the rows that uniform
+routing sends to the held experts (``buffer_rows``; never more than
+tokens x k, the worst case): every pass over the rows costs what a
+share really gets, not what it could get. A batch that routes more rows
+here takes further passes of the same buffer under a device-side loop
+(``_held_experts``), so no row is ever dropped and shapes are static
+whatever the routing; where the layer holds every expert the buffer
+holds the worst case and there is no loop. A shared expert
 (``shared_hidden``) runs on every token, unweighted.
 
     layer = MoEFFN(units=256, hidden_size=1024, num_experts=8,
@@ -178,58 +184,133 @@ def _group_sizes(keys, held_count):
                    axis=0, dtype=jnp.int32)
 
 
+def _sort_by_group(top_i, held_start, num_held, rows):
+    """The (token, choice) rows sorted by group: ``(order, starts,
+    held)``. ``order`` is the permutation that sorts them (the groups'
+    rows in token order, rows held elsewhere last), padded to whole
+    buffers of ``rows``; ``starts`` (num_held + 1,) every group's first
+    slot in that order and, last, the rows routed here; ``held`` (N, k)
+    whether the choice's expert is held here."""
+    keys, held = _group_keys(top_i, held_start, num_held)
+    starts = jnp.concatenate([
+        jnp.zeros((1,), jnp.int32),
+        jnp.cumsum(_group_sizes(keys, num_held), dtype=jnp.int32)])
+    order = jnp.pad(jnp.argsort(keys, stable=True),
+                    (0, -keys.shape[0] % rows))
+    return order, starts, held
+
+
 def _take_rows(a, index):
     """``a[index]`` along the first axis, zeros where ``index`` is out
     of range: one gather, no pass to mask its result."""
     return a.at[index].get(mode="fill", fill_value=0)
 
 
+# The buffer of sorted rows holds this many times the rows that uniform
+# routing sends to the experts held here. The rows really routed to a
+# chip's share move by about a tenth between batches (the ledger's
+# ``moe_experts_roofline``, which follows them, reads 25.7-28.2 over
+# its seeds), so 2 leaves the further passes to skewed routing.
+BUFFER_FACTOR = 2
+# the buffer is a whole number of the grouped products' row tiles, and
+# the sums over a token's slots are taken a tile of tokens at a time:
+# the matrix unit's side
+ROW_TILE = 128
+
+
+def buffer_rows(rows, num_held, num_experts):
+    """Rows of the buffer of sorted rows for ``rows`` (token, choice)
+    rows routed over ``num_experts`` of which ``num_held`` are held
+    here: ``BUFFER_FACTOR`` times what uniform routing sends here, in
+    whole row tiles, and never more than ``rows``, the worst case."""
+    expected = -(-rows * num_held // num_experts)
+    return min(rows, -(-BUFFER_FACTOR * expected // ROW_TILE) * ROW_TILE)
+
+
+def _sum_to_tokens(rows, w, slots, n, dtype):
+    """``(n, C)`` in ``dtype``: token *t*'s sum of ``w[s] * rows[s]`` over
+    the slots *s* of token *t* (``w`` None: the plain sum). ``slots`` is
+    ``_buffer_slots``' ``(token, by_token, sorted_token)``: the slots by
+    ascending token, so a tile of ``ROW_TILE`` tokens owns a run of
+    them, and its sums are one product of the tile's (weighted) one-hot
+    matrix with the run's rows: a grouped product whose groups are the
+    token tiles. The weights meet the rows in the rows' dtype and the
+    product accumulates in float32 (float32 rows at the highest
+    precision: a one-hot product must not round them); neither a scatter
+    nor an array of ``n * k`` rows is made. Slots whose token is out of
+    range (``n``) lie past every run."""
+    _, by_token, tok = slots
+    tile = min(ROW_TILE, n)
+    tiles = -(-n // tile)
+    mine = (tok % tile)[:, None] == jnp.arange(tile)[None, :]
+    mine = mine & (tok < n)[:, None]
+    if w is None:
+        hot = mine.astype(rows.dtype)
+    else:
+        hot = jnp.where(mine, w[by_token][:, None], 0).astype(rows.dtype)
+    runs = jnp.sum((tok // tile)[:, None] == jnp.arange(tiles)[None, :],
+                   axis=0, dtype=jnp.int32)
+    sums = jax.lax.ragged_dot_general(
+        hot, rows[by_token], runs, _RUNS_OF_ROWS,
+        precision=(jax.lax.Precision.HIGHEST
+                   if rows.dtype == jnp.float32 else None),
+        preferred_element_type=dtype)
+    return sums.reshape(tiles * tile, -1)[:n]
+
+
+# (slots, tile)^T x (slots, C) a run of slots -> (runs, tile, C)
+_RUNS_OF_ROWS = jax.lax.RaggedDotDimensionNumbers(
+    dot_dimension_numbers=(((0,), (0,)), ((), ())),
+    lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
+
+
 @jax.custom_vjp
-def _dispatch_rows(x, order, inv, take, back):
-    """The buffer of sorted rows: slot *s* holds token ``order[s] // k``
-    where ``take[s]`` (the slot lies inside the held groups), zeros
-    elsewhere. ``order`` is the permutation of the (token, choice) rows
-    that sorts them by group, ``inv`` its inverse, ``back[r]`` whether
-    row *r*'s expert is held. The transpose is a gather by ``inv`` and a
-    sum over the k choices, never a scatter; slots outside the groups
-    give and get nothing, whatever the grouped product left there."""
-    k = order.shape[0] // x.shape[0]
-    return _take_rows(x, jnp.where(take, order // k, x.shape[0]))
+def _dispatch_rows(x, slots):
+    """The buffer of sorted rows: slot *s* holds token ``token[s]`` of
+    ``x`` (``slots[0]``), zeros where that is out of range (a slot past
+    the rows routed here). The transpose is the sum over every token's
+    slots (``_sum_to_tokens``), never a scatter; slots out of range give
+    and get nothing, whatever the grouped product left there."""
+    return _take_rows(x, slots[0])
 
 
-def _dispatch_rows_fwd(x, order, inv, take, back):
-    return _dispatch_rows(x, order, inv, take, back), (inv, back, x.shape[0])
+def _dispatch_rows_fwd(x, slots):
+    return _take_rows(x, slots[0]), (slots, x.shape[0])
 
 
 def _dispatch_rows_bwd(res, g):
-    inv, back, n = res
-    rows = _take_rows(g, jnp.where(back, inv, g.shape[0]))
-    return (rows.reshape(n, -1, g.shape[-1]).sum(axis=1),
-            None, None, None, None)
+    slots, n = res
+    return _sum_to_tokens(g, None, slots, n, g.dtype), None
 
 
 _dispatch_rows.defvjp(_dispatch_rows_fwd, _dispatch_rows_bwd)
 
 
-@jax.custom_vjp
-def _collect_rows(out, order, inv, take, back):
-    """The products' rows back in (token, choice) order: row *r* is slot
-    ``inv[r]`` of ``out`` where ``back[r]``, zeros for a choice held
-    elsewhere. Transposed as a gather by ``order`` under ``take``."""
-    return _take_rows(out, jnp.where(back, inv, out.shape[0]))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _combine_rows(out, w, slots, n):
+    """``(n, C)`` in float32: every token's sum of its slots' rows of
+    ``out`` by their weights ``w`` (``_sum_to_tokens``). Transposed as
+    the gather of the cotangent's rows by token (the dispatch's own
+    operation), weighted; the weights' cotangent is a dot a slot."""
+    return _combine_rows_fwd(out, w, slots, n)[0]
 
 
-def _collect_rows_fwd(out, order, inv, take, back):
-    return _collect_rows(out, order, inv, take, back), (order, take)
+def _combine_rows_fwd(out, w, slots, n):
+    return _sum_to_tokens(out, w, slots, n, jnp.float32), (out, w, slots[0])
 
 
-def _collect_rows_bwd(res, g):
-    order, take = res
-    return (_take_rows(g, jnp.where(take, order, g.shape[0])),
-            None, None, None, None)
+def _combine_rows_bwd(n, res, g):
+    out, w, token = res
+    g = _take_rows(g.astype(out.dtype), token)
+    f32 = jnp.float32
+    # a slot past the rows routed here gets nothing, whatever the
+    # grouped product left in its row of ``out``
+    dot = jnp.sum(out.astype(f32) * g.astype(f32), axis=-1)
+    return (g * w[:, None],
+            jnp.where(token < n, dot, 0).astype(w.dtype), None)
 
 
-_collect_rows.defvjp(_collect_rows_fwd, _collect_rows_bwd)
+_combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)
 
 
 def _grouped_product(rows, w, sizes):
@@ -251,6 +332,125 @@ def _silu_gate(gate, up):
             * up.astype(jnp.float32)).astype(gate.dtype)
 
 
+def _buffer_slots(order, starts, lo, rows, n, k):
+    """The slots ``lo .. lo + rows`` of the sorted order: ``(row,
+    slots, sizes)``. Slot *s* holds (token, choice) row ``row[s]``; it
+    is valid where it lies under the rows routed here, ``starts[-1]``.
+    ``slots`` is ``(token, by_token, sorted_token)``, each (rows,):
+    every slot's token (``n`` where the slot is not valid), the slots by
+    ascending token with those not valid last, and the tokens in that
+    order. ``sizes`` (E_held,) are the rows of every group that lie in
+    these slots. ``order`` is padded to whole buffers."""
+    slot = lo + jnp.arange(rows)
+    valid = slot < starts[-1]
+    row = jax.lax.dynamic_slice(order, (lo,), (rows,))
+    # rows in (token, choice) order are in token order
+    sorted_row, by_token = jax.lax.sort_key_val(
+        jnp.where(valid, row, n * k), slot - lo)
+    return (row,
+            (jnp.where(valid, row // k, n), by_token, sorted_row // k),
+            jnp.diff(jnp.clip(starts, lo, lo + rows)))
+
+
+@functools.partial(jax.jit, static_argnames=("rows",))
+def _buffer_pass(x, weights, w_gate, w_up, w_down, order, starts, lo, rows):
+    """One buffer of ``rows`` sorted rows through the held experts: the
+    slots ``lo .. lo + rows`` of the (token, choice) rows sorted by
+    group. ``order`` is the permutation that sorts them, ``starts``
+    (E_held + 1,) every group's first slot and, last, the rows routed
+    here; ``weights`` (N * k,) the routing weights in (token, choice)
+    order, 0 for a choice held elsewhere. Returns (N, C) in float32:
+    every token's weighted sum over its choices that lie in these
+    slots. Jitted: the layers of a model, the first pass and the further
+    ones, forward and recomputed, share one trace; and under a
+    ``jax.vjp`` the name jax rewrites as ``jvp(..)`` /
+    ``transpose(jvp(..))`` is the jit's, so that the phases' names below
+    stay whole."""
+    n = x.shape[0]
+    with jax.named_scope("dispatch"):
+        row, slots, sizes = _buffer_slots(order, starts, lo, rows, n,
+                                          weights.shape[0] // n)
+        sorted_rows = _dispatch_rows(x, slots)
+    with jax.named_scope("experts"):
+        gate = _grouped_product(sorted_rows, w_gate, sizes)
+        up = _grouped_product(sorted_rows, w_up, sizes)
+        out = _grouped_product(_silu_gate(gate, up), w_down, sizes)
+    with jax.named_scope("combine"):
+        # a slot that is not valid holds a row held elsewhere or the
+        # padding's row 0 of another group: its weight meets no run
+        return _combine_rows(out, weights[row], slots, n)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
+def _held_experts(x, weights, w_gate, w_up, w_down, order, starts, rows):
+    """Every token's weighted sum over its choices held here, (N, C) in
+    ``x``'s dtype: the first ``rows`` slots of the sorted order in one
+    ``_buffer_pass`` and, where more rows than that were routed here,
+    the rest in further passes of the same buffer (``_further_passes``).
+    The backward pass keeps the first pass's residuals alone and
+    recomputes every further pass where it transposes it: one definition
+    of the expert computation, one program with static shapes whatever
+    the routing."""
+    return _every_pass(
+        (x, weights, w_gate, w_up, w_down), order, starts, rows,
+        lambda first, *operands: (first(*operands), None))[0]
+
+
+def _every_pass(operands, order, starts, rows, run_first):
+    """``(result, what run_first kept)``: the first buffer pass run by
+    ``run_first(pass, *operands) -> (its result, anything)``, then the
+    further ones."""
+    buffer_at = functools.partial(_buffer_pass, order=order, starts=starts,
+                                  rows=rows)
+    y, kept = run_first(functools.partial(buffer_at, lo=0), *operands)
+    y = _further_passes(lambda lo: buffer_at(*operands, lo=lo), y, rows,
+                        operands[1].shape[0], starts[-1])
+    return y.astype(operands[0].dtype), kept
+
+
+def _further_passes(one_more, first, rows, worst, routed):
+    """``first`` plus ``one_more(lo)`` for every further buffer's first
+    slot ``lo = rows, 2 * rows, ..`` under ``routed``, the rows routed
+    here (``worst`` at most, a shape): a device-side loop inside a
+    conditional, so that a batch whose rows all fit the first buffer
+    pays for neither the loop's state nor what the compiler moves out of
+    its body. Nothing where the buffer holds the worst case: the shapes
+    say so."""
+    if rows >= worst:
+        return first
+
+    def loop(first):
+        return jax.lax.while_loop(
+            lambda c: c[0] < routed,
+            lambda c: (c[0] + rows,
+                       jax.tree.map(jnp.add, c[1], one_more(c[0]))),
+            (rows, first))[1]
+
+    return jax.lax.cond(rows < routed, loop, lambda first: first, first)
+
+
+def _held_experts_fwd(x, weights, w_gate, w_up, w_down, order, starts, rows):
+    operands = (x, weights, w_gate, w_up, w_down)
+    y, pull = _every_pass(operands, order, starts, rows, jax.vjp)
+    return y, (pull, operands, order, starts)
+
+
+def _held_experts_bwd(rows, res, g):
+    pull, operands, order, starts = res
+    g = g.astype(jnp.float32)
+
+    def pulled_at(lo):
+        return jax.vjp(functools.partial(
+            _buffer_pass, order=order, starts=starts, lo=lo, rows=rows),
+            *operands)[1](g)
+
+    return (*_further_passes(pulled_at, pull(g), rows, operands[1].shape[0],
+                             starts[-1]), None, None)
+
+
+_held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
+
+
 @functools.partial(jax.jit, static_argnames=(
     "k", "num_held", "held_start", "scale"))
 def routed_experts(x, router_w, w_gate, w_up, w_down, *, k, held_start,
@@ -261,32 +461,20 @@ def routed_experts(x, router_w, w_gate, w_up, w_down, *, k, held_start,
     w_down: (E_held, F, C); the layer holds experts ``held_start ..
     held_start + num_held`` of the router's ``E_all``. Returns (N, C):
     for every token the weighted sum over its choices that are held
-    here. The buffer of sorted rows has N * k rows, the worst case."""
-    n, c = x.shape
+    here. The buffer of sorted rows has ``buffer_rows`` rows; a batch
+    that routes more rows here takes further passes (``_held_experts``),
+    so every routed row is computed."""
+    rows = buffer_rows(x.shape[0] * k, num_held, router_w.shape[0])
     with jax.named_scope("route"):
         weights, top_i = route_top_k(x, router_w, k, scale)
     with jax.named_scope("dispatch"):
-        keys, held = _group_keys(top_i, held_start, num_held)
-        sizes = _group_sizes(keys, num_held)
-        order = jnp.argsort(keys, stable=True)
-        inv = jnp.argsort(order)
-        take = jnp.arange(n * k) < jnp.sum(sizes)
-        back = held.reshape(-1)
-        rows = _dispatch_rows(x, order, inv, take, back)
-    with jax.named_scope("experts"):
-        gate = _grouped_product(rows, w_gate, sizes)
-        up = _grouped_product(rows, w_up, sizes)
-        out = _grouped_product(_silu_gate(gate, up), w_down, sizes)
-    with jax.named_scope("combine"):
-        per_choice = _collect_rows(out, order, inv, take,
-                                   back).reshape(n, k, c)
-        # the weights meet the rows in the rows' dtype and the sum over
-        # the k choices accumulates in float32: a float32 copy of the
-        # (N, k, C) rows is neither made nor kept for the backward pass
-        weights = jnp.where(held, weights, 0.0).astype(x.dtype)
-        return jnp.einsum("nk,nkc->nc", weights, per_choice,
-                          preferred_element_type=jnp.float32
-                          ).astype(x.dtype)
+        order, starts, held = _sort_by_group(top_i, held_start, num_held,
+                                             rows)
+    # the weights meet the rows in the rows' dtype; the sum over a
+    # token's choices accumulates in float32
+    weights = jnp.where(held, weights, 0.0).astype(x.dtype).reshape(-1)
+    return _held_experts(x, weights, w_gate, w_up, w_down, order, starts,
+                         rows)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "num_held",
@@ -326,10 +514,12 @@ class RoutedExpertsFFN(HybridBlock):
     docstring). ``experts_held`` is a contiguous ``range`` of the
     ``num_experts`` the router scores. In an eager forward (not under a
     trace) the layer records, under ``label``, the telemetry gauges
-    ``moe_rows_routed.<label>``, ``moe_load_max_over_mean.<label>`` and
-    ``moe_rows_dropped.<label>`` (0: the buffer holds the worst case),
-    and keeps the expert ids its router chose in ``last_expert_ids``
-    (N, k)."""
+    ``moe_rows_routed.<label>``, ``moe_load_max_over_mean.<label>``,
+    ``moe_buffer_rows.<label>`` (``buffer_rows``),
+    ``moe_rows_overflow.<label>`` (the routed rows past the buffer,
+    which take further passes) and ``moe_rows_dropped.<label>`` (0:
+    every pass computes its rows), and keeps the expert ids its router
+    chose in ``last_expert_ids`` (N, k)."""
 
     def __init__(self, units, hidden_size, num_experts,
                  num_experts_per_tok, experts_held=None,
@@ -374,7 +564,7 @@ class RoutedExpertsFFN(HybridBlock):
             sizes, self.last_expert_ids = routing_counts(
                 flat._data, router_weight._data, **geometry)
             if self._label:
-                self._record(sizes, flat.shape[0])
+                self._record(sizes, flat.shape[0], router_weight.shape[0])
         out = invoke(functools.partial(
             routed_experts, scale=self._scale, **geometry), [flat, router_weight, w_gate, w_up, w_down])
         out = out.reshape(shape)
@@ -382,13 +572,19 @@ class RoutedExpertsFFN(HybridBlock):
             out = out + self.shared(x)
         return out
 
-    def _record(self, sizes, tokens):
+    def _record(self, sizes, tokens, num_experts):
         from ..telemetry import metrics
         sizes = jax.device_get(sizes)
         rows = int(sizes.sum())
         mean = rows / len(sizes)
-        metrics.gauge(f"moe_rows_routed.{self._label}").set(rows)
-        metrics.gauge(f"moe_load_max_over_mean.{self._label}").set(
-            float(sizes.max()) / mean if mean else 0.0)
-        metrics.gauge(f"moe_rows_dropped.{self._label}").set(
-            max(0, rows - tokens * self._k))
+        buffer = buffer_rows(tokens * self._k, len(sizes), num_experts)
+        for name, value in (
+                ("moe_rows_routed", rows),
+                ("moe_load_max_over_mean",
+                 float(sizes.max()) / mean if mean else 0.0),
+                ("moe_buffer_rows", buffer),
+                # rows that took a further pass of the buffer
+                ("moe_rows_overflow", max(0, rows - buffer)),
+                # every pass computes its rows: none is ever left out
+                ("moe_rows_dropped", 0)):
+            metrics.gauge(f"{name}.{self._label}").set(value)

@@ -230,6 +230,22 @@ def _traced_counts():
         for label in ("kernel", "dense")}
 
 
+@pytest.fixture
+def traced_counts_kept():
+    """The process's counters left as the test found them: what a test
+    traces under a pretended chip is not the process's record, and the
+    benchmark's reader of the same counters
+    (``tests/benchmark/test_benchmark_attention_products.py``) may run
+    in this worker after it."""
+    from mxnet_tpu.telemetry import metrics
+    found = _traced_counts()
+    yield
+    for label, value in found.items():
+        counter = metrics.counter(f"attention_traced_total.{label}")
+        counter.reset()
+        counter.inc(value)
+
+
 def _interpreted(q, k, v, causal=False, scale=None):
     return flash_attention(q, k, v, causal, scale, interpret=True)
 
@@ -238,8 +254,8 @@ def _interpreted(q, k, v, causal=False, scale=None):
     (False, 512, "dense"),     # no chip: dense whatever the rule says
     (True, 512, "kernel"),
     (True, 128, "dense")])     # the rule refuses the shape
-def test_multi_head_attention_counts_its_traced_backend(monkeypatch, on_tpu,
-                                                        t, label):
+def test_multi_head_attention_counts_its_traced_backend(
+        monkeypatch, traced_counts_kept, on_tpu, t, label):
     """A call of ``MultiHeadAttention`` bumps
     ``attention_traced_total.<label>`` once, with the label the rule
     gives, and takes that backend."""
@@ -263,8 +279,8 @@ def test_multi_head_attention_counts_its_traced_backend(monkeypatch, on_tpu,
 
 @pytest.mark.parametrize("active,label", [(False, "dense"),
                                           (True, "kernel")])
-def test_fused_attention_counts_its_traced_backend(monkeypatch, active,
-                                                   label):
+def test_fused_attention_counts_its_traced_backend(
+        monkeypatch, traced_counts_kept, active, label):
     from mxnet_tpu.ops import fused, pallas_kernels
     monkeypatch.setattr(fused, "pallas_attention_active",
                         lambda *a: active)

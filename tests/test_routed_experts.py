@@ -162,3 +162,101 @@ def test_moeffn_takes_exactly_k_on_a_tie():
     assert onp.allclose(got, want, atol=1e-6)
 
 
+
+
+# ---------------------------------------------------------------------------
+# past the buffer: further passes, every row still computed
+# ---------------------------------------------------------------------------
+
+TOKENS, WIDTH, CHOICES, ROUTED, HELD = 48, 32, 4, 32, 4
+BUFFER = moe.buffer_rows(TOKENS * CHOICES, HELD, ROUTED)
+
+
+def _routing_this_many_rows_here(rows, seed=5):
+    """Inputs whose router sends exactly ``rows`` of the TOKENS x
+    CHOICES (token, choice) rows to experts 0..HELD: the router reads
+    the token's own features (an identity), and every token's features
+    are large on the experts it is to choose, distinct so that no two
+    tie."""
+    ks = jax.random.split(jax.random.key(seed), 5)
+    x = onp.array(0.1 * jax.random.normal(ks[0], (TOKENS, WIDTH)))
+    left = rows
+    for t in range(TOKENS):
+        here = min(CHOICES, left)
+        left -= here
+        # the held experts first, then experts held elsewhere
+        chosen = list(range(here)) + [HELD + (t + j) % (ROUTED - HELD)
+                                      for j in range(CHOICES - here)]
+        x[t, chosen] = 3.0 + 0.25 * onp.arange(CHOICES)
+    assert left == 0
+    return {"x": jnp.asarray(x),
+            "router_weight": 2.0 * jnp.eye(ROUTED, WIDTH),
+            "w_gate": 0.3 * jax.random.normal(ks[1], (HELD, WIDTH, 8)),
+            "w_up": 0.3 * jax.random.normal(ks[2], (HELD, WIDTH, 8)),
+            "w_down": 0.3 * jax.random.normal(ks[3], (HELD, 8, WIDTH))}
+
+
+LEAVES = ("x", "router_weight", "w_gate", "w_up", "w_down")
+SIZES = {"num_experts_per_tok": CHOICES, "moe_routed_scaling_factor": 2.5}
+
+
+def _program(p, probe):
+    out = moe.routed_experts(*(p[name] for name in LEAVES), k=CHOICES,
+                             held_start=0, num_held=HELD, scale=2.5)
+    return jnp.sum(out * probe), out
+
+
+def _reference(p, probe):
+    out, _ = laguna._experts(SIZES, p["x"], p, correctness.Rounding,
+                             held=(0, HELD))
+    return jnp.sum(out * probe), out
+
+
+@pytest.mark.parametrize("rows", [BUFFER - 1, BUFFER, BUFFER + 1,
+                                  TOKENS * CHOICES],
+                         ids=["one-under", "full", "one-over", "worst"])
+def test_rows_past_the_buffer_are_computed_not_dropped(rows):
+    """4 of 32 experts held, 4 choices a token: the buffer holds 128 of
+    the 192 rows. With one row under, exactly, one row over and every
+    row routed here the output and the gradients (input, router, the
+    three expert matrices) are the plain reference's, and the gauges
+    read what the case built."""
+    from mxnet_tpu.telemetry import metrics
+    assert BUFFER == 128 < TOKENS * CHOICES
+    p = _routing_this_many_rows_here(rows)
+    counts, _ = moe.routing_counts(p["x"], p["router_weight"], k=CHOICES,
+                                   held_start=0, num_held=HELD)
+    assert int(counts.sum()) == rows
+    probe = jax.random.normal(jax.random.key(11), (TOKENS, WIDTH))
+    (_, got), grads = jax.value_and_grad(_program, has_aux=True)(p, probe)
+    (_, want), wanted = jax.value_and_grad(_reference, has_aux=True)(
+        p, probe)
+    assert onp.allclose(got, want, atol=1e-5)
+    for name in LEAVES:
+        assert onp.allclose(grads[name], wanted[name], atol=2e-5), name
+    assert float(jnp.abs(wanted["router_weight"]).max()) > 1e-3
+
+    blk = moe.RoutedExpertsFFN(WIDTH, 8, ROUTED, CHOICES, range(HELD), 2.5,
+                               label=f"test.past.{rows}")
+    blk.initialize()
+    for name in LEAVES[1:]:
+        getattr(blk, name).set_data(_wrap(p[name]))
+    assert onp.allclose(blk(_wrap(p["x"]))._data, want, atol=1e-5)
+
+    def gauge(name):
+        return metrics.gauge(f"{name}.test.past.{rows}").value()
+
+    assert gauge("moe_rows_routed") == rows
+    assert gauge("moe_buffer_rows") == BUFFER
+    assert gauge("moe_rows_overflow") == max(0, rows - BUFFER)
+    assert gauge("moe_rows_dropped") == 0
+
+
+def test_the_buffer_is_twice_the_expected_rows_in_whole_tiles():
+    # the cell: 8192 tokens x 8 choices, 32 of 256 experts held
+    assert moe.buffer_rows(8192 * 8, 32, 256) == 16384
+    # every expert held, or twice the expected rows past the worst case
+    assert moe.buffer_rows(40 * 3, 8, 8) == 120
+    assert moe.buffer_rows(48 * 3, 4, 8) == 144
+    # whole row tiles
+    assert moe.buffer_rows(1000 * 8, 3, 64) == 768

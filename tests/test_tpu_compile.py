@@ -12,6 +12,8 @@ nothing touches it at import, in a ``skipif``, in ``parametrize``
 arguments or in conftest.py, and only the xdist worker that is handed
 this file loads it.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -168,3 +170,54 @@ def test_routed_experts_compile_without_a_dense_product(one_chip):
     # every row through every held expert would be 32 times that
     cost = compiled.cost_analysis()
     assert cost["flops"] < 2 * 9 * 2 * 65536 * 2048 * 512
+
+
+@pytest.fixture(scope="module")
+def expert_layer_text(one_chip):
+    """The Laguna cell's expert layer, result and gradients, compiled:
+    the optimized HLO."""
+    from mxnet_tpu.parallel.moe import routed_experts
+
+    def loss(x, r, g, u, d):
+        out = routed_experts(x, r, g, u, d, k=8, held_start=0,
+                             num_held=32, scale=2.5)
+        return out.astype(jnp.float32).sum(), out
+
+    args = _shapes(one_chip, ((8192, 2048), jnp.bfloat16),
+                   ((256, 2048), jnp.float32),
+                   ((32, 2048, 512), jnp.bfloat16),
+                   ((32, 2048, 512), jnp.bfloat16),
+                   ((32, 512, 2048), jnp.bfloat16))
+    return jax.jit(jax.value_and_grad(loss, (0, 1, 2, 3, 4), has_aux=True)) \
+        .lower(*args).compile().as_text()
+
+
+def test_routed_experts_hold_no_array_of_the_worst_case_rows(
+        expert_layer_text):
+    """The buffer of sorted rows has 16,384 rows in the cell (twice the
+    8,192 that uniform routing sends to 32 of 256 experts): neither the
+    first pass nor the further passes, forward or backward, make an
+    array of 65,536 rows by the model's or the experts' width, and the
+    (token, choice) index vectors are all that has 65,536 rows."""
+    from mxnet_tpu.parallel.moe import buffer_rows
+    assert buffer_rows(8192 * 8, 32, 256) == 16384
+    text = expert_layer_text
+    assert re.search(r"bf16\[16384,2048\]", text)
+    assert re.search(r"bf16\[16384,512\]", text)
+    worst = re.findall(r"\w+\[(?:65536,(?:8,)?(?:2048|512)"
+                       r"|8192,8,(?:2048|512))\]", text)
+    assert not worst, sorted(set(worst))
+
+
+def test_routed_experts_take_further_passes_under_a_conditional(
+        expert_layer_text):
+    """One program whatever the routing: the further passes of the
+    buffer are a loop inside a conditional on the rows routed here, one
+    in the forward pass and one in the backward pass (which recomputes
+    each further pass where it transposes it)."""
+    text = expert_layer_text
+    conditionals = re.findall(
+        r" conditional\(.*?op_name=\"([^\"]*)\"", text)
+    assert len(conditionals) == 2, conditionals
+    assert sum("transpose(" in name for name in conditionals) == 1
+    assert len(re.findall(r" while\(", text)) == 2
