@@ -54,6 +54,8 @@ __all__ = ["ElasticStepFunction"]
 
 
 class ElasticStepFunction(StepFunction):
+    _ties_shared = False  # buckets and votes go by name
+
     def __init__(self, net, loss_fn=None, trainer=None, **kwargs):
         if kwargs.get("psum_axis") is not None:
             raise MXNetError(

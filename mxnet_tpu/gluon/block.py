@@ -171,7 +171,10 @@ def functional_call(block, pvals: Dict[str, Any], args, training=False,
                     rng_scope.__exit__(None, None, None)
             aux = {p.name: v for p, v in tc.aux_updates}
             # map back to prefixed names used in pvals
-            name_of = {p.name: n for n, p in plist}
+            # a shared Parameter answers to the first of its names
+            name_of = {}
+            for n, p in plist:
+                name_of.setdefault(p.name, n)
             aux = {name_of.get(k, k): v for k, v in aux.items()}
         finally:
             tc_scope.__exit__(None, None, None)

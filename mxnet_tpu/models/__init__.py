@@ -7,3 +7,7 @@ from . import laguna  # noqa: F401
 from .laguna import (  # noqa: F401
     RMSNorm, LagunaAttention, LagunaDecoderLayer, LMHead, LagunaLM,
 )
+from . import lfm2  # noqa: F401
+from .lfm2 import (  # noqa: F401
+    Lfm2ShortConv, Lfm2Attention, Lfm2DecoderLayer, Lfm2MoeLM,
+)

@@ -21,7 +21,14 @@ net from such a dict. Per layer ``x + attn(norm(x))`` then
   ``mlp_layer_types`` says ``dense`` and
   ``parallel.moe.RoutedExpertsFFN`` (``moe``) where it says ``sparse``:
   the layer is told which of the ``num_experts_routed`` experts it
-  holds.
+  holds. Of the expert layer's two scoring rules this family uses
+  ``"softmax"`` (the default: softmax over all the router's outputs, the
+  k largest renormalised, times the scaling factor, with a shared
+  expert); ``"sigmoid"`` with a selection bias is ``models/lfm2.py``'s.
+- ``DecoderLayer`` is the pre-norm residual skeleton both decoder
+  families here build their layers from; ``RMSNorm``, ``LMHead``,
+  ``rotary_tables`` and the rotation are shared with ``models/lfm2.py``
+  too.
 
 Scope names in a traced program (``jax.named_scope`` under the blocks'
 attribute names): ``layers/<i>/attn/window`` or ``.../attn/full``
@@ -44,8 +51,8 @@ from ..ndarray.ndarray import invoke
 from ..ops.banded_attention import banded_attention
 from ..parallel.moe import GatedFFN, RoutedExpertsFFN
 
-__all__ = ["RMSNorm", "LagunaAttention", "LagunaDecoderLayer", "LMHead",
-           "LagunaLM", "rotary_tables"]
+__all__ = ["RMSNorm", "LagunaAttention", "DecoderLayer",
+           "LagunaDecoderLayer", "LMHead", "LagunaLM", "rotary_tables"]
 
 
 def _yarn_inverse_frequencies(dim, rope):
@@ -230,27 +237,36 @@ class LMHead(HybridBlock):
             [x, weight])
 
 
-class LagunaDecoderLayer(HybridBlock):
+class DecoderLayer(HybridBlock):
+    """The pre-norm residual layer of the decoders here:
+    ``x + mixer(norm(x))`` then ``x + ffn(norm(x))``. ``mixer`` and
+    ``ffn`` are ``(attribute name, maker)`` pairs, built under the
+    layer's name scope and hung under those names (which name them in a
+    traced program too); ``norms`` names the two RMSNorms."""
+
+    def __init__(self, units, eps, mixer, ffn,
+                 norms=("attn_norm", "mlp_norm"), **kwargs):
+        super().__init__(**kwargs)
+        self._halves = tuple(zip(norms, (mixer[0], ffn[0])))
+        with self.name_scope():
+            for norm, (name, make) in zip(norms, (mixer, ffn)):
+                setattr(self, norm, RMSNorm(units, eps, prefix=norm + "_"))
+                setattr(self, name, make())
+
+    def hybrid_forward(self, F, x):
+        for norm, name in self._halves:
+            x = x + getattr(self, name)(getattr(self, norm)(x))
+        return x
+
+
+class LagunaDecoderLayer(DecoderLayer):
     """``make_attn`` / ``make_ffn`` build the layer's two halves under
     its name scope; a sparse FFN hangs under ``moe``, a dense one under
     ``mlp``."""
 
     def __init__(self, units, eps, make_attn, make_ffn, sparse, **kwargs):
-        super().__init__(**kwargs)
-        with self.name_scope():
-            self.attn_norm = RMSNorm(units, eps, prefix="attn_norm_")
-            self.attn = make_attn()
-            self.mlp_norm = RMSNorm(units, eps, prefix="mlp_norm_")
-            if sparse:
-                self.moe = make_ffn()
-            else:
-                self.mlp = make_ffn()
-        self._sparse = sparse
-
-    def hybrid_forward(self, F, x):
-        x = x + self.attn(self.attn_norm(x))
-        ffn = self.moe if self._sparse else self.mlp
-        return x + ffn(self.mlp_norm(x))
+        super().__init__(units, eps, ("attn", make_attn),
+                         ("moe" if sparse else "mlp", make_ffn), **kwargs)
 
 
 class LagunaLM(HybridBlock):

@@ -26,9 +26,11 @@ is ever written:
   loop over the query blocks, unrolled at trace time, each with a
   static slice of the keys.
 
-On a TPU, where the shapes allow it, the same mathematics runs as the
-Pallas splash-attention kernel that ships with jax (block-sparse over
-the same mask, scores never leave fast memory); ``backend="xla"``
+On a TPU, where the shapes allow it (``splash_available``: head sizes of
+128 lanes or multiples, and 64, zero-padded to the lanes), the same
+mathematics runs as the Pallas splash-attention kernel that ships with
+jax (block-sparse over the same mask, scores never leave fast memory);
+``backend="xla"``
 forces the composition above, which is also what the CPU runs.
 """
 from __future__ import annotations
@@ -39,7 +41,7 @@ import math
 import jax
 import jax.numpy as jnp
 
-__all__ = ["banded_attention", "band_blocks"]
+__all__ = ["banded_attention", "band_blocks", "default_backend"]
 
 _NEG = -1e30  # a masked score: finite, so a row of them stays finite
 # rows of a block where the caller names none, measured on a v5e at 8192
@@ -138,9 +140,24 @@ def _xla_attention(q, k, v, window, block, scale):
 # ---------------------------------------------------------------------------
 
 def splash_available(t, d) -> bool:
-    """The kernel takes head sizes and sequence lengths that are
-    multiples of 128."""
-    return d % 128 == 0 and t % 128 == 0 and t >= 128
+    """The kernel takes sequence lengths that are multiples of 128 and
+    head sizes that are multiples of 128, or 64: a 64-wide head runs
+    zero-padded to the 128 lanes (``_splash_attention``), which at 32
+    query heads over 8 of 64, 8192 tokens, causal, forward and backward
+    on a v5e took 15.7 ms against 17.6 for the composition below and
+    17.5 for ``pallas_kernels.flash_attention`` over repeated key/value
+    heads (``tools/attention_table.py --head64 1``; PERF.md section 6,
+    PR 32). Narrower heads were not measured and take the
+    composition."""
+    return (d % 128 == 0 or d == 64) and t % 128 == 0 and t >= 128
+
+
+def default_backend(t, d) -> str:
+    """What ``banded_attention`` runs where the caller names no backend:
+    the kernel on a TPU where the shapes allow it, else the
+    composition."""
+    return "splash" if (jax.default_backend() == "tpu"
+                        and splash_available(t, d)) else "xla"
 
 
 @functools.lru_cache(maxsize=None)
@@ -172,9 +189,15 @@ def _splash_attention(q, k, v, window, block, scale, interpret=False):
     hkv = k.shape[1]
     g = hq // hkv
     kernel = _splash_kernel(g, t, window, block, interpret)
-    qg = (q * jnp.asarray(scale, q.dtype)).reshape(b, hkv, g, t, d)
-    o = jax.vmap(jax.vmap(kernel))(qg, k, v)
-    return o.reshape(b, hq, t, d)
+    q = q * jnp.asarray(scale, q.dtype)
+    lanes = -(-d // 128) * 128
+    if lanes != d:
+        # a head narrower than the lanes: zeros past its dimensions add
+        # nothing to a score and give result columns that are cut off
+        pad = [(0, 0), (0, 0), (0, 0), (0, lanes - d)]
+        q, k, v = jnp.pad(q, pad), jnp.pad(k, pad), jnp.pad(v, pad)
+    o = jax.vmap(jax.vmap(kernel))(q.reshape(b, hkv, g, t, lanes), k, v)
+    return o.reshape(b, hq, t, lanes)[..., :d]
 
 
 # ---------------------------------------------------------------------------
@@ -198,9 +221,7 @@ def banded_attention(q, k, v, window=None, block=None, scale=None,
         raise ValueError(f"window must be at least 1, got {window}")
     scale = 1.0 / math.sqrt(q.shape[-1]) if scale is None else scale
     if backend is None:
-        backend = "splash" if (jax.default_backend() == "tpu"
-                               and splash_available(*q.shape[2:])) \
-            else "xla"
+        backend = default_backend(*q.shape[2:])
     if backend in ("splash", "splash_interpret"):
         return _splash_attention(q, k, v, window, block, scale,
                                  interpret=backend == "splash_interpret")

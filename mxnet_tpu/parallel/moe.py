@@ -11,7 +11,12 @@ tokens x k: it is a small-model convenience, not expert parallelism.
 ``RoutedExpertsFFN`` is the expert-parallel layer proper, as one chip
 of an expert-parallel deployment runs it: it is told which experts it
 holds (``experts_held``), routes every token over ALL ``num_experts``
-(softmax, top-k, renormalised over the k, times a scaling factor),
+by one of two scoring rules (``route_top_k``; the layer's ``scoring``):
+``"softmax"`` (softmax, top-k, renormalised over the k, times a scaling
+factor: ``models.LagunaLM``) or ``"sigmoid"`` (sigmoid scores, the k
+largest of score plus a selection bias that no optimizer trains, the
+unbiased scores renormalised with an epsilon, times the factor:
+``models.Lfm2MoeLM``); whatever the rule, it
 sorts the (token, expert) rows by expert, computes the held experts'
 SiLU-gated FFNs as grouped products over the sorted rows, and combines
 by routing weight. Rows routed to experts held elsewhere are left out
@@ -34,6 +39,9 @@ holds the worst case and there is no loop. A shared expert
                              num_experts=256, num_experts_per_tok=8,
                              experts_held=range(0, 32),
                              routed_scaling=2.5, shared_hidden=512)
+    layer = RoutedExpertsFFN(units=2048, hidden_size=1792,
+                             num_experts=32, num_experts_per_tok=4,
+                             experts_held=range(0, 8), scoring="sigmoid")
 """
 from __future__ import annotations
 
@@ -159,16 +167,42 @@ def expert_parallel_shardings(block, expert_axis: str = "model"):
 # the expert-parallel layer: top-k dispatch over the experts held here
 # ---------------------------------------------------------------------------
 
-def route_top_k(x, router_w, k, scale):
-    """``(weights, expert ids)``, each (N, k): softmax over all the
-    router's outputs in float32, the k largest (the lower index wins a
-    tie), renormalised over the k, times ``scale``."""
+# what the sigmoid rule adds to the sum of a token's chosen scores
+# before it divides by it
+SIGMOID_ROUTER_EPS = 1e-6
+
+
+def route_top_k(x, router_w, k, scale, bias=None):
+    """``(weights, expert ids)``, each (N, k), from the router's outputs
+    over all its experts in float32, by one of two scoring rules.
+
+    Softmax (``bias`` None): softmax over the outputs, the k largest
+    (the lower index wins a tie), renormalised over the k, times
+    ``scale``.
+
+    Sigmoid with a selection bias (``bias`` (E,), float32, no trained
+    weight): ``s = sigmoid(outputs)``; the k experts with the largest
+    ``s + bias`` are chosen (the lower index wins a tie); their weights
+    are the UNBIASED ``s`` over (their sum + ``SIGMOID_ROUTER_EPS``),
+    times ``scale``. The gradient reaches the router through ``s`` of
+    the chosen experts and the renormalisation, never through the
+    bias."""
     f32 = jnp.float32
     logits = jnp.dot(x.astype(f32), router_w.astype(f32).T,
                      precision=jax.lax.Precision.HIGHEST)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_i = jax.lax.top_k(probs, k)
-    return top_p / jnp.sum(top_p, axis=-1, keepdims=True) * scale, top_i
+    if bias is None:
+        probs = jax.nn.softmax(logits, axis=-1)
+        top_p, top_i = jax.lax.top_k(probs, k)
+        return top_p / jnp.sum(top_p, axis=-1, keepdims=True) * scale, top_i
+    scores = jax.nn.sigmoid(logits)
+    _, top_i = jax.lax.top_k(
+        scores + jax.lax.stop_gradient(bias.astype(f32)), k)
+    # the chosen scores by a one-hot select over the experts: a pass,
+    # and its transpose another, where a gather's is a scatter-add
+    chosen = top_i[..., None] == jnp.arange(scores.shape[-1])
+    top_s = jnp.sum(jnp.where(chosen, scores[:, None, :], 0.0), axis=-1)
+    return top_s / (jnp.sum(top_s, axis=-1, keepdims=True)
+                    + SIGMOID_ROUTER_EPS) * scale, top_i
 
 
 def _group_keys(top_i, held_start, held_count):
@@ -453,20 +487,21 @@ _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 @functools.partial(jax.jit, static_argnames=(
     "k", "num_held", "held_start", "scale"))
-def routed_experts(x, router_w, w_gate, w_up, w_down, *, k, held_start,
-                   num_held, scale):
+def routed_experts(x, router_w, w_gate, w_up, w_down, bias=None, *, k,
+                   held_start, num_held, scale):
     """The held experts' part of a top-k routed SiLU-gated FFN.
 
     x: (N, C); router_w: (E_all, C); w_gate / w_up: (E_held, C, F);
     w_down: (E_held, F, C); the layer holds experts ``held_start ..
-    held_start + num_held`` of the router's ``E_all``. Returns (N, C):
+    held_start + num_held`` of the router's ``E_all``; ``bias`` (E_all,)
+    picks ``route_top_k``'s sigmoid rule. Returns (N, C):
     for every token the weighted sum over its choices that are held
     here. The buffer of sorted rows has ``buffer_rows`` rows; a batch
     that routes more rows here takes further passes (``_held_experts``),
     so every routed row is computed."""
     rows = buffer_rows(x.shape[0] * k, num_held, router_w.shape[0])
     with jax.named_scope("route"):
-        weights, top_i = route_top_k(x, router_w, k, scale)
+        weights, top_i = route_top_k(x, router_w, k, scale, bias)
     with jax.named_scope("dispatch"):
         order, starts, held = _sort_by_group(top_i, held_start, num_held,
                                              rows)
@@ -479,12 +514,22 @@ def routed_experts(x, router_w, w_gate, w_up, w_down, *, k, held_start,
 
 @functools.partial(jax.jit, static_argnames=("k", "num_held",
                                              "held_start"))
-def routing_counts(x, router_w, *, k, held_start, num_held):
+def routing_counts(x, router_w, bias=None, *, k, held_start, num_held):
     """``(rows each held expert gets from the tokens x: (num_held,), the
     expert ids the router chose: (N, k))``."""
-    _, top_i = route_top_k(x, router_w, k, 1.0)
+    _, top_i = route_top_k(x, router_w, k, 1.0, bias)
     keys, _ = _group_keys(top_i, held_start, num_held)
     return _group_sizes(keys, num_held), top_i
+
+
+@functools.partial(jax.jit, static_argnames=("k",))
+def bias_changed_share(x, router_w, bias, *, k):
+    """The share of the tokens ``x`` whose chosen k under the sigmoid
+    rule differ, as a set, from the k largest scores alone."""
+    with_bias = route_top_k(x, router_w, k, 1.0, bias)[1]
+    without = route_top_k(x, router_w, k, 1.0, jnp.zeros_like(bias))[1]
+    return jnp.mean(jnp.any(jnp.sort(with_bias, axis=-1)
+                            != jnp.sort(without, axis=-1), axis=-1))
 
 
 class GatedFFN(HybridBlock):
@@ -512,19 +557,25 @@ class GatedFFN(HybridBlock):
 class RoutedExpertsFFN(HybridBlock):
     """One chip's share of a top-k routed expert layer (module
     docstring). ``experts_held`` is a contiguous ``range`` of the
-    ``num_experts`` the router scores. In an eager forward (not under a
-    trace) the layer records, under ``label``, the telemetry gauges
-    ``moe_rows_routed.<label>``, ``moe_load_max_over_mean.<label>``,
-    ``moe_buffer_rows.<label>`` (``buffer_rows``),
-    ``moe_rows_overflow.<label>`` (the routed rows past the buffer,
-    which take further passes) and ``moe_rows_dropped.<label>`` (0:
-    every pass computes its rows), and keeps the expert ids its router
-    chose in ``last_expert_ids`` (N, k)."""
+    ``num_experts`` the router scores. ``scoring`` is the router's rule
+    (``route_top_k``): ``"softmax"``, or ``"sigmoid"``, which adds the
+    parameter ``expert_bias`` (num_experts,), float32, ``grad_req``
+    ``"null"``: the selection bias, which no optimizer touches. In an
+    eager forward (not under a trace) the layer records, under
+    ``label``, the telemetry gauges ``moe_rows_routed.<label>``,
+    ``moe_load_max_over_mean.<label>``, ``moe_buffer_rows.<label>``
+    (``buffer_rows``), ``moe_rows_overflow.<label>`` (the routed rows
+    past the buffer, which take further passes),
+    ``moe_rows_dropped.<label>`` (0: every pass computes its rows) and,
+    under the sigmoid rule, ``moe_bias_changed_choice.<label>`` (the
+    share of tokens whose chosen experts are not the largest scores
+    alone), and keeps the expert ids its router chose in
+    ``last_expert_ids`` (N, k)."""
 
     def __init__(self, units, hidden_size, num_experts,
                  num_experts_per_tok, experts_held=None,
                  routed_scaling=1.0, shared_hidden=0, label=None,
-                 **kwargs):
+                 scoring="softmax", **kwargs):
         super().__init__(**kwargs)
         held = range(num_experts) if experts_held is None \
             else experts_held
@@ -536,6 +587,9 @@ class RoutedExpertsFFN(HybridBlock):
         if not 0 < num_experts_per_tok <= num_experts:
             raise ValueError("num_experts_per_tok must lie in "
                              f"1..{num_experts}")
+        if scoring not in ("softmax", "sigmoid"):
+            raise ValueError("scoring must be 'softmax' or 'sigmoid', "
+                             f"got {scoring!r}")
         self._units, self._k = units, int(num_experts_per_tok)
         self._held_start, self._num_held = held[0], len(held)
         self._scale = float(routed_scaling)
@@ -544,6 +598,10 @@ class RoutedExpertsFFN(HybridBlock):
         with self.name_scope():
             self.router_weight = self.params.get(
                 "router_weight", shape=(num_experts, units), init=None)
+            if scoring == "sigmoid":
+                self.expert_bias = self.params.get(
+                    "expert_bias", shape=(num_experts,), init="zeros",
+                    grad_req="null")
             self.w_gate = self.params.get(
                 "w_gate", shape=(len(held), units, hidden_size), init=None)
             self.w_up = self.params.get(
@@ -555,18 +613,28 @@ class RoutedExpertsFFN(HybridBlock):
         for p in (self.w_gate, self.w_up, self.w_down):
             p._expert_sharded = True
 
-    def hybrid_forward(self, F, x, router_weight, w_gate, w_up, w_down):
+    def hybrid_forward(self, F, x, router_weight, w_gate, w_up, w_down,
+                       expert_bias=None):
         shape = x.shape
         flat = x.reshape((-1, shape[-1]))
         geometry = dict(k=self._k, held_start=self._held_start,
                         num_held=self._num_held)
+        router = [router_weight] if expert_bias is None \
+            else [router_weight, expert_bias]
         if not _trace_ctx.active:
-            sizes, self.last_expert_ids = routing_counts(
-                flat._data, router_weight._data, **geometry)
+            raw = [a._data for a in [flat] + router]
+            sizes, self.last_expert_ids = routing_counts(*raw, **geometry)
             if self._label:
                 self._record(sizes, flat.shape[0], router_weight.shape[0])
+                if expert_bias is not None:
+                    from ..telemetry import metrics
+                    metrics.gauge(
+                        f"moe_bias_changed_choice.{self._label}").set(float(
+                            bias_changed_share(*raw, k=self._k)))
+        # the bias goes last: ``routed_experts``' one optional operand
         out = invoke(functools.partial(
-            routed_experts, scale=self._scale, **geometry), [flat, router_weight, w_gate, w_up, w_down])
+            routed_experts, scale=self._scale, **geometry),
+            [flat, router_weight, w_gate, w_up, w_down] + router[1:])
         out = out.reshape(shape)
         if self.shared is not None:
             out = out + self.shared(x)

@@ -54,6 +54,8 @@ class ShardedStepFunction(StepFunction):
     The global batch must divide by the plan's batch-axis size.
     """
 
+    _ties_shared = False  # the plan places parameters by name
+
     def __init__(self, net, loss_fn=None, shard_plan: ShardPlan = None,
                  **kwargs):
         if kwargs.get("psum_axis") is not None:

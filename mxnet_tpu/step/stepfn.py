@@ -117,6 +117,10 @@ class StepFunction:
     the per-sample loss.
     """
 
+    # a Parameter shared between blocks is one leaf of this step; a
+    # subclass whose placement or exchange goes by name refuses it
+    _ties_shared = True
+
     def __init__(self, net, loss_fn=None, trainer=None, optimizer="sgd",
                  optimizer_params=None, arg_dict=None, aux_dict=None,
                  input_names=("data", "softmax_label"), grad_names=None,
@@ -255,22 +259,31 @@ class StepFunction:
                 self._net(_wrap(_raw(sample_x)[:1]))
             plist = sorted(
                 self._net._collect_params_with_prefix().items())
-        self._plist = plist
-        self._param_objs = {n: p for n, p in plist}
-        # weight tying: one Parameter under several prefixed names
-        # would split its gradient across the aliases (each alias gets
-        # a partial vjp cotangent), update each alias from the same
-        # pre-step weight, and advance its update count once per alias
-        # — silently diverging from the eager loop. Refuse loudly.
-        by_id = {}
+        # weight tying: one Parameter under several prefixed names (an
+        # embedding that is also the head) is ONE leaf of the step,
+        # under the first of its names. ``functional_call`` binds the
+        # Parameter object, so every use reads that one traced value:
+        # the leaf's gradient is the sum over its uses, it is updated
+        # once and its update count advances once, as in the eager
+        # loop. (A leaf a name would split the gradient across the
+        # aliases and update each from the same pre-step weight.)
+        by_id, tied = {}, []
         for n, p in plist:
             if id(p) in by_id:
-                raise MXNetError(
-                    f"StepFunction: parameter '{p.name}' is shared "
-                    f"between blocks (as '{by_id[id(p)]}' and '{n}'); "
-                    "weight-tied models are not supported by the fused "
-                    "step — use the eager record/backward/step loop")
-            by_id[id(p)] = n
+                tied.append((p.name, by_id[id(p)], n))
+            else:
+                by_id[id(p)] = n
+        if tied and not self._ties_shared:
+            name, first, alias = tied[0]
+            raise MXNetError(
+                f"{type(self).__name__}: parameter '{name}' is shared "
+                f"between blocks (as '{first}' and '{alias}'); "
+                "weight-tied models are not supported by this step — "
+                "use StepFunction or the eager record/backward/step "
+                "loop")
+        plist = [(n, p) for n, p in plist if by_id[id(p)] == n]
+        self._plist = plist
+        self._param_objs = {n: p for n, p in plist}
         if self._trainer is not None:
             index_of = self._trainer._param2idx
             trainable = [(n, p) for n, p in plist

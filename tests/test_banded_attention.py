@@ -117,3 +117,32 @@ def test_the_kernel_computes_the_same_mask(window):
     assert float(got) == pytest.approx(float(want), rel=1e-4)
     for a, b in zip(g_got, g_want):
         assert onp.allclose(a, b, atol=1e-3)
+
+
+def test_a_head_of_64_runs_the_kernel_padded_to_the_lanes():
+    """A 64-wide head (grouped-query, 4 query heads over 2): the splash
+    kernel over heads zero-padded to 128 lanes, interpreted on the CPU,
+    gives the composition's attention and gradients, in the head's own
+    width; the rule takes 64 and multiples of 128 and nothing else."""
+    from mxnet_tpu.ops.banded_attention import (default_backend,
+                                                splash_available)
+    ks = jax.random.split(jax.random.key(11), 4)
+    q = jax.random.normal(ks[0], (1, 4, 384, 64))
+    k = jax.random.normal(ks[1], (1, 2, 384, 64))
+    v = jax.random.normal(ks[2], (1, 2, 384, 64))
+    ct = jax.random.normal(ks[3], (1, 4, 384, 64))
+
+    def run(backend):
+        return jax.value_and_grad(lambda *a: jnp.sum(banded_attention(
+            *a, block=128, backend=backend) * ct), (0, 1, 2))(q, k, v)
+
+    (want, g_want), (got, g_got) = run("xla"), run("splash_interpret")
+    assert float(got) == pytest.approx(float(want), rel=1e-4)
+    for a, b in zip(g_got, g_want):
+        assert a.shape == b.shape
+        assert onp.allclose(a, b, atol=1e-3)
+    assert splash_available(8192, 64) and splash_available(8192, 128)
+    assert splash_available(8192, 256)
+    assert not splash_available(8192, 32) and not splash_available(8192, 96)
+    assert not splash_available(8200, 64)
+    assert default_backend(8192, 64) == "xla"      # no TPU here

@@ -526,19 +526,103 @@ def test_bucket_assignment_rebuilt_after_cast():
         assert str(p.data().dtype) == "bfloat16"  # no dtype drift
 
 
-def test_fused_step_refuses_shared_parameters():
-    """Weight-tied blocks (params=) would split gradients across
-    aliases — the fused step must refuse, not silently mis-train."""
-    x, _ = _data(feat=10)
+def _tied_net():
+    """Two Dense layers over ONE weight and bias (``params=``)."""
     net = nn.HybridSequential()
     with net.name_scope():
-        d1 = nn.Dense(10, flatten=False, in_units=10)
+        d1 = nn.Dense(10, activation="tanh", flatten=False, in_units=10)
         net.add(d1)
         net.add(nn.Dense(10, flatten=False, in_units=10,
                          params=d1.params))
-    net.initialize()
+    net.initialize(mx.initializer.Xavier())
+    return net
+
+
+@pytest.mark.parametrize("opt_name,opt_kwargs", [
+    ("sgd", {"learning_rate": 0.05, "momentum": 0.9, "wd": 0.01}),
+    ("adam", {"learning_rate": 0.01}),
+])
+def test_fused_step_ties_shared_parameters(opt_name, opt_kwargs):
+    """A Parameter shared between blocks (``params=``) is ONE leaf of
+    the fused step: its gradient the sum over its uses, one update, its
+    update count advanced once a step; bitwise the eager loop's."""
+    x, _ = _data(feat=10)
+    y = nd.array(onp.random.RandomState(1).uniform(
+        -1, 1, (x.shape[0], 10)).astype("float32"))
+    net_a, net_b = _tied_net(), _tied_net()
+    net_a(x), net_b(x)
+    _clone_into(net_a, net_b)
+    names = net_b._collect_params_with_prefix()
+    assert names["0.weight"] is names["1.weight"]
+    tr_a = gluon.Trainer(net_a.collect_params(), opt_name, dict(opt_kwargs))
+    tr_b = gluon.Trainer(net_b.collect_params(), opt_name, dict(opt_kwargs))
+    loss_fn = gluon.loss.L2Loss()
+    fused = tr_b.fuse_step(net_b, loss_fn)
+    pa = net_a._collect_params_with_prefix()
+    for step in range(3):
+        with autograd.record():
+            loss_a = loss_fn(net_a(x), y)
+        loss_a.backward()
+        tr_a.step(x.shape[0])
+        loss_b = fused.step(x, y)
+        # equal to rounding, not to the bit: one program adds the two
+        # uses' gradients inside its reductions, the tape after them
+        assert onp.allclose(loss_a.asnumpy(), loss_b.asnumpy(),
+                            rtol=1e-6, atol=0), step
+        for k in pa:
+            assert onp.allclose(pa[k].data().asnumpy(),
+                                names[k].data().asnumpy(),
+                                rtol=1e-5, atol=1e-7), (k, step)
+    for sa, sb in zip(_state_leaves(tr_a._updaters[0]),
+                      _state_leaves(tr_b._updaters[0])):
+        for a, b in zip(sa, sb):
+            assert onp.allclose(a, b, rtol=1e-5, atol=1e-7)
+    assert fused._trainable == ("0.bias", "0.weight")
+    index = tr_b._param2idx[names["0.weight"].name]
+    assert tr_b._optimizer._index_update_count[index] == 3
+    assert tr_b._optimizer._index_update_count == \
+        tr_a._optimizer._index_update_count
+    # the gradient held both uses: a net whose second layer has a
+    # weight of its own, started from the same values, moves otherwise
+    untied = nn.HybridSequential()
+    with untied.name_scope():
+        untied.add(nn.Dense(10, activation="tanh", flatten=False,
+                            in_units=10))
+        untied.add(nn.Dense(10, flatten=False, in_units=10))
+    untied.initialize()
+    untied(x)
+    start = _tied_net()
+    start(x)
+    _clone_into(net_a, start)  # any tied values: only equality matters
+    for k, p in untied._collect_params_with_prefix().items():
+        p.set_data(start._collect_params_with_prefix()[k].data())
+    tied_step = StepFunction(start, gluon.loss.L2Loss(), optimizer="sgd")
+    loose_step = StepFunction(untied, gluon.loss.L2Loss(), optimizer="sgd")
+    assert onp.array_equal(tied_step.step(x, y).asnumpy(),
+                           loose_step.step(x, y).asnumpy())
+    w_tied = start._collect_params_with_prefix()["0.weight"].data()
+    w_loose = untied._collect_params_with_prefix()
+    assert not onp.array_equal(w_tied.asnumpy(),
+                               w_loose["0.weight"].data().asnumpy())
+    before = net_a._collect_params_with_prefix()["0.weight"].data()
+    moved = (w_loose["0.weight"].data() - before) \
+        + (w_loose["1.weight"].data() - before)
+    assert onp.allclose((w_tied - before).asnumpy(), moved.asnumpy(),
+                        rtol=1e-5, atol=1e-7)
+
+
+def test_sharded_and_elastic_steps_still_refuse_shared_parameters():
+    """The plan places parameters, and the elastic step buckets and
+    votes on gradients, by name: they are not made to tie, and say so."""
+    from mxnet_tpu.elastic.stepfn import ElasticStepFunction
+    from mxnet_tpu.shard import ShardPlan
+    assert ElasticStepFunction._ties_shared is False
+    x, _ = _data(feat=10)
+    net = _tied_net()
     net(x)
-    fused = StepFunction(net, gluon.loss.L2Loss(), optimizer="sgd")
+    tr = gluon.Trainer(net.collect_params(), "sgd", {"learning_rate": 0.1})
+    fused = tr.fuse_step(net, gluon.loss.L2Loss(),
+                         shard_plan=ShardPlan({"batch": 2}))
     with pytest.raises(mx.MXNetError, match="shared"):
         fused.step(x, nd.zeros((x.shape[0], 10)))
 

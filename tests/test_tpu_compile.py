@@ -146,6 +146,48 @@ def test_banded_attention_compiles_as_a_kernel(one_chip, heads, window):
     assert text.count("tpu_custom_call") >= 2
 
 
+def test_head_64_attention_compiles_as_a_kernel(one_chip):
+    """The LFM2 cell's full layer (8192 tokens, 32 query heads over 8
+    key/value heads of 64): the splash kernel over heads padded to the
+    lanes, forward and backward."""
+    from mxnet_tpu.ops.banded_attention import banded_attention
+
+    def loss(q, k, v):
+        return banded_attention(q, k, v, backend="splash") \
+            .astype(jnp.float32).sum()
+
+    q, k, v = _shapes(one_chip, ((1, 32, 8192, 64), jnp.bfloat16),
+                      ((1, 8, 8192, 64), jnp.bfloat16),
+                      ((1, 8, 8192, 64), jnp.bfloat16))
+    text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, k, v).compile() \
+        .as_text()
+    assert text.count("tpu_custom_call") >= 2
+
+
+def test_short_conv_compiles_without_a_convolution(one_chip):
+    """The LFM2 cell's gated short convolution (8192 tokens, 2048
+    channels, bfloat16): fusions of shifted adds, no convolution
+    instruction and no array of windows (a (T, 3, C) or (3, T, C) copy
+    of the input). Forward is one fusion with no temporary at all; the
+    backward pass keeps less beside its operands and results than the
+    three thirds of ``du`` and one float32 array of (T, C)."""
+    from mxnet_tpu.ops.short_conv import short_conv
+
+    u, w, g = _shapes(one_chip, ((1, 8192, 6144), jnp.bfloat16),
+                      ((2048, 3), jnp.bfloat16),
+                      ((1, 8192, 2048), jnp.bfloat16))
+    forward = jax.jit(short_conv).lower(u, w).compile()
+    backward = jax.jit(lambda u, w, g: jax.vjp(short_conv, u, w)[1](g)) \
+        .lower(u, w, g).compile()
+    for compiled in (forward, backward):
+        text = compiled.as_text()
+        assert "convolution(" not in text
+        assert "8192,3,2048" not in text and "3,8192,2048" not in text
+    assert forward.memory_analysis().temp_size_in_bytes == 0
+    assert backward.memory_analysis().temp_size_in_bytes \
+        < 8192 * 2048 * (3 * 2 + 4)
+
+
 def test_routed_experts_compile_without_a_dense_product(one_chip):
     """The Laguna cell's expert layer (8192 tokens, 8 of 256 experts a
     token, 32 held): the grouped products are kernels over the sorted

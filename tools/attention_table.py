@@ -3,6 +3,7 @@
 backward: the measurement behind ``flash_attention_available``'s rule.
 
     python tools/attention_table.py [--bh 192] [--block 1] [--causal 1]
+    python tools/attention_table.py --head64 1 [--block 1]
 
 One JSON line a case: ``{"t", "d", "dtype", "causal", "kernel_ms",
 "dense_ms"}`` (a side that does not fit the device reads null), the
@@ -10,6 +11,13 @@ attention alone or, with ``--block 1``, inside one block of a model
 (projection, heads, attention, output projection: the difference of the
 two sides is attention's, laid out as a step lays it out); for
 ``--check 1`` both sides' gaps to dense at ``highest`` precision.
+``--head64 1`` is the table behind ``banded_attention``'s rule for a
+head narrower than the lanes: causal grouped-query attention at 32 query
+heads over 8 key/value heads of 64, 8192 tokens, bfloat16, forward plus
+backward, as jax's splash kernel over heads zero-padded to 128 lanes
+(``splash_padded_ms``), as this repo's ``flash_attention`` with the
+key/value heads repeated (``flash_ms``) and as the XLA composition
+(``xla_ms``).
 Times are the device's own, from a profile of the calls; a CPU run
 refuses to start (its times would say nothing about the chip).
 """
@@ -117,6 +125,52 @@ def errors(bh, causal):
               flush=True)
 
 
+def head64(reps, block):
+    """One JSON line: the three ways to a causal 32-over-8 head layer of
+    64 at 8192 tokens; with ``block`` between a q/k/v and an output
+    projection of 2048."""
+    from mxnet_tpu.ops.banded_attention import banded_attention
+    t, h, kv, d, c = 8192, 32, 8, 64, 2048
+    keys = jax.random.split(jax.random.key(64), 6)
+    bf16 = jnp.bfloat16
+
+    def flash(q, k, v):
+        g = q.shape[1] // k.shape[1]
+        return flash_attention(q, jnp.repeat(k, g, axis=1),
+                               jnp.repeat(v, g, axis=1), True)
+
+    sides = {
+        "splash_padded_ms": functools.partial(banded_attention,
+                                              backend="splash"),
+        "flash_ms": flash,
+        "xla_ms": functools.partial(banded_attention, backend="xla")}
+    if block:
+        def model(attn):
+            def run(x, wq, wkv, wo):
+                q = (x @ wq).reshape(1, t, h, d).transpose(0, 2, 1, 3)
+                kvs = (x @ wkv).reshape(1, t, 2, kv, d)
+                k = kvs[:, :, 0].transpose(0, 2, 1, 3)
+                v = kvs[:, :, 1].transpose(0, 2, 1, 3)
+                o = attn(q, k, v).transpose(0, 2, 1, 3)
+                return o.reshape(1, t, h * d) @ wo
+            return run
+        shapes = ((1, t, c), (c, h * d), (c, 2 * kv * d), (h * d, c),
+                  (1, t, c))
+        ops = [(jax.random.normal(key, s, jnp.float32)
+                * (1.0 if i in (0, 4) else c ** -0.5)).astype(bf16)
+               for i, (key, s) in enumerate(zip(keys, shapes))]
+    else:
+        model = lambda attn: attn
+        shapes = ((1, h, t, d), (1, kv, t, d), (1, kv, t, d), (1, h, t, d))
+        ops = [jax.random.normal(key, s, jnp.float32).astype(bf16)
+               for key, s in zip(keys, shapes)]
+    row = {"t": t, "d": d, "heads": h, "kv_heads": kv, "dtype": "bfloat16",
+           "in_block": bool(block)}
+    for side, attn in sides.items():
+        row[side] = time_ms(fwd_bwd(model(attn)), ops, reps)
+    print(json.dumps(row), flush=True)
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--bh", type=int, default=192)
@@ -124,9 +178,12 @@ def main():
     ap.add_argument("--causal", type=int, default=0)
     ap.add_argument("--check", type=int, default=0)
     ap.add_argument("--block", type=int, default=0)
+    ap.add_argument("--head64", type=int, default=0)
     args = ap.parse_args()
     if jax.devices()[0].platform != "tpu":
         sys.exit("attention_table.py measures a chip; none is attached")
+    if args.head64:
+        return head64(args.reps, args.block)
     causal = bool(args.causal)
     if args.check:
         return errors(args.bh, causal)
