@@ -32,7 +32,10 @@ net from such a dict. Per layer ``x + attn(norm(x))`` then
 
 Scope names in a traced program (``jax.named_scope`` under the blocks'
 attribute names): ``layers/<i>/attn/window`` or ``.../attn/full``
-around the two attention products and the softmax; under
+around the two attention products and the softmax (a sliding layer that
+the band kernel of ``ops.banded_attention`` takes has its rotary
+positions and its gate inside the kernels, so under ``window`` too);
+under
 ``layers/<i>/moe``: ``route``, ``dispatch``, ``experts``, ``combine``,
 ``shared``.
 """
@@ -48,7 +51,10 @@ import numpy as onp
 from ..gluon import nn
 from ..gluon.block import HybridBlock
 from ..ndarray.ndarray import invoke
-from ..ops.banded_attention import banded_attention
+from ..ops.banded_attention import (banded_attention,
+                                    banded_attention_token_major,
+                                    default_backend)
+from ..ops.pallas_kernels import count_traced
 from ..parallel.moe import GatedFFN, RoutedExpertsFFN
 
 __all__ = ["RMSNorm", "LagunaAttention", "DecoderLayer",
@@ -126,18 +132,31 @@ def _rotate(x, cos, sin, turn):
 
 
 @functools.partial(jax.jit, static_argnames=("heads", "kv_heads",
-                                             "head_dim", "window"))
+                                             "head_dim", "window",
+                                             "backend"))
 def _gated_attention(q, k, v, gate, cos, sin, turn, *, heads, kv_heads,
-                     head_dim, window):
+                     head_dim, window, backend=None):
     """Projected ``q`` (B, T, H * D), ``k`` / ``v`` (B, T, Hkv * D) and
-    gate logits (B, T, H) to the gated heads' outputs (B, T, H * D)."""
+    gate logits (B, T, H) to the gated heads' outputs (B, T, H * D);
+    ``backend`` as ``ops.banded_attention`` names them (None: its
+    rule)."""
     b, t, _ = q.shape
-    q = _rotate(q.reshape(b, t, heads, head_dim), cos, sin, turn)
-    k = _rotate(k.reshape(b, t, kv_heads, head_dim), cos, sin, turn)
+    q = q.reshape(b, t, heads, head_dim)
+    k = k.reshape(b, t, kv_heads, head_dim)
     v = v.reshape(b, t, kv_heads, head_dim)
+    if window is not None and backend in ("band", "band_interpret"):
+        # the band kernel reads a head where the projection left it,
+        # and turns, scales and gates in the same pass
+        with jax.named_scope("window"):
+            o = banded_attention_token_major(
+                q, k, v, window=window, backend=backend,
+                rotary=(cos, sin, turn), gate=gate)
+        return o.reshape(b, t, heads * head_dim)
+    q, k = _rotate(q, cos, sin, turn), _rotate(k, cos, sin, turn)
     with jax.named_scope("full" if window is None else "window"):
         o = banded_attention(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-                             v.transpose(0, 2, 1, 3), window=window)
+                             v.transpose(0, 2, 1, 3), window=window,
+                             backend=backend)
     o = _gate_heads(o.transpose(0, 2, 1, 3), gate)      # (B, T, H, D)
     return o.reshape(b, t, heads * head_dim)
 
@@ -212,9 +231,15 @@ class LagunaAttention(HybridBlock):
         return self._tables[t]
 
     def hybrid_forward(self, F, x):
-        cos, sin, turn = self._rotary(x.shape[1])
+        t = x.shape[1]
+        cos, sin, turn = self._rotary(t)
+        geometry = self._geometry
+        backend = default_backend(t, self._head_dim, geometry["window"],
+                                  geometry["heads"] // geometry["kv_heads"])
+        count_traced({"band": "band", "splash": "kernel",
+                      "xla": "dense"}[backend.split("_")[0]])
         fn = functools.partial(_gated_attention, cos=cos, sin=sin,
-                               turn=turn, **self._geometry)
+                               turn=turn, backend=backend, **geometry)
         out = invoke(fn, [self.q_proj(x), self.k_proj(x), self.v_proj(x),
                           self.g_proj(x)])
         return self.o_proj(out)

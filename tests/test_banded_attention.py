@@ -146,3 +146,119 @@ def test_a_head_of_64_runs_the_kernel_padded_to_the_lanes():
     assert not splash_available(8192, 32) and not splash_available(8192, 96)
     assert not splash_available(8200, 64)
     assert default_backend(8192, 64) == "xla"      # no TPU here
+
+
+# ---------------------------------------------------------------------------
+# the band kernel (token-major heads), interpreted
+# ---------------------------------------------------------------------------
+
+def _token_major(x):
+    return x.transpose(0, 2, 1, 3)
+
+
+def _band_operands(t, group, dtype, kv_heads=2, d=128):
+    ks = jax.random.split(jax.random.key(t + group), 4)
+    shapes = [(1, t, kv_heads * group, d), (1, t, kv_heads, d),
+              (1, t, kv_heads, d), (1, t, kv_heads * group, d)]
+    return [jax.random.normal(key, s, jnp.float32).astype(dtype)
+            for key, s in zip(ks, shapes)]
+
+
+@pytest.mark.parametrize("t,window,group,block,dtype", [
+    (1024, 512, 2, None, jnp.float32),        # a window of one block
+    (1024, 256, 1, None, jnp.float32),        # of two sub-blocks
+    (512, 256, 2, None, jnp.float32),         # T of one block only
+    (512, 128, 4, None, jnp.float32),         # a window of one sub-block
+    (768, 256, 2, 256, jnp.float32),          # three blocks of the window
+    (2048, 1024, 1, None, jnp.float32),       # a window over BAND_BLOCK
+    (1280, 640, 1, None, jnp.float32),        # five sub-blocks a block
+    (1024, 512, 2, None, jnp.bfloat16),
+    (1024, 512, 8, 1024, jnp.bfloat16),       # the cell's group and dtype
+], ids=["w-block", "w-2sub", "one-block", "w-sub", "blocks-256", "w-1024",
+        "w-640", "bf16", "bf16-group-8"])
+def test_the_band_kernel_equals_masked_dense(t, window, group, block, dtype):
+    """The band kernel interpreted on the CPU, result and gradients of
+    q, k and v, against dense masked attention in float32: the first
+    query block (nothing before position 0) is in every case."""
+    from mxnet_tpu.ops.banded_attention import banded_attention_token_major
+    q, k, v, ct = _band_operands(t, group, dtype)
+    f32 = [_token_major(a.astype(jnp.float32)) for a in (q, k, v, ct)]
+
+    def band(q, k, v):
+        return banded_attention_token_major(
+            q, k, v, window=window, block=block, backend="band_interpret")
+
+    got, vjp = jax.vjp(band, q, k, v)
+    want, vjp_dense = jax.vjp(
+        lambda *a: _dense_attention(*a, window), *f32[:3])
+    assert got.dtype == dtype and got.shape == q.shape
+    tol = 2e-5 if dtype == jnp.float32 else 0.05
+    for a, b in zip((got,) + vjp(ct), (want,) + vjp_dense(f32[3])):
+        assert a.dtype == dtype
+        gap = jnp.max(jnp.abs(_token_major(a.astype(jnp.float32)) - b))
+        assert float(gap) <= tol * float(jnp.max(jnp.abs(b)))
+
+
+@pytest.mark.parametrize("t,d,window,takes", [
+    (8192, 128, 512, True),       # the Laguna cell's window layers
+    (8192, 256, 512, True),
+    (2048, 128, 1024, True),
+    (8192, 128, None, False),     # no window
+    (512, 128, 512, False),       # a window that reaches the whole sequence
+    (8192, 64, 512, False),       # a head narrower than the lanes
+    (8192, 128, 500, False),      # a window of no whole sub-blocks
+    (8192, 128, 2048, False),     # a window past BAND_MAX_WINDOW
+    (8192 + 256, 128, 512, False),    # T of no whole query blocks
+    (2560, 128, 1024, False),     # nor of whole windows, where longer
+], ids=["cell", "d-256", "w-1024", "no-window", "window-is-t", "d-64",
+        "window-500", "window-2048", "odd-t", "odd-t-w-1024"])
+def test_band_available_is_a_rule_on_the_shapes(monkeypatch, t, d, window,
+                                                takes):
+    from mxnet_tpu.ops import banded_attention as ba
+    assert ba.band_available(t, d, window) is takes
+    # the cell's 8 query heads a key/value head fit; 64 at a window of
+    # 512 would not
+    assert ba.band_available(t, d, window, group=8) is takes
+    assert not ba.band_available(t, d, window, group=64 if window != 128
+                                 else 128)
+    assert ba.default_backend(t, d, window) == "xla"       # no TPU here
+    assert ba.default_backend(t, d) == "xla"
+    monkeypatch.setattr(ba.jax, "default_backend", lambda: "tpu")
+    assert ba.default_backend(t, d, window) == (
+        "band" if takes else "splash" if d != 96 and t % 128 == 0
+        else "xla")
+    # a caller that does not say its window never gets the band kernel
+    assert ba.default_backend(t, d) != "band"
+
+
+@pytest.mark.parametrize("t,d,window", [
+    (40, 8, 16), (48, 16, None), (256, 128, 128), (384, 128, 100)],
+    ids=["band", "causal", "window-is-t", "window-100"])
+def test_token_major_equals_banded_attention_transposed(t, d, window):
+    """Off the chip, and for every shape the band kernel does not take,
+    the token-major entry is ``banded_attention`` between two
+    transposes: the same numbers, result and gradients."""
+    from mxnet_tpu.ops.banded_attention import (band_available,
+                                                banded_attention_token_major)
+    q, k, v, ct = _band_operands(t, 3, jnp.float32, d=d)
+    assert not band_available(t, d, window)
+
+    def both(fn, *ops):
+        out, vjp = jax.vjp(fn, *ops[:3])
+        return (out,) + vjp(ops[3])
+
+    got = both(lambda *a: banded_attention_token_major(*a, window=window),
+               q, k, v, ct)
+    want = both(lambda *a: banded_attention(*a, window=window),
+                *[_token_major(a) for a in (q, k, v, ct)])
+    for a, b in zip(got, want):
+        assert onp.array_equal(a, _token_major(b))
+    with pytest.raises(ValueError, match="band kernel takes no window"):
+        banded_attention_token_major(q, k, v, window=window, backend="band")
+
+
+def test_banded_attention_takes_the_band_backend_on_heads_major_operands():
+    q, k, v, ct = [_token_major(a) for a in
+                   _band_operands(512, 2, jnp.float32)]
+    got = banded_attention(q, k, v, window=256, backend="band_interpret")
+    assert onp.allclose(got, _dense_attention(q, k, v, 256), atol=2e-5)

@@ -3,6 +3,7 @@
 CPU, against the plain reference that the benchmark keeps
 (``benchmark/families/laguna.py``: the one copy, as for ``bert_base``).
 """
+import functools
 import json
 import math
 import os
@@ -228,3 +229,94 @@ def test_scope_names_are_in_the_compiled_step():
                    for p in paths)
     assert not any(scope_paths.holds(p, ("layers", "1", "attn", "full"))
                    for p in paths)
+
+
+def _traced_counts():
+    from mxnet_tpu.telemetry import metrics
+    return {label: metrics.counter(
+        f"attention_traced_total.{label}").value()
+        for label in ("band", "kernel", "dense")}
+
+
+@pytest.mark.parametrize("on_tpu,head_dim,window,label", [
+    (False, 128, 128, "dense"),    # no chip: the composition, whatever
+    (True, 128, 128, "band"),      # a window the band kernel takes
+    (True, 128, 200, "kernel"),    # one it does not: jax's splash kernel
+    (True, 128, None, "kernel"),   # a full layer
+    (True, 16, 128, "dense"),      # a head no kernel takes
+], ids=["cpu", "band", "window-200", "full", "head-16"])
+def test_attention_counts_its_traced_backend(monkeypatch, on_tpu, head_dim,
+                                             window, label):
+    """A call of ``LagunaAttention`` bumps
+    ``attention_traced_total.<label>`` once, with the label of what
+    ``ops.banded_attention``'s rule gives at the call's shape, and runs
+    that (a chip is pretended by the rule's answers with the kernels
+    interpreted); each gives the composition's numbers."""
+    from mxnet_tpu.models import laguna as family
+    from mxnet_tpu.ops import banded_attention as ba
+    from mxnet_tpu.telemetry import metrics
+
+    def on_a_chip(t, d, window=None, group=1):
+        if ba.band_available(t, d, window, group):
+            return "band_interpret"
+        return "splash_interpret" if ba.splash_available(t, d) else "xla"
+
+    rope = {"rope_type": "default", "rope_theta": 10000.0}
+    attn = family.LagunaAttention(32, 2, 1, head_dim, rope, window=window)
+    attn.initialize(ctx=mx.cpu(0))
+    x = mx.nd.array(onp.random.RandomState(3).randn(1, 512, 32)
+                    .astype("float32"))
+    want = attn(x).asnumpy()            # resolves the deferred shapes
+    found = _traced_counts()
+    try:
+        if on_tpu:
+            monkeypatch.setattr(family, "default_backend", on_a_chip)
+        before = _traced_counts()
+        got = attn(x).asnumpy()
+        after = _traced_counts()
+    finally:
+        # the process's record is the benchmark readers' too
+        for name, value in found.items():
+            counter = metrics.counter(f"attention_traced_total.{name}")
+            counter.reset()
+            counter.inc(value)
+    assert {k: after[k] - before[k] for k in after} == \
+        {k: int(k == label) for k in after}
+    assert onp.allclose(got, want, atol=2e-5)
+
+
+@pytest.mark.parametrize("t,window,heads,kv_heads,dtype,rotary_share", [
+    (1024, 512, 4, 2, jnp.float32, 0.5),
+    (512, 128, 2, 1, jnp.float32, 1.0),      # T of one query block
+    (1024, 256, 8, 1, jnp.bfloat16, 0.5),    # the cell's group and dtype
+], ids=["w-512", "one-block", "bf16-group-8"])
+def test_the_band_kernel_turns_and_gates_as_the_composition_does(
+        t, window, heads, kv_heads, dtype, rotary_share):
+    """A sliding layer's block (rotary positions on q and k, attention,
+    the per-head gate) through the band kernel, which does all three in
+    one pass, interpreted: the result and the gradients of q, k, v and
+    the gate logits of the block as the XLA composition computes it."""
+    from mxnet_tpu.models import laguna as family
+    d = 128
+    cos, sin, turn = family.rotary_tables(
+        t, d, {"rope_type": "default", "rope_theta": 10000.0,
+               "partial_rotary_factor": rotary_share})
+    ks = jax.random.split(jax.random.key(t + heads), 5)
+    shapes = [(1, t, heads * d), (1, t, kv_heads * d), (1, t, kv_heads * d),
+              (1, t, heads), (1, t, heads * d)]
+    *ops, ct = [jax.random.normal(key, s, jnp.float32).astype(dtype)
+                for key, s in zip(ks, shapes)]
+
+    def run(backend):
+        out, vjp = jax.vjp(functools.partial(
+            family._gated_attention, cos=cos, sin=sin, turn=turn,
+            heads=heads, kv_heads=kv_heads, head_dim=d, window=window,
+            backend=backend), *ops)
+        return (out,) + vjp(ct)
+
+    tol = 1e-5 if dtype == jnp.float32 else 0.03
+    for a, b in zip(run("band_interpret"), run("xla")):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        assert float(jnp.max(jnp.abs(a - b))) <= tol * float(
+            jnp.max(jnp.abs(b)))

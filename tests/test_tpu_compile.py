@@ -146,6 +146,53 @@ def test_banded_attention_compiles_as_a_kernel(one_chip, heads, window):
     assert text.count("tpu_custom_call") >= 2
 
 
+def test_a_sliding_layer_compiles_as_the_band_kernel_alone(one_chip):
+    """The Laguna cell's window layer between its projections (rotary
+    positions on q and k, attention over a window of 512, the per-head
+    gate; 8192 tokens, 64 query heads over 8 key/value heads of 128,
+    bfloat16), result and gradients: the band kernel forward and
+    backward, which reads the heads where the projections leave them.
+    Beside the operands and the kernels' results the program holds no
+    array of q's size in any dtype: no transpose, no relayout copy, no
+    pad, no scaled or float32 copy of q, o, do or dq."""
+    import functools
+    from mxnet_tpu.models import laguna
+    t, heads, kv_heads, d = 8192, 64, 8, 128
+    cos, sin, turn = laguna.rotary_tables(
+        t, d, {"rope_type": "default", "rope_theta": 10000.0,
+               "partial_rotary_factor": 0.5})
+    block = functools.partial(
+        laguna._gated_attention, cos=cos, sin=sin, turn=turn, heads=heads,
+        kv_heads=kv_heads, head_dim=d, window=512, backend="band")
+
+    def both(q, k, v, gate, ct):
+        out, vjp = jax.vjp(block, q, k, v, gate)
+        return (out,) + vjp(ct)
+
+    bf16 = jnp.bfloat16
+    args = _shapes(one_chip, ((1, t, heads * d), bf16),
+                   ((1, t, kv_heads * d), bf16), ((1, t, kv_heads * d), bf16),
+                   ((1, t, heads), bf16), ((1, t, heads * d), bf16))
+    text = jax.jit(both).lower(*args).compile().as_text()
+    for kernel in ("band_attention_fwd", "band_attention_bwd"):
+        assert re.search(rf"{kernel}\S* = .*custom-call", text), kernel
+    # what the entry computation holds is what lies in device memory
+    # (a fused computation's own instructions never leave the chip's
+    # fast memory)
+    entry = text[text.index("ENTRY"):]
+    big = []
+    for m in re.finditer(r"%?([\w.\-]+) = (\w+)\[([\d,]+)\]\S* "
+                         r"([\w\-]+)\(", entry):
+        name, dtype, dims, op = m.groups()
+        size = 1
+        for n in dims.split(","):
+            size *= int(n)
+        if size >= heads * t * d and op not in ("parameter",
+                                                "get-tuple-element"):
+            big.append((op, dtype, dims, name))
+    assert not big, big
+
+
 def test_head_64_attention_compiles_as_a_kernel(one_chip):
     """The LFM2 cell's full layer (8192 tokens, 32 query heads over 8
     key/value heads of 64): the splash kernel over heads padded to the

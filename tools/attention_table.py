@@ -4,6 +4,7 @@ backward: the measurement behind ``flash_attention_available``'s rule.
 
     python tools/attention_table.py [--bh 192] [--block 1] [--causal 1]
     python tools/attention_table.py --head64 1 [--block 1]
+    python tools/attention_table.py --window 1 [--way band]
 
 One JSON line a case: ``{"t", "d", "dtype", "causal", "kernel_ms",
 "dense_ms"}`` (a side that does not fit the device reads null), the
@@ -18,6 +19,16 @@ backward, as jax's splash kernel over heads zero-padded to 128 lanes
 (``splash_padded_ms``), as this repo's ``flash_attention`` with the
 key/value heads repeated (``flash_ms``) and as the XLA composition
 (``xla_ms``).
+``--window 1`` is the table behind the band kernel's rule and its
+constants (``ops/banded_attention.py BAND_BLOCK``, ``BAND_SUB``): the
+Laguna cell's window layer (64 query heads over 8 key/value heads of
+128, 8192 tokens, window 512, bfloat16), forward plus backward, one JSON
+line a way (``{"way", "alone_ms", "in_block_ms"}``): the band kernel on
+token-major heads at each query block tried, jax's splash
+kernel at blocks of 512 with two backward kernels (what ran before PR
+34) and at 1024 with the fused one, and the XLA composition;
+``in_block_ms`` between a q/k/v and an output projection of 2048, less
+the projections alone.
 Times are the device's own, from a profile of the calls; a CPU run
 refuses to start (its times would say nothing about the chip).
 """
@@ -171,6 +182,81 @@ def head64(reps, block):
     print(json.dumps(row), flush=True)
 
 
+def window_table(reps, only=""):
+    """One JSON line a way to the Laguna cell's window layer (the ways
+    whose name holds ``only``); see the module docstring."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk, splash_attention_mask as sm)
+    from mxnet_tpu.ops.banded_attention import (
+        banded_attention, banded_attention_token_major)
+    t, h, kv, d, c, window = 8192, 64, 8, 128, 2048, 512
+    bf16 = jnp.bfloat16
+    keys = jax.random.split(jax.random.key(512), 5)
+
+    def token_major(attn):
+        """``attn`` on (1, T, H * D) operands as the projections leave
+        them (a way's relayouts are its own cost)."""
+        def run(q, k, v):
+            return attn(q.reshape(1, t, h, d), k.reshape(1, t, kv, d),
+                        v.reshape(1, t, kv, d)).reshape(1, t, h * d)
+        return run
+
+    def heads_major(attn):
+        def run(q, k, v):
+            o = attn(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+                     v.transpose(0, 2, 1, 3))
+            return o.transpose(0, 2, 1, 3)
+        return token_major(run)
+
+    def splash_fused(q, k, v):
+        b = 1024
+        kernel = sk.make_splash_mqa_single_device(
+            mask=sm.MultiHeadMask([sm.LocalMask(
+                (t, t), window_size=(window - 1, 0), offset=0)] * (h // kv)),
+            block_sizes=sk.BlockSizes(
+                block_q=b, block_kv=b, block_kv_compute=b, block_q_dkv=b,
+                block_kv_dkv=b, block_kv_dkv_compute=b,
+                use_fused_bwd_kernel=True))
+        q = q * jnp.asarray(d ** -0.5, q.dtype)
+        o = jax.vmap(jax.vmap(kernel))(q.reshape(1, kv, h // kv, t, d), k, v)
+        return o.reshape(1, h, t, d)
+
+    ways = {f"band_{bq}": token_major(functools.partial(
+        banded_attention_token_major, window=window, block=bq,
+        backend="band")) for bq in (512, 1024, 2048)}
+    ways["splash_512_two_backward_kernels"] = heads_major(functools.partial(
+        banded_attention, window=window, backend="splash"))
+    ways["splash_1024_fused_backward"] = heads_major(splash_fused)
+    ways["xla_composition"] = heads_major(functools.partial(
+        banded_attention, window=window, backend="xla"))
+
+    def model(attn):
+        def run(x, wq, wkv, wo):
+            q, kvs = x @ wq, x @ wkv
+            o = attn(q, kvs[..., :kv * d], kvs[..., kv * d:]) if attn else q
+            return o @ wo
+        return run
+
+    shapes = ((1, t, c), (c, h * d), (c, 2 * kv * d), (h * d, c), (1, t, c))
+    block_ops = [(jax.random.normal(key, s, jnp.float32)
+                  * (1.0 if i in (0, 4) else c ** -0.5)).astype(bf16)
+                 for i, (key, s) in enumerate(zip(keys, shapes))]
+    shapes = ((1, t, h * d), (1, t, kv * d), (1, t, kv * d), (1, t, h * d))
+    ops = [jax.random.normal(key, s, jnp.float32).astype(bf16)
+           for key, s in zip(keys, shapes)]
+    projections = time_ms(fwd_bwd(model(None)), block_ops, reps)
+    print(json.dumps({"way": "projections_alone",
+                      "in_block_ms": projections}), flush=True)
+    for way, attn in ways.items():
+        if only not in way:
+            continue
+        inside = time_ms(fwd_bwd(model(attn)), block_ops, reps)
+        print(json.dumps({
+            "way": way, "alone_ms": time_ms(fwd_bwd(attn), ops, reps),
+            "in_block_ms": None if inside is None else inside - projections,
+        }), flush=True)
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--bh", type=int, default=192)
@@ -179,11 +265,16 @@ def main():
     ap.add_argument("--check", type=int, default=0)
     ap.add_argument("--block", type=int, default=0)
     ap.add_argument("--head64", type=int, default=0)
+    ap.add_argument("--window", type=int, default=0)
+    ap.add_argument("--way", default="", help="with --window: only the "
+                    "ways whose name holds this")
     args = ap.parse_args()
     if jax.devices()[0].platform != "tpu":
         sys.exit("attention_table.py measures a chip; none is attached")
     if args.head64:
         return head64(args.reps, args.block)
+    if args.window:
+        return window_table(args.reps, args.way)
     causal = bool(args.causal)
     if args.check:
         return errors(args.bh, causal)
