@@ -11,3 +11,7 @@ from . import lfm2  # noqa: F401
 from .lfm2 import (  # noqa: F401
     Lfm2ShortConv, Lfm2Attention, Lfm2DecoderLayer, Lfm2MoeLM,
 )
+from . import ling  # noqa: F401
+from .ling import (  # noqa: F401
+    LingKDA, LingLatentAttention, LingDecoderLayer, LingHybridLM,
+)

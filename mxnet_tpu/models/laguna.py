@@ -21,14 +21,15 @@ net from such a dict. Per layer ``x + attn(norm(x))`` then
   ``mlp_layer_types`` says ``dense`` and
   ``parallel.moe.RoutedExpertsFFN`` (``moe``) where it says ``sparse``:
   the layer is told which of the ``num_experts_routed`` experts it
-  holds. Of the expert layer's two scoring rules this family uses
+  holds. Of the expert layer's three scoring rules this family uses
   ``"softmax"`` (the default: softmax over all the router's outputs, the
   k largest renormalised, times the scaling factor, with a shared
-  expert); ``"sigmoid"`` with a selection bias is ``models/lfm2.py``'s.
-- ``DecoderLayer`` is the pre-norm residual skeleton both decoder
-  families here build their layers from; ``RMSNorm``, ``LMHead``,
-  ``rotary_tables`` and the rotation are shared with ``models/lfm2.py``
-  too.
+  expert); ``"sigmoid"`` with a selection bias is ``models/lfm2.py``'s,
+  the same limited to a token's best groups ``models/ling.py``'s.
+- ``DecoderLayer`` is the pre-norm residual skeleton the three decoder
+  families here build their layers from; ``RMSNorm``, ``LMHead`` and
+  the rotation are shared with ``models/lfm2.py`` (``rotary_tables``
+  too) and ``models/ling.py`` (the per-head gate too).
 
 Scope names in a traced program (``jax.named_scope`` under the blocks'
 attribute names): ``layers/<i>/attn/window`` or ``.../attn/full``
@@ -263,7 +264,7 @@ class LMHead(HybridBlock):
 
 
 class DecoderLayer(HybridBlock):
-    """The pre-norm residual layer of the decoders here:
+    """The pre-norm residual layer of the three decoder families here:
     ``x + mixer(norm(x))`` then ``x + ffn(norm(x))``. ``mixer`` and
     ``ffn`` are ``(attribute name, maker)`` pairs, built under the
     layer's name scope and hung under those names (which name them in a

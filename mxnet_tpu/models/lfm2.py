@@ -6,7 +6,7 @@ The blocks follow the published ``config.json`` of
 ``LiquidAI/LFM2-8B-A1B`` (``model_type: lfm2_moe``) key by key;
 ``Lfm2MoeLM.from_config`` builds the net from such a dict. Per layer
 ``x + op(norm(x))`` then ``x + ffn(norm(x))`` (``laguna.DecoderLayer``,
-the skeleton both decoder families share), a final RMSNorm, logits
+the skeleton the decoder families share), a final RMSNorm, logits
 ``h E^T`` with ``E`` the embedding matrix.
 
 - ``Lfm2ShortConv`` (``layer_types[i] == "conv"``): ``in_proj`` to

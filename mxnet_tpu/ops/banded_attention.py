@@ -10,6 +10,9 @@ backward.
 Layout: ``q`` (B, Hq, T, D), ``k`` / ``v`` (B, Hkv, T, D), ``Hq`` a
 multiple of ``Hkv``; query head ``h`` reads key/value head ``h // G``
 (``banded_attention_token_major``: the same with T before the heads).
+``v``'s head size may differ from ``q``'s and ``k``'s (latent
+attention: 192-wide scores over 128-wide values); the result has
+``v``'s.
 A query at position ``t`` sees keys ``t - window + 1 .. t`` (all of
 ``0 .. t`` without a window). Scores and softmax are float32; the
 probabilities meet ``v`` in ``v``'s dtype.
@@ -34,8 +37,8 @@ the composition above (``backend="xla"`` forces it). On a TPU:
   sequence), and a window the band kernel does not take: the Pallas
   splash-attention kernel that ships with jax (block-sparse over the
   same mask, scores never leave fast memory) where ``splash_available``
-  says so: head sizes of 128 lanes or multiples, and 64, zero-padded to
-  the lanes;
+  says so: head sizes of 128 lanes or multiples, and 64 and 192,
+  zero-padded to the lanes;
 - a sliding window on heads as the projections leave them
   (``banded_attention_token_major``: (B, T, H, D), no transpose on
   either side) where ``band_available`` says so: this repo's band
@@ -115,9 +118,9 @@ def _band_group(q, k, v, window, block, scale):
     nb, nk = t // block, band_blocks(window, block)
 
     def band(a):
-        a = a.reshape(nb, block, d)
+        a = a.reshape(nb, block, a.shape[-1])
         a = jnp.concatenate(
-            [jnp.zeros((nk - 1, block, d), a.dtype), a], axis=0)
+            [jnp.zeros((nk - 1,) + a.shape[1:], a.dtype), a], axis=0)
         return jnp.concatenate([a[j:j + nb] for j in range(nk)], axis=1)
 
     kb, vb = band(k), band(v)                      # (nb, nk * block, D)
@@ -130,7 +133,7 @@ def _band_group(q, k, v, window, block, scale):
         + jnp.arange(nk * block)[None, None, :]
     mask = (kpos <= qpos) & (qpos - kpos < window) & (kpos >= 0)
     o = _softmax_av(s, mask[None], vb, "gnqk,nkd->gnqd")
-    return o.reshape(g, t, d).astype(q.dtype)
+    return o.reshape(g, t, v.shape[-1]).astype(q.dtype)
 
 
 def _causal_group(q, k, v, block, scale):
@@ -167,38 +170,50 @@ def _xla_attention(q, k, v, window, block, scale):
         group = functools.partial(_causal_group, block=block, scale=scale)
     group = jax.checkpoint(group)
     qg = q.reshape(b * hkv, g, tp, d)
-    kg, vg = k.reshape(b * hkv, tp, d), v.reshape(b * hkv, tp, d)
+    kg, vg = k.reshape(b * hkv, tp, d), v.reshape(b * hkv, tp, v.shape[-1])
     o = jax.lax.map(lambda a: group(*a), (qg, kg, vg))
-    return o.reshape(b, hq, tp, d)[:, :, :t]
+    return o.reshape(b, hq, tp, v.shape[-1])[:, :, :t]
 
 
 # ---------------------------------------------------------------------------
 # the Pallas kernel (TPU): jax's splash attention over the same mask
 # ---------------------------------------------------------------------------
 
-def splash_available(t, d) -> bool:
+def _pads_to_lanes(d):
+    return d % 128 == 0 or d in (64, 192)
+
+
+def splash_available(t, d, dv=None) -> bool:
     """The kernel takes sequence lengths that are multiples of 128 and
-    head sizes that are multiples of 128, or 64: a 64-wide head runs
-    zero-padded to the 128 lanes (``_splash_attention``), which at 32
-    query heads over 8 of 64, 8192 tokens, causal, forward and backward
-    on a v5e took 15.7 ms against 17.6 for the composition below and
-    17.5 for ``pallas_kernels.flash_attention`` over repeated key/value
-    heads (``tools/attention_table.py --head64 1``; PERF.md section 6,
-    PR 32). Narrower heads were not measured and take the
+    head sizes (``d`` of q and k, ``dv`` of v where it differs) that are
+    multiples of 128, or 64, or 192: a narrower head runs zero-padded to
+    whole lanes of 128 (``_splash_attention``). 64: at 32 query heads
+    over 8 of 64, 8192 tokens, causal, forward and backward on a v5e
+    the padded kernel took 15.7 ms against 17.6 for the composition
+    below and 17.5 for ``pallas_kernels.flash_attention`` over repeated
+    key/value heads (``tools/attention_table.py --head64 1``; PERF.md
+    section 6, PR 32). 192 over values of 128 (latent attention: 32
+    heads, 4096 tokens, causal, forward and backward): q and k padded
+    to 256 lanes, the values' width kept, 8.03 ms alone and 8.23
+    between the projections against 11.38 and 13.39 for the composition
+    (``tools/attention_table.py --latent 1``; PERF.md section 6, PR
+    35). Other head sizes were not measured and take the
     composition."""
-    return (d % 128 == 0 or d == 64) and t % 128 == 0 and t >= 128
+    return _pads_to_lanes(d) and _pads_to_lanes(dv or d) \
+        and t % 128 == 0 and t >= 128
 
 
-def default_backend(t, d, window=None, group=1) -> str:
+def default_backend(t, d, window=None, group=1, dv=None) -> str:
     """What runs where the caller names no backend: on a TPU the band
     kernel for a ``window`` and a ``group`` it takes (``band_available``;
     token-major callers alone say theirs here), the splash kernel where
-    the shapes allow it, else the composition."""
+    the shapes allow it (``dv``: the values' head size where it is not
+    ``d``), else the composition."""
     if jax.default_backend() != "tpu":
         return "xla"
-    if band_available(t, d, window, group):
+    if band_available(t, d, window, group) and dv in (None, d):
         return "band"
-    return "splash" if splash_available(t, d) else "xla"
+    return "splash" if splash_available(t, d, dv) else "xla"
 
 
 @functools.lru_cache(maxsize=None)
@@ -231,14 +246,19 @@ def _splash_attention(q, k, v, window, block, scale, interpret=False):
     g = hq // hkv
     kernel = _splash_kernel(g, t, window, block, interpret)
     q = q * jnp.asarray(scale, q.dtype)
-    lanes = -(-d // 128) * 128
-    if lanes != d:
-        # a head narrower than the lanes: zeros past its dimensions add
-        # nothing to a score and give result columns that are cut off
-        pad = [(0, 0), (0, 0), (0, 0), (0, lanes - d)]
-        q, k, v = jnp.pad(q, pad), jnp.pad(k, pad), jnp.pad(v, pad)
-    o = jax.vmap(jax.vmap(kernel))(q.reshape(b, hkv, g, t, lanes), k, v)
-    return o.reshape(b, hq, t, lanes)[..., :d]
+    dv = v.shape[-1]
+
+    def to_lanes(a):
+        # a head that fills no whole lanes: zeros past its dimensions
+        # add nothing to a score and give result columns that are cut
+        # off
+        short = -a.shape[-1] % 128
+        return jnp.pad(a, [(0, 0)] * 3 + [(0, short)]) if short else a
+
+    q, k, v = to_lanes(q), to_lanes(k), to_lanes(v)
+    o = jax.vmap(jax.vmap(kernel))(
+        q.reshape(b, hkv, g, t, q.shape[-1]), k, v)
+    return o.reshape(b, hq, t, v.shape[-1])[..., :dv]
 
 
 # ---------------------------------------------------------------------------
@@ -724,7 +744,7 @@ def banded_attention(q, k, v, window=None, block=None, scale=None,
             block=block, scale=scale, backend=backend))
     scale = 1.0 / math.sqrt(q.shape[-1]) if scale is None else scale
     if backend is None:
-        backend = default_backend(*q.shape[2:])
+        backend = default_backend(*q.shape[2:], dv=v.shape[-1])
     if backend in ("splash", "splash_interpret"):
         return _splash_attention(q, k, v, window, block, scale,
                                  interpret=backend == "splash_interpret")

@@ -11,12 +11,16 @@ tokens x k: it is a small-model convenience, not expert parallelism.
 ``RoutedExpertsFFN`` is the expert-parallel layer proper, as one chip
 of an expert-parallel deployment runs it: it is told which experts it
 holds (``experts_held``), routes every token over ALL ``num_experts``
-by one of two scoring rules (``route_top_k``; the layer's ``scoring``):
-``"softmax"`` (softmax, top-k, renormalised over the k, times a scaling
-factor: ``models.LagunaLM``) or ``"sigmoid"`` (sigmoid scores, the k
-largest of score plus a selection bias that no optimizer trains, the
-unbiased scores renormalised with an epsilon, times the factor:
-``models.Lfm2MoeLM``); whatever the rule, it
+by one of three scoring rules (``route_top_k``; the layer's ``scoring``
+and ``groups``): ``"softmax"`` (softmax, top-k, renormalised over the k,
+times a scaling factor: ``models.LagunaLM``), ``"sigmoid"`` (sigmoid
+scores, the k largest of score plus a selection bias that no optimizer
+trains, the unbiased scores renormalised with an epsilon, times the
+factor: ``models.Lfm2MoeLM``) or the sigmoid rule limited to groups
+(``groups=(n_group, topk_group)``: the experts stand in ``n_group``
+groups of consecutive ids, the ``topk_group`` groups with the largest
+sum of their two best biased scores stay, and the k are chosen inside
+them: ``models.LingHybridLM``); whatever the rule, it
 sorts the (token, expert) rows by expert, computes the held experts'
 SiLU-gated FFNs as grouped products over the sorted rows, and combines
 by routing weight. Rows routed to experts held elsewhere are left out
@@ -29,7 +33,8 @@ here takes further passes of the same buffer under a device-side loop
 (``_held_experts``), so no row is ever dropped and shapes are static
 whatever the routing; where the layer holds every expert the buffer
 holds the worst case and there is no loop. A shared expert
-(``shared_hidden``) runs on every token, unweighted.
+(``shared_hidden``) runs on every token, unweighted, under any of the
+rules.
 
     layer = MoEFFN(units=256, hidden_size=1024, num_experts=8,
                    num_experts_per_tok=2)
@@ -42,6 +47,11 @@ holds the worst case and there is no loop. A shared expert
     layer = RoutedExpertsFFN(units=2048, hidden_size=1792,
                              num_experts=32, num_experts_per_tok=4,
                              experts_held=range(0, 8), scoring="sigmoid")
+    layer = RoutedExpertsFFN(units=2560, hidden_size=768,
+                             num_experts=512, num_experts_per_tok=8,
+                             experts_held=range(0, 8), scoring="sigmoid",
+                             groups=(8, 4), routed_scaling=2.5,
+                             shared_hidden=768)
 """
 from __future__ import annotations
 
@@ -168,13 +178,30 @@ def expert_parallel_shardings(block, expert_axis: str = "model"):
 # ---------------------------------------------------------------------------
 
 # what the sigmoid rule adds to the sum of a token's chosen scores
-# before it divides by it
+# before it divides by it (LFM2's value; Ling's source writes 1e-20,
+# which on a sum of eight sigmoids differs from this by less than 2 ulp
+# of float32: one constant serves both)
 SIGMOID_ROUTER_EPS = 1e-6
 
 
-def route_top_k(x, router_w, k, scale, bias=None):
+def _inside_best_groups(biased, groups):
+    """``biased`` (N, E) with the experts outside a token's best groups
+    at minus infinity. ``groups = (n_group, topk_group)``: the E experts
+    stand in ``n_group`` groups of consecutive ids; a group's score is
+    the sum of its two largest entries; the ``topk_group`` groups with
+    the largest scores stay (the lower index wins a tie)."""
+    n_group, topk_group = groups
+    n, e = biased.shape
+    best_two, _ = jax.lax.top_k(biased.reshape(n, n_group, e // n_group), 2)
+    _, kept = jax.lax.top_k(jnp.sum(best_two, axis=-1), topk_group)
+    stays = jnp.any(kept[..., None] == jnp.arange(n_group), axis=1)
+    return jnp.where(jnp.repeat(stays, e // n_group, axis=-1), biased,
+                     -jnp.inf)
+
+
+def route_top_k(x, router_w, k, scale, bias=None, groups=None):
     """``(weights, expert ids)``, each (N, k), from the router's outputs
-    over all its experts in float32, by one of two scoring rules.
+    over all its experts in float32, by one of three scoring rules.
 
     Softmax (``bias`` None): softmax over the outputs, the k largest
     (the lower index wins a tie), renormalised over the k, times
@@ -186,7 +213,12 @@ def route_top_k(x, router_w, k, scale, bias=None):
     are the UNBIASED ``s`` over (their sum + ``SIGMOID_ROUTER_EPS``),
     times ``scale``. The gradient reaches the router through ``s`` of
     the chosen experts and the renormalisation, never through the
-    bias."""
+    bias.
+
+    The same limited to groups (``groups = (n_group, topk_group)``
+    beside a ``bias``): the k are chosen among the experts of the
+    token's ``topk_group`` best groups (``_inside_best_groups``, on
+    ``s + bias``); the weights as above."""
     f32 = jnp.float32
     logits = jnp.dot(x.astype(f32), router_w.astype(f32).T,
                      precision=jax.lax.Precision.HIGHEST)
@@ -195,8 +227,10 @@ def route_top_k(x, router_w, k, scale, bias=None):
         top_p, top_i = jax.lax.top_k(probs, k)
         return top_p / jnp.sum(top_p, axis=-1, keepdims=True) * scale, top_i
     scores = jax.nn.sigmoid(logits)
-    _, top_i = jax.lax.top_k(
-        scores + jax.lax.stop_gradient(bias.astype(f32)), k)
+    biased = scores + jax.lax.stop_gradient(bias.astype(f32))
+    if groups is not None:
+        biased = _inside_best_groups(biased, groups)
+    _, top_i = jax.lax.top_k(biased, k)
     # the chosen scores by a one-hot select over the experts: a pass,
     # and its transpose another, where a gather's is a scatter-add
     chosen = top_i[..., None] == jnp.arange(scores.shape[-1])
@@ -486,22 +520,23 @@ _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "k", "num_held", "held_start", "scale"))
+    "k", "num_held", "held_start", "scale", "groups"))
 def routed_experts(x, router_w, w_gate, w_up, w_down, bias=None, *, k,
-                   held_start, num_held, scale):
+                   held_start, num_held, scale, groups=None):
     """The held experts' part of a top-k routed SiLU-gated FFN.
 
     x: (N, C); router_w: (E_all, C); w_gate / w_up: (E_held, C, F);
     w_down: (E_held, F, C); the layer holds experts ``held_start ..
     held_start + num_held`` of the router's ``E_all``; ``bias`` (E_all,)
-    picks ``route_top_k``'s sigmoid rule. Returns (N, C):
+    picks ``route_top_k``'s sigmoid rule and ``groups`` its group limit.
+    Returns (N, C):
     for every token the weighted sum over its choices that are held
     here. The buffer of sorted rows has ``buffer_rows`` rows; a batch
     that routes more rows here takes further passes (``_held_experts``),
     so every routed row is computed."""
     rows = buffer_rows(x.shape[0] * k, num_held, router_w.shape[0])
     with jax.named_scope("route"):
-        weights, top_i = route_top_k(x, router_w, k, scale, bias)
+        weights, top_i = route_top_k(x, router_w, k, scale, bias, groups)
     with jax.named_scope("dispatch"):
         order, starts, held = _sort_by_group(top_i, held_start, num_held,
                                              rows)
@@ -513,23 +548,38 @@ def routed_experts(x, router_w, w_gate, w_up, w_down, bias=None, *, k,
 
 
 @functools.partial(jax.jit, static_argnames=("k", "num_held",
-                                             "held_start"))
-def routing_counts(x, router_w, bias=None, *, k, held_start, num_held):
+                                             "held_start", "groups"))
+def routing_counts(x, router_w, bias=None, *, k, held_start, num_held,
+                   groups=None):
     """``(rows each held expert gets from the tokens x: (num_held,), the
     expert ids the router chose: (N, k))``."""
-    _, top_i = route_top_k(x, router_w, k, 1.0, bias)
+    _, top_i = route_top_k(x, router_w, k, 1.0, bias, groups)
     keys, _ = _group_keys(top_i, held_start, num_held)
     return _group_sizes(keys, num_held), top_i
 
 
-@functools.partial(jax.jit, static_argnames=("k",))
-def bias_changed_share(x, router_w, bias, *, k):
+def _sets_differ_share(a, b):
+    """The share of rows whose expert ids (N, k) differ as sets."""
+    return jnp.mean(jnp.any(jnp.sort(a, axis=-1) != jnp.sort(b, axis=-1),
+                            axis=-1))
+
+
+@functools.partial(jax.jit, static_argnames=("k", "groups"))
+def bias_changed_share(x, router_w, bias, *, k, groups=None):
     """The share of the tokens ``x`` whose chosen k under the sigmoid
-    rule differ, as a set, from the k largest scores alone."""
-    with_bias = route_top_k(x, router_w, k, 1.0, bias)[1]
-    without = route_top_k(x, router_w, k, 1.0, jnp.zeros_like(bias))[1]
-    return jnp.mean(jnp.any(jnp.sort(with_bias, axis=-1)
-                            != jnp.sort(without, axis=-1), axis=-1))
+    rule differ, as a set, from what a bias of zero would choose."""
+    return _sets_differ_share(
+        route_top_k(x, router_w, k, 1.0, bias, groups)[1],
+        route_top_k(x, router_w, k, 1.0, jnp.zeros_like(bias), groups)[1])
+
+
+@functools.partial(jax.jit, static_argnames=("k", "groups"))
+def group_limit_changed_share(x, router_w, bias, *, k, groups):
+    """The share of the tokens ``x`` whose chosen k under the group
+    limit differ, as a set, from the k largest of score plus bias."""
+    return _sets_differ_share(
+        route_top_k(x, router_w, k, 1.0, bias, groups)[1],
+        route_top_k(x, router_w, k, 1.0, bias)[1])
 
 
 class GatedFFN(HybridBlock):
@@ -558,9 +608,12 @@ class RoutedExpertsFFN(HybridBlock):
     """One chip's share of a top-k routed expert layer (module
     docstring). ``experts_held`` is a contiguous ``range`` of the
     ``num_experts`` the router scores. ``scoring`` is the router's rule
-    (``route_top_k``): ``"softmax"``, or ``"sigmoid"``, which adds the
-    parameter ``expert_bias`` (num_experts,), float32, ``grad_req``
-    ``"null"``: the selection bias, which no optimizer touches. In an
+    (``route_top_k``, three in all): ``"softmax"``, or ``"sigmoid"``,
+    which adds the parameter ``expert_bias`` (num_experts,), float32,
+    ``grad_req`` ``"null"``: the selection bias, which no optimizer
+    touches; the sigmoid rule takes ``groups = (n_group, topk_group)``
+    for its group limit. ``shared_hidden`` builds a shared expert under
+    any rule. In an
     eager forward (not under a trace) the layer records, under
     ``label``, the telemetry gauges ``moe_rows_routed.<label>``,
     ``moe_load_max_over_mean.<label>``, ``moe_buffer_rows.<label>``
@@ -569,13 +622,14 @@ class RoutedExpertsFFN(HybridBlock):
     ``moe_rows_dropped.<label>`` (0: every pass computes its rows) and,
     under the sigmoid rule, ``moe_bias_changed_choice.<label>`` (the
     share of tokens whose chosen experts are not the largest scores
-    alone), and keeps the expert ids its router chose in
-    ``last_expert_ids`` (N, k)."""
+    alone) and, with ``groups``, ``moe_group_limit_changed_choice.<label>``
+    (the share whose choice the group limit changed), and keeps the
+    expert ids its router chose in ``last_expert_ids`` (N, k)."""
 
     def __init__(self, units, hidden_size, num_experts,
                  num_experts_per_tok, experts_held=None,
                  routed_scaling=1.0, shared_hidden=0, label=None,
-                 scoring="softmax", **kwargs):
+                 scoring="softmax", groups=None, **kwargs):
         super().__init__(**kwargs)
         held = range(num_experts) if experts_held is None \
             else experts_held
@@ -590,6 +644,20 @@ class RoutedExpertsFFN(HybridBlock):
         if scoring not in ("softmax", "sigmoid"):
             raise ValueError("scoring must be 'softmax' or 'sigmoid', "
                              f"got {scoring!r}")
+        if groups is not None:
+            n_group, topk_group = groups
+            if scoring != "sigmoid" or num_experts % n_group \
+                    or not 0 < topk_group <= n_group \
+                    or num_experts // n_group < 2 \
+                    or topk_group * (num_experts // n_group) \
+                    < num_experts_per_tok:
+                raise ValueError(
+                    "groups = (n_group, topk_group) goes with sigmoid "
+                    "scoring, n_group whole groups of at least two "
+                    "experts and topk_group of them holding at least "
+                    f"num_experts_per_tok, got {groups!r}")
+            groups = (int(n_group), int(topk_group))
+        self._groups = groups
         self._units, self._k = units, int(num_experts_per_tok)
         self._held_start, self._num_held = held[0], len(held)
         self._scale = float(routed_scaling)
@@ -618,7 +686,7 @@ class RoutedExpertsFFN(HybridBlock):
         shape = x.shape
         flat = x.reshape((-1, shape[-1]))
         geometry = dict(k=self._k, held_start=self._held_start,
-                        num_held=self._num_held)
+                        num_held=self._num_held, groups=self._groups)
         router = [router_weight] if expert_bias is None \
             else [router_weight, expert_bias]
         if not _trace_ctx.active:
@@ -628,9 +696,15 @@ class RoutedExpertsFFN(HybridBlock):
                 self._record(sizes, flat.shape[0], router_weight.shape[0])
                 if expert_bias is not None:
                     from ..telemetry import metrics
+                    choice = dict(k=self._k, groups=self._groups)
                     metrics.gauge(
                         f"moe_bias_changed_choice.{self._label}").set(float(
-                            bias_changed_share(*raw, k=self._k)))
+                            bias_changed_share(*raw, **choice)))
+                    if self._groups is not None:
+                        metrics.gauge("moe_group_limit_changed_choice."
+                                      + self._label).set(float(
+                                          group_limit_changed_share(
+                                              *raw, **choice)))
         # the bias goes last: ``routed_experts``' one optional operand
         out = invoke(functools.partial(
             routed_experts, scale=self._scale, **geometry),

@@ -66,6 +66,21 @@ def _seed():
     _amp.FP32_OPS.update(_saved_fp32)
 
 
+@pytest.fixture(scope="module")
+def layer_gauges_cleaned():
+    """A module whose nets run eager forwards leaves its expert layers'
+    gauges behind under a layer's label (``moe_bias_changed_choice
+    .layers.2``); a later module on the same worker that reads every
+    gauge of a name (the benchmark's readers' tests) would find them.
+    What the module registered under ``moe_`` goes when it ends."""
+    from mxnet_tpu.telemetry import metrics
+    before = set(metrics.all_metrics())
+    yield
+    for name in set(metrics.all_metrics()) - before:
+        if name.startswith("moe_"):
+            metrics.unregister(name)
+
+
 @pytest.fixture
 def load_example():
     """``load_example("gan/dcgan.py")`` imports one script of examples/

@@ -123,7 +123,8 @@ def test_a_head_of_64_runs_the_kernel_padded_to_the_lanes():
     """A 64-wide head (grouped-query, 4 query heads over 2): the splash
     kernel over heads zero-padded to 128 lanes, interpreted on the CPU,
     gives the composition's attention and gradients, in the head's own
-    width; the rule takes 64 and multiples of 128 and nothing else."""
+    width; the rule takes 64, 192 and multiples of 128 and nothing
+    else."""
     from mxnet_tpu.ops.banded_attention import (default_backend,
                                                 splash_available)
     ks = jax.random.split(jax.random.key(11), 4)
@@ -146,6 +147,45 @@ def test_a_head_of_64_runs_the_kernel_padded_to_the_lanes():
     assert not splash_available(8192, 32) and not splash_available(8192, 96)
     assert not splash_available(8200, 64)
     assert default_backend(8192, 64) == "xla"      # no TPU here
+
+
+def test_scores_of_192_over_values_of_128_run_padded_to_the_lanes(
+        monkeypatch):
+    """Latent attention's products: q and k 192 wide, v 128 wide, one
+    key/value head a query head. The composition and the splash kernel
+    (q and k zero-padded to 256 lanes, the values' own width, the scale
+    1 / sqrt(192)), interpreted on the CPU, give dense masked attention
+    and its gradients, 128 wide; the rule takes 192 over 128."""
+    from mxnet_tpu.ops import banded_attention as ba
+    ks = jax.random.split(jax.random.key(192), 4)
+    q = jax.random.normal(ks[0], (1, 2, 256, 192))
+    k = jax.random.normal(ks[1], (1, 2, 256, 192))
+    v = jax.random.normal(ks[2], (1, 2, 256, 128))
+    ct = jax.random.normal(ks[3], (1, 2, 256, 128))
+
+    def run(attn):
+        return jax.value_and_grad(lambda *a: jnp.sum(attn(*a) * ct),
+                                  (0, 1, 2))(q, k, v)
+
+    want, g_want = run(lambda *a: _dense_attention(*a, None))
+    for backend in ("xla", "splash_interpret"):
+        got, g_got = run(lambda *a: banded_attention(*a, block=128,
+                                                     backend=backend))
+        assert banded_attention(q, k, v, backend=backend).shape == v.shape
+        assert float(got) == pytest.approx(float(want), rel=1e-4)
+        for a, b in zip(g_got, g_want):
+            assert a.shape == b.shape
+            assert onp.allclose(a, b, atol=1e-3)
+    assert ba.splash_available(4096, 192, 128)
+    assert ba.splash_available(4096, 192)
+    assert not ba.splash_available(4096, 160, 128)
+    assert not ba.splash_available(4096, 192, 96)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ba.default_backend(4096, 192, dv=128) == "splash"
+    assert ba.default_backend(4096, 160, dv=128) == "xla"
+    # the band kernel takes no head whose values differ from its keys
+    assert ba.default_backend(8192, 128, 512, 8) == "band"
+    assert ba.default_backend(8192, 128, 512, 8, dv=64) == "splash"
 
 
 # ---------------------------------------------------------------------------

@@ -25,6 +25,7 @@ from benchmark import correctness  # noqa: E402
 from benchmark.families import lfm2_moe  # noqa: E402
 
 SEED = 2 ** 31 + 17
+pytestmark = pytest.mark.usefixtures("layer_gauges_cleaned")
 
 
 @pytest.fixture(scope="module")

@@ -187,6 +187,8 @@ CASES = {
         {"num_experts_per_tok": 2}),
     "_moe_load_balance_loss": lambda: ([T(5, 4), T(3, 4)], {}),
     "_short_conv": lambda: ([T(2, 8, 12), T(4, 3)], {}),
+    "_kda": lambda: ([T(2, 5, 3, 4), T(2, 5, 3, 4), T(2, 5, 3, 4),
+                      -abs(T(2, 5, 3, 4)), T(2, 5, 3)], {}),
     "_contrib_calibrate_entropy": lambda: (
         [nd.array(rs.uniform(0, 10, (255,)).astype("float32")),
          nd.array(onp.linspace(-4, 4, 256).astype("float32"))], {}),

@@ -211,6 +211,46 @@ def test_head_64_attention_compiles_as_a_kernel(one_chip):
     assert text.count("tpu_custom_call") >= 2
 
 
+def test_latent_attention_compiles_as_a_kernel(one_chip):
+    """The Ling cell's latent layer (4096 tokens, 32 heads whose scores
+    are 192 wide and whose values are 128 wide): the splash kernel over
+    q and k padded to 256 lanes, the values' own width, forward and
+    backward."""
+    from mxnet_tpu.ops.banded_attention import banded_attention
+
+    def loss(q, k, v):
+        return banded_attention(q, k, v, backend="splash") \
+            .astype(jnp.float32).sum()
+
+    q, k, v = _shapes(one_chip, ((1, 32, 4096, 192), jnp.bfloat16),
+                      ((1, 32, 4096, 192), jnp.bfloat16),
+                      ((1, 32, 4096, 128), jnp.bfloat16))
+    compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, k, v).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 2
+
+
+def test_the_delta_rule_compiles_as_a_scan_over_chunks(one_chip):
+    """The Ling cell's mixer (4096 tokens, 32 heads of 128, bfloat16 q,
+    k, v, float32 decay and beta) under ``jax.checkpoint``, as the
+    mixer that holds the call rematerialises it, forward and backward:
+    a loop over the chunks and no token-by-token state: nothing of
+    T x d x d a head (the recurrence's states, 8.6 GB), and less beside
+    operands and results than a quarter of the chip."""
+    from mxnet_tpu.ops.kda import kda
+
+    shapes = _shapes(one_chip, *([((1, 4096, 32, 128), jnp.bfloat16)] * 3),
+                     ((1, 4096, 32, 128), jnp.float32),
+                     ((1, 4096, 32), jnp.float32),
+                     ((1, 4096, 32, 128), jnp.bfloat16))
+    compiled = jax.jit(lambda q, k, v, log_a, beta, do: jax.vjp(
+        jax.checkpoint(kda), q, k, v, log_a, beta)[1](do)
+                       ).lower(*shapes).compile()
+    text = compiled.as_text()
+    assert "while(" in text
+    assert "4096,32,128,128" not in text and "4096,1,32,128,128" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 4e9
+
+
 def test_short_conv_compiles_without_a_convolution(one_chip):
     """The LFM2 cell's gated short convolution (8192 tokens, 2048
     channels, bfloat16): fusions of shifted adds, no convolution

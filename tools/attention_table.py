@@ -5,6 +5,8 @@ backward: the measurement behind ``flash_attention_available``'s rule.
     python tools/attention_table.py [--bh 192] [--block 1] [--causal 1]
     python tools/attention_table.py --head64 1 [--block 1]
     python tools/attention_table.py --window 1 [--way band]
+    python tools/attention_table.py --latent 1
+    python tools/attention_table.py --kda 1
 
 One JSON line a case: ``{"t", "d", "dtype", "causal", "kernel_ms",
 "dense_ms"}`` (a side that does not fit the device reads null), the
@@ -29,6 +31,18 @@ kernel at blocks of 512 with two backward kernels (what ran before PR
 34) and at 1024 with the fused one, and the XLA composition;
 ``in_block_ms`` between a q/k/v and an output projection of 2048, less
 the projections alone.
+``--latent 1`` is the table behind ``splash_available``'s 192: latent
+attention's products (32 heads, scores 192 wide over values 128 wide,
+4096 tokens, causal, bfloat16), forward plus backward, one JSON line a
+way (``{"way", "alone_ms", "in_block_ms"}``): jax's splash kernel over
+q and k zero-padded to 256 lanes and the XLA composition, alone and
+between the q and key/value projections and the output projection of a
+2560-wide model, less the projections alone.
+``--kda 1`` is the table behind ``ops/kda.py KDA_CHUNK``: the gated
+delta rule at the Ling cell's mixer (4096 tokens, 32 heads of 128,
+bfloat16 q, k, v, float32 decay and beta) under ``jax.checkpoint``, as
+the mixer that holds the call rematerialises it, forward plus backward,
+one JSON line a chunk size (32, 64, 128).
 Times are the device's own, from a profile of the calls; a CPU run
 refuses to start (its times would say nothing about the chip).
 """
@@ -257,6 +271,76 @@ def window_table(reps, only=""):
         }), flush=True)
 
 
+def latent_table(reps):
+    """One JSON line a way to latent attention's products (module
+    docstring)."""
+    from mxnet_tpu.ops.banded_attention import banded_attention
+    t, h, dqk, dv, c, rank = 4096, 32, 192, 128, 2560, 512
+    bf16 = jnp.bfloat16
+    keys = jax.random.split(jax.random.key(192), 6)
+
+    def heads_major(attn):
+        def run(q, k, v):
+            o = attn(q.reshape(1, t, h, dqk).transpose(0, 2, 1, 3),
+                     k.reshape(1, t, h, dqk).transpose(0, 2, 1, 3),
+                     v.reshape(1, t, h, dv).transpose(0, 2, 1, 3))
+            return o.transpose(0, 2, 1, 3).reshape(1, t, h * dv)
+        return run
+
+    def model(attn):
+        def run(x, wq, wk, wv, wo):
+            q = x @ wq
+            o = attn(q, x[..., :rank] @ wk, x[..., :rank] @ wv) if attn \
+                else q[..., :h * dv]
+            return o @ wo
+        return run
+
+    shapes = ((1, t, c), (c, h * dqk), (rank, h * dqk), (rank, h * dv),
+              (h * dv, c), (1, t, c))
+    block_ops = [(jax.random.normal(key, s, jnp.float32)
+                  * (1.0 if i in (0, 5) else s[0] ** -0.5)).astype(bf16)
+                 for i, (key, s) in enumerate(zip(keys, shapes))]
+    shapes = ((1, t, h * dqk), (1, t, h * dqk), (1, t, h * dv),
+              (1, t, h * dv))
+    ops = [jax.random.normal(key, s, jnp.float32).astype(bf16)
+           for key, s in zip(keys, shapes)]
+    projections = time_ms(fwd_bwd(model(None)), block_ops, reps)
+    print(json.dumps({"way": "projections_alone",
+                      "in_block_ms": projections}), flush=True)
+    for way, backend in (("splash_padded_to_256", "splash"),
+                         ("xla_composition", "xla")):
+        attn = heads_major(functools.partial(banded_attention,
+                                             backend=backend))
+        inside = time_ms(fwd_bwd(model(attn)), block_ops, reps)
+        print(json.dumps({
+            "way": way, "alone_ms": time_ms(fwd_bwd(attn), ops, reps),
+            "in_block_ms": None if inside is None else inside - projections,
+        }), flush=True)
+
+
+def kda_table(reps):
+    """One JSON line a chunk size of the delta rule (module
+    docstring)."""
+    from mxnet_tpu.ops.kda import _kda
+    t, h, d = 4096, 32, 128
+    keys = jax.random.split(jax.random.key(128), 6)
+    unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)
+    q, k, v, do = (jax.random.normal(key, (1, t, h, d), jnp.float32)
+                   for key in keys[:4])
+    log_a = -5.0 * jax.nn.sigmoid(
+        jax.random.normal(keys[4], (1, t, h, d)) - 4.0)
+    beta = jax.nn.sigmoid(jax.random.normal(keys[5], (1, t, h)))
+    bf16 = jnp.bfloat16
+    ops = [unit(q).astype(bf16), unit(k).astype(bf16), v.astype(bf16),
+           log_a, beta, do.astype(bf16)]
+    for chunk in (32, 64, 128):
+        fn = functools.partial(_kda, chunk=chunk)
+        print(json.dumps({
+            "chunk": chunk,
+            "fwd_bwd_ms": time_ms(fwd_bwd(jax.checkpoint(fn)), ops, reps),
+            "fwd_ms": time_ms(fn, ops[:5], reps)}), flush=True)
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--bh", type=int, default=192)
@@ -268,6 +352,8 @@ def main():
     ap.add_argument("--window", type=int, default=0)
     ap.add_argument("--way", default="", help="with --window: only the "
                     "ways whose name holds this")
+    ap.add_argument("--latent", type=int, default=0)
+    ap.add_argument("--kda", type=int, default=0)
     args = ap.parse_args()
     if jax.devices()[0].platform != "tpu":
         sys.exit("attention_table.py measures a chip; none is attached")
@@ -275,6 +361,10 @@ def main():
         return head64(args.reps, args.block)
     if args.window:
         return window_table(args.reps, args.way)
+    if args.latent:
+        return latent_table(args.reps)
+    if args.kda:
+        return kda_table(args.reps)
     causal = bool(args.causal)
     if args.check:
         return errors(args.bh, causal)
