@@ -32,6 +32,7 @@ from .. import random as _random
 from ..base import MXNetError
 from ..context import current_context
 from ..ndarray.ndarray import NDArray, _wrap
+from ..trace import setup as _setup
 from .parameter import Parameter, ParameterDict, DeferredInitializationError
 
 __all__ = ["Block", "HybridBlock", "SymbolBlock", "nn_trace_ctx"]
@@ -282,8 +283,16 @@ class Block:
     def initialize(self, init=None, ctx=None, verbose=False,
                    force_reinit=False):
         from ..initializer import Uniform
-        self.collect_params().initialize(init or Uniform(), ctx, verbose,
-                                         force_reinit)
+        with _setup.eager("block.initialize", block=self.name) as sp:
+            params = self.collect_params()
+            params.initialize(init or Uniform(), ctx, verbose,
+                              force_reinit)
+            if sp.sampled:
+                # a deferred parameter is placed by its first forward
+                placed = [p._data._data for p in params.values()
+                          if p._data is not None]
+                sp.set(parameters=len(params), placed=len(placed),
+                       bytes=sum(a.nbytes for a in placed))
 
     def cast(self, dtype):
         for child in self._children.values():
@@ -292,12 +301,13 @@ class Block:
             param.cast(dtype)
 
     def __call__(self, *args):
-        for hook in self._forward_pre_hooks:
-            hook(self, args)
-        with self._traced_scope():
-            out = self.forward(*args)
-        for hook in self._forward_hooks:
-            hook(self, args, out)
+        with _setup.block_forward(self, args):
+            for hook in self._forward_pre_hooks:
+                hook(self, args)
+            with self._traced_scope():
+                out = self.forward(*args)
+            for hook in self._forward_hooks:
+                hook(self, args, out)
         return out
 
     def forward(self, *args):
@@ -417,7 +427,7 @@ class HybridBlock(Block):
             return self.forward(*args)
         if not self._active:
             return super().__call__(*args)
-        with self._traced_scope():
+        with _setup.block_forward(self, args), self._traced_scope():
             return self._call_cached(*args)
 
     def optimize_for(self, x, *args, backend=None, **kwargs):

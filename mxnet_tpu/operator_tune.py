@@ -229,23 +229,32 @@ def choose(name: str, candidates: Sequence[Tuple[str, Callable]],
         # startup, not per invocation); in-process winners are reused
         return cached
     best_i, best_t = 0, float("inf")
-    for i, (label, fn) in enumerate(candidates):
-        try:
-            t = _time_candidate(fn, raw, kwargs, iters)
-        except Exception as e:
-            # a candidate may not support this config — but a kernel
-            # the compiler refuses must not silently become the other
-            # path: say so, and keep the error where callers can see it
-            err = f"{type(e).__name__}: {e}"
-            _failures[f"{name}[{label}]|{key}"] = err
-            from .base import get_logger
-            get_logger("mxnet_tpu.operator_tune").warning(
-                "autotune: candidate '%s' of %s failed and is skipped: "
-                "%s", label, key, err[:2000])
-            continue
-        _costs[f"{name}[{label}]|{key}"] = t
-        if t < best_t:
-            best_i, best_t = i, t
+    from .telemetry import metrics as _metrics
+    from .trace import setup as _setup
+    t0 = time.perf_counter()
+    with _setup.eager("operator_tune.measure", op=name):
+        for i, (label, fn) in enumerate(candidates):
+            try:
+                t = _time_candidate(fn, raw, kwargs, iters)
+            except Exception as e:
+                # a candidate may not support this config — but a kernel
+                # the compiler refuses must not silently become the
+                # other path: say so, and keep the error where callers
+                # can see it
+                err = f"{type(e).__name__}: {e}"
+                _failures[f"{name}[{label}]|{key}"] = err
+                from .base import get_logger
+                get_logger("mxnet_tpu.operator_tune").warning(
+                    "autotune: candidate '%s' of %s failed and is "
+                    "skipped: %s", label, key, err[:2000])
+                continue
+            _costs[f"{name}[{label}]|{key}"] = t
+            if t < best_t:
+                best_i, best_t = i, t
+    _metrics.histogram(
+        "operator_tune_measure_seconds",
+        "wall time of operator_tune's measurements of candidates "
+        "(compiles included)").observe(time.perf_counter() - t0)
     if best_t < float("inf"):
         # only cache a MEASURED winner — if every candidate failed
         # (transient device error), fall back to the default this time

@@ -20,7 +20,9 @@ cache key, so it is never built from a temp name, a pid or the time.
 Hits and misses are logged through the telemetry metrics registry via
 jax's monitoring events, so ``tools/mxprof.py step`` and the
 MXNET_METRICS_EXPORT stream show whether warmup actually came from
-disk.
+disk. The same listener hands jax's program-building events (trace,
+lowering, compile or cache read) to :mod:`mxnet_tpu.trace.setup`; it is
+registered at import whether or not a cache is placed.
 """
 from __future__ import annotations
 
@@ -50,6 +52,22 @@ def _on_event(event: str, **kwargs):
     _metrics.counter(*hit).inc()
 
 
+def listen():
+    """Register the listeners on jax's monitoring events: the cache's
+    hit and miss counters, and the program-building events of
+    :mod:`mxnet_tpu.trace.setup`. Idempotent."""
+    global _LISTENER_ON
+    if _LISTENER_ON:
+        return
+    import jax
+    from ..trace import setup as _setup
+    jax.monitoring.register_event_listener(_on_event)
+    jax.monitoring.register_scalar_listener(_setup.on_start)
+    jax.monitoring.register_event_duration_secs_listener(_setup.on_duration)
+    jax.monitoring.register_event_time_span_listener(_setup.on_time_span)
+    _LISTENER_ON = True
+
+
 def enable_compile_cache(directory: str,
                          min_compile_time_secs: float = 0.5) -> bool:
     """Point jax's persistent compilation cache at ``directory`` and
@@ -57,7 +75,7 @@ def enable_compile_cache(directory: str,
     ``JAX_COMPILATION_CACHE_DIR`` in the environment wins: the cache
     then stays where jax already put it and only the listener is
     registered. Returns True when a cache is active. Idempotent."""
-    global _LISTENER_ON
+    listen()
     placed_outside = bool(os.environ.get("JAX_COMPILATION_CACHE_DIR"))
     if not directory and not placed_outside:
         return False
@@ -69,15 +87,13 @@ def enable_compile_cache(directory: str,
         # cache even tiny programs: CPU test models compile in <0.5 s
         # but the restart win is the same
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    if not _LISTENER_ON:
-        jax.monitoring.register_event_listener(_on_event)
-        _LISTENER_ON = True
     return True
 
 
 def maybe_enable_compile_cache() -> bool:
     """Import-time hook (mxnet_tpu/__init__.py calls this once the flag
     registry is up): apply MXNET_COMPILE_CACHE_DIR, or just listen when
-    the cache was placed by JAX_COMPILATION_CACHE_DIR."""
+    the cache was placed by JAX_COMPILATION_CACHE_DIR; the listeners
+    are registered either way."""
     from ..base import get_env
     return enable_compile_cache(get_env("MXNET_COMPILE_CACHE_DIR", ""))

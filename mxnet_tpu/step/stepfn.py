@@ -255,7 +255,8 @@ class StepFunction:
                 p.data()
         except DeferredInitializationError:
             from .. import autograd as _ag
-            with _ag.pause():
+            from ..trace import setup as _setup
+            with _ag.pause(), _setup.eager("step.resolve", fn=self._name):
                 self._net(_wrap(_raw(sample_x)[:1]))
             plist = sorted(
                 self._net._collect_params_with_prefix().items())
@@ -585,8 +586,9 @@ class StepFunction:
         guard = self._guard_enabled()
 
         # the per-step trace root (serving's serve.request analog):
-        # compile/prep/dispatch/writeback decompose as children, keyed
-        # by step number so mxprof trace correlates across subsystems.
+        # prep/dispatch/writeback decompose as children, keyed by step
+        # number so mxprof trace correlates across subsystems; a miss
+        # adds the program's trace/lower/compile under the dispatch.
         # The tree counts its thread's CPU time (cpu_ns), so that wall
         # less CPU tells a host that waits inside the runtime from one
         # that computes
@@ -607,17 +609,14 @@ class StepFunction:
                    self._optimizer.fused_signature()) \
                 + self._shard_key()
             fn = self._cache.get(key)
-            if fn is None:
+            missed = fn is None
+            if missed:
                 self._record_miss(inputs)
-                tb0 = time.perf_counter()
-                with _trace.span("step.compile", "train"):
-                    fn = self._make_jit(self._build_pure(guard), guard)
+                # lazy: jax traces, lowers and compiles it (or reads it
+                # from the persistent cache) in the first dispatch
+                fn = self._make_jit(self._build_pure(guard), guard)
                 self._cache[key] = fn
                 self._last = (fn, key)
-                _metrics.histogram(
-                    "fused_step_compile_seconds",
-                    "fused-step trace+compile latency").observe(
-                    time.perf_counter() - tb0)
             else:
                 _metrics.counter(
                     "fused_step_cache_hits_total",
@@ -638,7 +637,14 @@ class StepFunction:
             t1 = time.perf_counter()
             with _trace.span("step.dispatch", "train",
                              batch=batch_size):
-                out = fn(pvals, svals, hyper, inputs, rng)
+                if missed:
+                    # the builds inside are the step program's:
+                    # step.trace / step.lower / step.compile
+                    from ..trace import setup as _setup
+                    with _setup.step_program():
+                        out = fn(pvals, svals, hyper, inputs, rng)
+                else:
+                    out = fn(pvals, svals, hyper, inputs, rng)
             new_params, new_states, loss = out[:3]
             t2 = time.perf_counter()
             with _trace.span("step.writeback", "train"):
@@ -837,7 +843,9 @@ class StepFunction:
         the CURRENT buffers (a persistent-cache hit when the step
         already ran); does not execute or donate. ``as_text()`` is the
         post-optimization HLO, ``memory_analysis()`` and
-        ``cost_analysis()`` XLA's own accounting."""
+        ``cost_analysis()`` XLA's own accounting. Runs under the span
+        ``step.aot``; what it builds feeds no set-up instrument."""
+        from ..trace import setup as _setup
         if self._last is None:
             raise MXNetError("no compiled step yet — call step() first")
         fn, _ = self._last
@@ -846,9 +854,10 @@ class StepFunction:
         inputs = tuple(jax.ShapeDtypeStruct(v.shape, v.dtype)
                        for v in map(_raw, (x,) + labels))
         hyper = onp.zeros((2, len(self._indices)), onp.float32)
-        pvals, svals = self._gather()
-        rng = jax.random.key_data(jax.random.key(0))
-        return fn.lower(pvals, svals, hyper, inputs, rng).compile()
+        with _setup.aot():
+            pvals, svals = self._gather()
+            rng = jax.random.key_data(jax.random.key(0))
+            return fn.lower(pvals, svals, hyper, inputs, rng).compile()
 
     def cost_analysis(self, x, *labels):
         """XLA cost analysis of the compiled step (bench roofline,

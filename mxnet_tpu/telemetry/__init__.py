@@ -59,10 +59,6 @@ def record_step(batch_size: int, seconds: float, prefix: str = "trainer"):
                     "samples consumed by steps").inc(batch_size)
     metrics.histogram(f"{prefix}_step_seconds",
                       "wall-clock step latency").observe(seconds)
-    if seconds > 0:
-        metrics.gauge(f"{prefix}_throughput_samples_per_sec",
-                      "instantaneous step throughput"
-                      ).set(batch_size / seconds)
     memory.maybe_sample()
     from ..base import get_env
     sink = get_env("MXNET_METRICS_EXPORT", "")

@@ -140,16 +140,18 @@ class SpanContext:
     a child from another thread. ``sampled=False`` contexts still
     propagate (children inherit the drop decision), and so does
     ``cpu`` (children of a span that counts its thread's CPU time
-    count theirs)."""
+    count theirs). ``subsystem`` is the span's own, for a retroactive
+    child that belongs in the same flight-recorder ring."""
 
-    __slots__ = ("trace_id", "span_id", "sampled", "cpu")
+    __slots__ = ("trace_id", "span_id", "sampled", "cpu", "subsystem")
 
     def __init__(self, trace_id: str, span_id: str, sampled: bool,
-                 cpu: bool = False):
+                 cpu: bool = False, subsystem: str = "app"):
         self.trace_id = trace_id
         self.span_id = span_id
         self.sampled = sampled
         self.cpu = cpu
+        self.subsystem = subsystem
 
     def __repr__(self):
         return (f"SpanContext({self.trace_id}, {self.span_id}, "
@@ -191,7 +193,7 @@ class Span:
 
     def context(self) -> SpanContext:
         return SpanContext(self.trace_id, self.span_id, self.sampled,
-                           self.cpu)
+                           self.cpu, self.subsystem)
 
     @property
     def duration_s(self) -> Optional[float]:
@@ -442,15 +444,18 @@ def span(name: str, subsystem: str = "app", cpu: bool = False,
 def emit(name: str, subsystem: str, t0_ns: int, t1_ns: int,
          parent: Optional[SpanContext] = None,
          attrs: Optional[dict] = None,
-         status: str = "ok") -> Optional[Span]:
+         status: str = "ok",
+         span_id: Optional[str] = None) -> Optional[Span]:
     """Record a RETROACTIVE span over an already-measured interval
     under an explicit parent — the cross-thread form (the scheduler's
     queue/decode phases, measured by stamps on the sequence and
     emitted when the phase closes). No parent = no span (internal
-    phases never start their own traces)."""
+    phases never start their own traces). ``span_id``: one taken
+    beforehand, so that the span's children can be emitted before it,
+    as a live span's are recorded before it closes."""
     if parent is None or not parent.sampled or not enabled():
         return None
-    sp = Span(name, subsystem, parent.trace_id, _new_span_id(),
+    sp = Span(name, subsystem, parent.trace_id, span_id or _new_span_id(),
               parent.span_id, t0_ns=t0_ns, sampled=True)
     sp.t1_ns = t1_ns
     sp.status = status

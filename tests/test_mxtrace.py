@@ -729,6 +729,279 @@ def test_step_hlo_carries_scope_names_without_the_profiler(
 
 
 # ---------------------------------------------------------------------------
+# set-up from inside the program: the step program's trace, lowering and
+# compile where they happen, the eager set-up's spans, and the registry
+# instruments they feed (mxnet_tpu/trace/setup.py)
+# ---------------------------------------------------------------------------
+
+BUILD_SPANS = ("step.trace", "step.lower", "step.compile")
+STEP_HISTOGRAMS = ("step_program_trace_seconds",
+                   "step_program_lower_seconds",
+                   "step_program_compile_seconds")
+SETUP_INSTRUMENTS = STEP_HISTOGRAMS + (
+    "step_program_cache_retrieval_seconds", "setup_eager_seconds",
+    "eager_program_build_seconds", "eager_programs_built_total",
+    "operator_tune_measure_seconds")
+# what a step that hits its signature cache writes to the registry
+HIT_WRITES = ("fused_step_cache_hits_total", "fused_step_host_seconds",
+              "fused_step_dispatch_seconds",
+              "fused_step_writeback_seconds", "trainer_step_total",
+              "trainer_samples_total", "trainer_step_seconds")
+
+
+def _setup_counts():
+    """name -> observations (a counter: its value) of the set-up
+    instruments, read without creating them."""
+    found = _metrics.all_metrics()
+    out = {}
+    for name in SETUP_INSTRUMENTS:
+        m = found.get(name)
+        out[name] = 0 if m is None else (
+            m.count if isinstance(m, _metrics.Histogram) else m.value())
+    return out
+
+
+def _ancestors(span, by_id):
+    names = []
+    while span["parent_id"] in by_id:
+        span = by_id[span["parent_id"]]
+        names.append(span["name"])
+    return names
+
+
+def test_first_step_records_the_programs_build_under_train_step():
+    fused, x, y = _dense_step()
+    trace.drain()
+    before = _setup_counts()
+    fused.step(x, y)
+    spans = trace.drain()
+    after = _setup_counts()
+    by_id = {s["span_id"]: s for s in spans}
+    for name in BUILD_SPANS:
+        (sp,) = [s for s in spans if s["name"] == name]
+        assert sp["dur_us"] > 0
+        # built inside the first dispatch, under the step's root
+        assert _ancestors(sp, by_id) == ["step.dispatch", "train.step"]
+        dispatch = by_id[sp["parent_id"]]
+        assert dispatch["ts_us"] <= sp["ts_us"]
+        assert sp["ts_us"] + sp["dur_us"] <= \
+            dispatch["ts_us"] + dispatch["dur_us"]
+        assert "pure_step" in sp["attrs"]["fun_name"]
+    (compile_,) = [s for s in spans if s["name"] == "step.compile"]
+    assert compile_["attrs"]["source"] in ("compiler", "cache")
+    # one observation each: the outermost events alone
+    for name in STEP_HISTOGRAMS:
+        assert after[name] == before[name] + 1, name
+    # the functions jax traced inside the step's trace are its children
+    (step_trace,) = [s for s in spans if s["name"] == "step.trace"]
+    assert any(s["parent_id"] == step_trace["span_id"]
+               and s["name"] == "jax.trace" for s in spans)
+    # the second step builds nothing and feeds nothing
+    fused.step(x, y)
+    names = {s["name"] for s in trace.drain()}
+    assert names == set(STEP_TREE)
+    assert _setup_counts() == after
+
+
+def test_compiled_lands_under_step_aot_and_feeds_no_step_histogram():
+    fused, x, y = _dense_step()
+    fused.step(x, y)
+    trace.drain()
+    before = _setup_counts()
+    # another batch size: jax traces, lowers and compiles it anew (the
+    # signature that ran is in jax's own caches and builds nothing)
+    fused.compiled(x[:2], y[:2])
+    spans = trace.drain()
+    assert _setup_counts() == before
+    by_id = {s["span_id"]: s for s in spans}
+    (aot,) = [s for s in spans if s["name"] == "step.aot"]
+    assert aot["parent_id"] is None
+    built = [s for s in spans if s["name"].startswith("jax.")]
+    assert any(s["name"] == "jax.compile" for s in built)
+    assert all("step.aot" in _ancestors(s, by_id) for s in built)
+    assert not set(BUILD_SPANS) & {s["name"] for s in spans}
+
+
+def test_a_plain_jit_outside_the_program_feeds_no_setup_instrument():
+    import jax
+    import jax.numpy as jnp
+    before = _setup_counts()
+    with trace.span("caller", "app"):
+        jax.jit(lambda a: a * 3.0 + 1.0)(
+            jnp.ones((7, 3))).block_until_ready()
+    jax.jit(lambda a: a - 1.0)(jnp.ones((3, 7))).block_until_ready()
+    assert _setup_counts() == before
+    spans = trace.drain()
+    by_id = {s["span_id"]: s for s in spans}
+    built = [s for s in spans if s["name"].startswith("jax.")]
+    # with an ambient span the builds are its children (the functions
+    # traced inside theirs), in its subsystem; with none they record
+    # nothing
+    assert {s["name"] for s in built
+            if by_id[s["parent_id"]]["name"] == "caller"} == {
+        "jax.trace", "jax.lower", "jax.compile"}
+    assert all(_ancestors(s, by_id)[-1] == "caller"
+               and s["subsystem"] == "app" for s in built)
+
+
+def test_an_inner_jit_is_the_outer_traces_child_and_counted_once():
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.trace import setup
+
+    inner = jax.jit(lambda a: a * 2.0)
+
+    def outer_fn(a):
+        return inner(a) + 1.0
+
+    x = jnp.ones((5, 3))
+    hist = _metrics.histogram("step_program_trace_seconds")
+    n0, s0 = hist.count, hist.sum
+    with trace.span("root", "app"), setup.step_program():
+        jax.jit(outer_fn)(x).block_until_ready()
+    spans = trace.drain()
+    (outer,) = [s for s in spans if s["name"] == "step.trace"]
+    assert outer["attrs"]["fun_name"] == "outer_fn"
+    (nested,) = [s for s in spans if s["name"] == "jax.trace"
+                 and s["attrs"]["fun_name"] == "<lambda>"]
+    assert nested["parent_id"] == outer["span_id"]
+    assert nested["dur_us"] <= outer["dur_us"]
+    # the histogram took the outer trace once, the inner not at all
+    assert hist.count == n0 + 1
+    assert hist.sum - s0 == pytest.approx(outer["dur_us"] / 1e6,
+                                          abs=1e-6)
+
+
+def test_block_forward_is_recorded_for_the_outermost_eager_call_only():
+    import jax
+    from mxnet_tpu.gluon.block import functional_call
+    net = gluon.nn.HybridSequential()
+    net.add(gluon.nn.Dense(6, in_units=9, activation="relu"),
+            gluon.nn.Dense(2, in_units=6))
+    net.initialize()
+    x = nd.array(onp.ones((3, 9), "float32"))
+    trace.drain()
+    net(x)
+    fwd = [s for s in trace.drain() if s["name"] == "block.forward"]
+    assert len(fwd) == 1
+    assert fwd[0]["attrs"]["block"] == net.name
+    assert fwd[0]["parent_id"] is None
+    # under a trace the blocks' calls see tracers: no span
+    pvals = {n: p.data()._data
+             for n, p in net._collect_params_with_prefix().items()}
+    jax.jit(lambda p, a: functional_call(net, p, [a])[0][0])(
+        pvals, x._data).block_until_ready()
+    assert not [s for s in trace.drain() if s["name"] == "block.forward"]
+    # hybridized, the outermost call is one span again
+    net.hybridize()
+    net(x)
+    fwd = [s for s in trace.drain() if s["name"] == "block.forward"]
+    assert len(fwd) == 1 and fwd[0]["parent_id"] is None
+
+
+def test_eager_setup_feeds_its_wall_time_and_the_programs_it_builds():
+    before = _setup_counts()
+    net = gluon.nn.Dense(5, in_units=11)
+    net.initialize()
+    net(nd.array(onp.ones((3, 11), "float32"))).asnumpy()
+    after = _setup_counts()
+    spans = {s["name"]: s for s in trace.drain()
+             if s["parent_id"] is None}
+    assert spans["block.initialize"]["attrs"]["parameters"] == 2
+    assert spans["block.initialize"]["attrs"]["bytes"] == 4 * (5 * 11 + 5)
+    # block.initialize and block.forward, each outermost
+    assert after["setup_eager_seconds"] == \
+        before["setup_eager_seconds"] + 2
+    assert after["eager_programs_built_total"] > \
+        before["eager_programs_built_total"]
+    assert after["eager_program_build_seconds"] > \
+        before["eager_program_build_seconds"]
+    for name in STEP_HISTOGRAMS:
+        assert after[name] == before[name]
+
+
+def test_step_resolve_wraps_the_shape_resolving_forward():
+    mx.random.seed(0)
+    net = gluon.nn.Dense(4)  # in_units deferred to the first batch
+    net.initialize()
+    trainer = gluon.Trainer(net.collect_params(), "sgd",
+                            {"learning_rate": 0.05})
+    fused = trainer.fuse_step(net, gluon.loss.L2Loss())
+    x = nd.array(onp.ones((4, 13), "float32"))
+    y = nd.array(onp.zeros((4, 4), "float32"))
+    trace.drain()
+    before = _setup_counts()
+    fused.step(x, y)
+    spans = trace.drain()
+    by_id = {s["span_id"]: s for s in spans}
+    (resolve,) = [s for s in spans if s["name"] == "step.resolve"]
+    assert resolve["parent_id"] is None
+    (fwd,) = [s for s in spans if s["name"] == "block.forward"]
+    assert _ancestors(fwd, by_id) == ["step.resolve"]
+    # the nested span is not counted again
+    assert _setup_counts()["setup_eager_seconds"] == \
+        before["setup_eager_seconds"] + 1
+
+
+def test_operator_tune_measure_is_an_eager_setup_span(tmp_path,
+                                                     monkeypatch):
+    from mxnet_tpu import operator_tune
+    monkeypatch.setenv("MXNET_HOME", str(tmp_path))
+    operator_tune.clear_cache()
+    operator_tune.set_tuning_mode("auto")
+    before = _setup_counts()
+    label, _ = operator_tune.choose(
+        "setup_toy", [("a", lambda v: v + 1), ("b", lambda v: v + 2)],
+        onp.ones((4,), "float32"), iters=1)
+    assert label in ("a", "b")
+    after = _setup_counts()
+    (measure,) = [s for s in trace.drain()
+                  if s["name"] == "operator_tune.measure"]
+    assert measure["attrs"]["op"] == "setup_toy"
+    for name in ("setup_eager_seconds", "operator_tune_measure_seconds"):
+        assert after[name] == before[name] + 1
+    operator_tune.clear_cache()
+
+
+def test_with_tracing_off_the_histograms_are_fed_and_no_span_recorded():
+    config.set_flag("MXTRACE", False)
+    before = _setup_counts()
+    fused, x, y = _dense_step()
+    fused.step(x, y)
+    after = _setup_counts()
+    for name in STEP_HISTOGRAMS:
+        assert after[name] == before[name] + 1
+    assert after["setup_eager_seconds"] == \
+        before["setup_eager_seconds"] + 1  # block.initialize
+    assert trace.drain() == []
+
+
+def test_a_signature_cache_hit_writes_what_the_parent_wrote_less_the_gauge(
+        monkeypatch):
+    fused, x, y = _dense_step()
+    fused.step(x, y)
+    trace.drain()
+    written = []
+
+    def recording(cls, method):
+        original = getattr(cls, method)
+
+        def record(self, *args, **kwargs):
+            written.append(self.name)
+            return original(self, *args, **kwargs)
+        monkeypatch.setattr(cls, method, record)
+
+    for cls, method in ((_metrics.Counter, "inc"),
+                        (_metrics.Histogram, "observe"),
+                        (_metrics.Gauge, "set"), (_metrics.Gauge, "max")):
+        recording(cls, method)
+    fused.step(x, y)
+    monkeypatch.undo()
+    assert sorted(written) == sorted(HIT_WRITES)
+    assert sorted(s["name"] for s in trace.drain()) == sorted(STEP_TREE)
+
+
+# ---------------------------------------------------------------------------
 # recompile auditor kinds (satellite: fused_step / serving2 /
 # plan-fingerprint keys each classify a forced miss with its shapes)
 # ---------------------------------------------------------------------------

@@ -859,6 +859,14 @@ def test_mxprof_step_report(tmp_path):
     assert r2.returncode == 0, r2.stderr[-800:]
     assert "fused step (mxstep)" in r2.stdout
     assert "2 hit(s), 1 miss(es)" in r2.stdout
+    # the one miss built the program: its trace, lowering and compile
+    # one observation each, each longer than nothing
+    for name in ("step_program_trace_seconds",
+                 "step_program_lower_seconds",
+                 "step_program_compile_seconds"):
+        (row,) = [ln for ln in r2.stdout.splitlines()
+                  if ln.split()[:1] == [name]]
+        assert "n=1 " in row and "avg=    0.000 ms" not in row, row
     r3 = subprocess.run(
         [sys.executable, os.path.join(ROOT, "tools", "mxprof.py"),
          "step", sink, "--json"], env=env, capture_output=True,

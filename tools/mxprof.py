@@ -211,7 +211,9 @@ def step_report(metrics):
         lines.append(f"  signature cache: {hits} hit(s), {misses} "
                      f"miss(es) ({100.0 * hits / total:.1f}% hit rate)")
         lines.append("  time per phase:")
-        for name in ("fused_step_compile_seconds",
+        for name in ("step_program_trace_seconds",
+                     "step_program_lower_seconds",
+                     "step_program_compile_seconds",
                      "fused_step_host_seconds",
                      "fused_step_dispatch_seconds",
                      "fused_step_writeback_seconds",
