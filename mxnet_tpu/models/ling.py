@@ -16,10 +16,11 @@ guess. Per layer ``x + mixer(norm(x))`` then ``x + ffn(norm(x))``
   W_v))`` (``conv``: one causal ``short_conv_kernel_size``-tap filter a
   channel); ``beta = sigmoid(x W_b)`` a head; a decay a channel,
   ``log a = kda_lower_bound * sigmoid(exp(A_log)[h] * (x W_f +
-  dt_bias))``; the gated delta rule over a (head_dim x head_dim) state a
-  head (``ops.kda``: on a TPU a Pallas kernel pair, else a chunked
-  scan); an RMSNorm over each head's
-  channels (one weight of ``head_dim``) times ``sigmoid(x W_g)[h]``;
+  dt_bias))`` (the filters, norms and gate: ``ops.kda_operands``, on a
+  TPU a Pallas kernel pair); the gated delta rule over a (head_dim x
+  head_dim) state a head (``ops.kda``: on a TPU a Pallas kernel pair,
+  else a chunked scan); an RMSNorm over each head's channels (one
+  weight of ``head_dim``) times ``sigmoid(x W_g)[h]``;
   ``W_o``.
 - ``LingLatentAttention`` (layers ``i`` with ``(i + 1) %
   layer_group_size == 0``): ``q = x W_q`` in heads of ``qk_nope_head_dim
@@ -48,8 +49,10 @@ the gated norm of the output; ``layers/<i>/attn/full`` around the
 latent layer's two products and the softmax; under ``layers/<i>/moe``:
 ``route``, ``dispatch``, ``experts``, ``combine``, ``shared``. Every
 traced mixer bumps ``kda_traced_total.kernel`` or ``.chunked`` (the
-backend ``ops.kda.default_backend`` picks by shape), every traced latent
-block ``attention_traced_total.kernel`` or ``.dense``.
+backend ``ops.kda.default_backend`` picks by shape) and
+``kda_mix_traced_total.kernel`` or ``.xla`` (``ops.kda_operands``'),
+every traced latent block ``attention_traced_total.kernel`` or
+``.dense``.
 """
 from __future__ import annotations
 
@@ -66,47 +69,16 @@ from ..ops.banded_attention import banded_attention, default_backend
 from ..ops.kda import count_traced as count_kda
 from ..ops.kda import default_backend as kda_backend
 from ..ops.kda import kda
+from ..ops.kda_operands import count_traced as count_mix
+from ..ops.kda_operands import default_backend as mix_backend
+from ..ops.kda_operands import kda_operands
 from ..ops.pallas_kernels import count_traced
-from ..ops.short_conv import _filtered
 from ..parallel.moe import GatedFFN, RoutedExpertsFFN
 from .laguna import (DecoderLayer, LMHead, RMSNorm, _dense, _gate_heads,
                      _rotate)
 
 __all__ = ["LingKDA", "LingLatentAttention", "LingDecoderLayer",
            "LingHybridLM"]
-
-L2_EPS = 1e-6            # under the root of q's and k's l2 norm
-
-
-def _heads_of(y, heads):
-    b, t, c = y.shape
-    return y.reshape(b, t, heads, c // heads)
-
-
-def _kda_operands(q, k, v, f, beta, q_filter, k_filter, v_filter, a_log,
-                  dt_bias, heads, lower_bound):
-    """The delta rule's five operands from the mixer's projections
-    (B, T, H * D) and ``beta``'s logits (B, T, H): float32 inside, q, k
-    and v in the projections' dtype out, the decay's logarithm and beta
-    in float32."""
-    f32 = jnp.float32
-
-    def mixed(x, w):
-        return _heads_of(jax.nn.silu(_filtered(x.astype(f32),
-                                               w.astype(f32))), heads)
-
-    def unit(x):
-        return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), axis=-1,
-                                         keepdims=True) + L2_EPS)
-
-    rate = jnp.exp(a_log.astype(f32))[:, None]
-    log_a = lower_bound * jax.nn.sigmoid(
-        rate * _heads_of(f.astype(f32) + dt_bias.astype(f32), heads))
-    return (unit(mixed(q, q_filter)).astype(q.dtype),
-            unit(mixed(k, k_filter)).astype(k.dtype),
-            mixed(v, v_filter).astype(v.dtype), log_a,
-            jax.nn.sigmoid(beta.astype(f32)))
-
 
 def _gated_head_norm(o, gate, weight, eps):
     """Each head's output (B, T, H, D) through an RMSNorm over its
@@ -121,14 +93,15 @@ def _gated_head_norm(o, gate, weight, eps):
 
 
 @functools.partial(jax.jit, static_argnames=("heads", "lower_bound", "eps",
-                                             "backend"))
+                                             "backend", "mix"))
 def _kda_mixer(x, w_q, w_k, w_v, w_f, w_b, w_g, w_o, q_filter, k_filter,
                v_filter, a_log, dt_bias, norm_weight, *, heads, lower_bound,
-               eps, backend):
+               eps, backend, mix):
     """The whole mixer on its input ``x`` (B, T, C) and its leaves, ONE
     rematerialised unit: the backward pass keeps ``x`` alone and
     rebuilds the seven projections, the delta rule's operands (the
     float32 decay among them) and the operator's chunks from it, once.
+    ``mix`` is ``ops.kda_operands``' backend, ``backend`` ``ops.kda``'s.
     Kept instead, a layer's projections, operands and outputs are 0.4 GB
     at 4096 tokens, and six layers of them do not fit beside the step's
     state (PERF.md section 6, PR 35)."""
@@ -139,13 +112,15 @@ def _kda_mixer(x, w_q, w_k, w_v, w_f, w_b, w_g, w_o, q_filter, k_filter,
         def project(w):
             return jnp.matmul(x, w.T)
 
-        projected = [project(w) for w in (w_q, w_k, w_v, w_f, w_b)]
+        b, t, _ = x.shape
+        *projected, beta = [project(w) for w in (w_q, w_k, w_v, w_f, w_b)]
         with jax.named_scope("mix"):
-            operands = _kda_operands(
+            operands = [a.reshape(b, t, heads, -1) for a in kda_operands(
                 *projected, q_filter, k_filter, v_filter, a_log, dt_bias,
-                heads, lower_bound)
+                heads=heads, lower_bound=lower_bound, backend=mix)]
+            beta = jax.nn.sigmoid(beta.astype(jnp.float32))
         with jax.named_scope("scan"):
-            o = kda(*operands, backend=backend)
+            o = kda(*operands, beta, backend=backend)
         o = _gated_head_norm(o, project(w_g), norm_weight, eps)
         return jnp.matmul(o.reshape(x.shape[:2] + (-1,)), w_o.T)
 
@@ -191,9 +166,12 @@ class LingKDA(HybridBlock):
     def hybrid_forward(self, F, x, q_filter, k_filter, v_filter, A_log,
                        dt_bias, o_norm_weight, **projections):
         backend = kda_backend(x.shape[1], self._head_dim, self._head_dim)
+        mix = mix_backend(x.shape[1], self._head_dim)
         count_kda(backend)
+        count_mix(mix)
         return invoke(
-            functools.partial(_kda_mixer, backend=backend, **self._static),
+            functools.partial(_kda_mixer, backend=backend, mix=mix,
+                              **self._static),
             [x] + [projections[name + "_weight"]
                    for name in self.PROJECTIONS]
             + [q_filter, k_filter, v_filter, A_log, dt_bias, o_norm_weight])

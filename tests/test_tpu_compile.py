@@ -13,6 +13,7 @@ arguments or in conftest.py, and only the xdist worker that is handed
 this file loads it.
 """
 import functools
+import math
 import re
 
 import jax
@@ -251,6 +252,62 @@ def test_the_delta_rule_compiles_as_a_kernel_pair(one_chip):
     assert text.count("tpu_custom_call") >= 2
     assert "4096,32,128,128" not in text and "4096,1,32,128,128" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 4e9
+
+
+def test_the_delta_rule_mixer_hands_its_operands_straight_on(one_chip):
+    """The Ling cell's whole mixer (4096 tokens of 2560, 32 heads of
+    128, bfloat16) under the scope ``kda``, result and gradients, both
+    kernel pairs: the operands' pair ``kda_mix_fwd`` / ``kda_mix_bwd``
+    under ``kda`` > ``mix`` in its metadata (what
+    ``kda_mix_ms_per_step`` reads), and every ``kda_fwd`` reading q, k,
+    v and the decay as ``kda_mix_fwd`` wrote them: no copy or transpose
+    of a (4096, 4096) array between the two, and none under ``mix``."""
+    from mxnet_tpu.models import ling
+    t, heads, d, units = 4096, 32, 128, 2560
+    width = heads * d
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    mixer = functools.partial(ling._kda_mixer, heads=heads, lower_bound=-5.0,
+                              eps=1e-6, backend="kernel", mix="kernel")
+
+    def both(*args):
+        with jax.named_scope("kda"):
+            out, vjp = jax.vjp(mixer, *args[:-1])
+            return (out,) + vjp(args[-1])
+
+    args = _shapes(one_chip, ((1, t, units), bf16),
+                   *[((width, units), bf16)] * 4,
+                   *[((heads, units), bf16)] * 2, ((units, width), bf16),
+                   *[((width, 4), bf16)] * 3, ((heads,), f32),
+                   ((width,), f32), ((d,), f32), ((1, t, units), bf16))
+    entry = jax.jit(both).lower(*args).compile().as_text()
+    entry = entry[entry.index("ENTRY"):]
+    lines = {m.group(1): m.group(0) for m in re.finditer(
+        r"%([\w.\-]+) = [^\n]*", entry)}
+
+    def op_name(line):
+        return re.search(r'op_name="([^"]*)"', line).group(1).split("/")
+
+    def under(path, *names):
+        segments = iter(path)
+        return all(any(s == name for s in segments) for name in names)
+
+    calls = {kernel: [name for name in lines if name.startswith(kernel + ".")
+                      and "custom-call(" in lines[name]]
+             for kernel in ("kda_mix_fwd", "kda_mix_bwd", "kda_fwd")}
+    # the primal forward and its rematerialisation; one backward
+    assert len(calls["kda_mix_fwd"]) == 2 and len(calls["kda_mix_bwd"]) == 1
+    for name in calls["kda_mix_fwd"] + calls["kda_mix_bwd"]:
+        assert under(op_name(lines[name]), "kda", "mix"), lines[name][:300]
+    for name in calls["kda_fwd"]:
+        operands = re.search(r"custom-call\(([^)]*)\)", lines[name]).group(1)
+        for operand in re.findall(r"%([\w.\-]+)", operands)[:4]:
+            assert re.search(r"get-tuple-element\(%kda_mix_fwd\.",
+                             lines[operand]), lines[operand][:300]
+    for name, line in lines.items():
+        m = re.match(r"%\S+ = \w+\[([\d,]+)\]\S* (copy|transpose)\(", line)
+        if m and math.prod(int(n) for n in m.group(1).split(",")) \
+                >= t * width and "op_name" in line:
+            assert not under(op_name(line), "mix"), line[:300]
 
 
 def test_short_conv_compiles_without_a_convolution(one_chip):

@@ -7,6 +7,7 @@ backward: the measurement behind ``flash_attention_available``'s rule.
     python tools/attention_table.py --window 1 [--way band]
     python tools/attention_table.py --latent 1
     python tools/attention_table.py --kda 1
+    python tools/attention_table.py --kda-mix 1
 
 One JSON line a case: ``{"t", "d", "dtype", "causal", "kernel_ms",
 "dense_ms"}`` (a side that does not fit the device reads null), the
@@ -46,6 +47,16 @@ rematerialises it, forward plus backward and forward alone, one JSON
 line a way and chunk size (``{"way", "chunk", "fwd_bwd_ms", "fwd_ms"}``):
 the XLA chunked scan at 32, 64, 128 tokens a chunk, the Pallas kernel
 pair at 32 and 64.
+``--kda-mix 1`` is the table behind ``ops/kda_operands.py MIX_ROWS`` and
+``MIX_HEADS``: the operands of the Ling cell's delta rule (4096 tokens,
+32 heads of 128, the bfloat16 projections q, k, v, f, filters of 4
+taps), forward alone and forward plus backward under ``jax.checkpoint``,
+one JSON line a way (``{"way", "rows", "heads", "fwd_ms", "fwd_bwd_ms",
+"gap"}``): the XLA composition on heads (B, T, H, D), an XLA
+``custom_vjp`` on (B, T, H * D) written as ``ops/short_conv.py`` writes
+its own, and the Pallas pair at several blocks of tokens and heads;
+``gap`` is the largest distance of a way's results and gradients from
+the composition's, over each one's largest entry.
 Times are the device's own, from a profile of the calls; a CPU run
 refuses to start (its times would say nothing about the chip).
 """
@@ -346,6 +357,105 @@ def kda_table(reps):
                 "fwd_ms": time_ms(fn, ops[:5], reps)}), flush=True)
 
 
+def fused_operands(heads, lower_bound):
+    """The operands as ONE ``custom_vjp`` in XLA on (B, T, H * D): the
+    filters as shifted adds, a head's sums through a reshape inside the
+    passes; the backward pass keeps the projections alone."""
+    from mxnet_tpu.ops.kda_operands import L2_EPS
+    from mxnet_tpu.ops.short_conv import _filtered, _shifted
+    f32 = jnp.float32
+
+    def head_sum(a):
+        b, t, c = a.shape
+        total = jnp.sum(a.reshape(b, t, heads, -1), axis=-1, keepdims=True)
+        return jnp.broadcast_to(total, (b, t, heads, c // heads)).reshape(
+            a.shape)
+
+    @jax.custom_vjp
+    def op(q, k, v, f, q_filter, k_filter, v_filter, rate, bias):
+        out = []
+        for n, (x, w) in enumerate(((q, q_filter), (k, k_filter),
+                                    (v, v_filter))):
+            s = jax.nn.silu(_filtered(x.astype(f32), w.astype(f32)))
+            if n < 2:
+                s = s * jax.lax.rsqrt(head_sum(jnp.square(s)) + L2_EPS)
+            out.append(s.astype(x.dtype))
+        return (*out, lower_bound * jax.nn.sigmoid(
+            rate * (f.astype(f32) + bias)))
+
+    def bwd(res, cotangents):
+        q, k, v, f, q_filter, k_filter, v_filter, rate, bias = res
+        grads, filters = [], []
+        for n, (x, w, g) in enumerate(((q, q_filter, cotangents[0]),
+                                       (k, k_filter, cotangents[1]),
+                                       (v, v_filter, cotangents[2]))):
+            taps = w.shape[1]
+            x32, w32, g = x.astype(f32), w.astype(f32), g.astype(f32)
+            y = _filtered(x32, w32)
+            sg = jax.nn.sigmoid(y)
+            if n < 2:
+                s = y * sg
+                r = jax.lax.rsqrt(head_sum(jnp.square(s)) + L2_EPS)
+                g = r * g - s * (r * r * r) * head_sum(g * s)
+            dy = g * (sg * (1.0 + y * (1.0 - sg)))
+            grads.append(sum(w32[:, j] * _shifted(dy, j - (taps - 1))
+                             for j in range(taps)).astype(x.dtype))
+            filters.append(jnp.stack([
+                jnp.sum(dy * _shifted(x32, taps - 1 - j), axis=(0, 1))
+                for j in range(taps)], axis=1).astype(w.dtype))
+        z = f.astype(f32) + bias
+        sg = jax.nn.sigmoid(rate * z)
+        du = cotangents[3] * (lower_bound * sg * (1.0 - sg))
+        return (*grads, (du * rate).astype(f.dtype), *filters,
+                jnp.sum(du * z, axis=(0, 1)), jnp.sum(du * rate, axis=(0, 1)))
+
+    op.defvjp(lambda *a: (op(*a), a), bwd)
+
+    def run(q, k, v, f, q_filter, k_filter, v_filter, a_log, dt_bias):
+        d = q.shape[-1] // heads
+        return op(q, k, v, f, q_filter, k_filter, v_filter,
+                  jnp.repeat(jnp.exp(a_log), d), dt_bias)
+    return run
+
+
+def kda_mix_table(reps):
+    """One JSON line a way to the delta rule's operands (module
+    docstring)."""
+    from mxnet_tpu.ops import kda_operands as M
+    t, h, d, taps = 4096, 32, 128, 4
+    c = h * d
+    keys = jax.random.split(jax.random.key(4), 13)
+    bf16 = jnp.bfloat16
+    normal = jax.random.normal
+    ops = [normal(key, (1, t, c)).astype(bf16) for key in keys[:4]]
+    ops += [(0.5 * normal(key, (c, taps))).astype(bf16) for key in keys[4:7]]
+    ops += [0.5 * normal(keys[7], (h,)), -4.0 + 0.5 * normal(keys[8], (c,))]
+    cotangents = tuple([normal(key, (1, t, c)).astype(bf16)
+                        for key in keys[9:12]] + [normal(keys[12], (1, t, c))])
+    static = dict(heads=h, lower_bound=-5.0)
+    ways = [("composition", None, None, functools.partial(
+        M.kda_operands, backend="xla", **static)),
+        ("fused_custom_vjp", None, None, fused_operands(h, -5.0))]
+    for rows, heads in ((256, 4), (512, 4), (256, 8), (512, 2), (1024, 2),
+                        (128, 8), (1024, 4)):
+        ways.append(("kernel", rows, heads, functools.partial(
+            M._kernel_path, heads=h, lower_bound=-5.0, interpret=False,
+            rows=rows, block_heads=heads)))
+    both = jax.jit(fwd_bwd(ways[0][3]))
+    want = [a.astype(jnp.float32) for a in jax.tree.leaves(
+        both(*ops, cotangents))]
+    for way, rows, heads, fn in ways:
+        got = jax.tree.leaves(jax.jit(fwd_bwd(fn))(*ops, cotangents))
+        gap = max(float(jnp.max(jnp.abs(a.astype(jnp.float32) - b))
+                        / jnp.max(jnp.abs(b))) for a, b in zip(got, want))
+        print(json.dumps({
+            "way": way, "rows": rows, "heads": heads,
+            "fwd_ms": time_ms(fn, ops, reps),
+            "fwd_bwd_ms": time_ms(fwd_bwd(jax.checkpoint(fn)),
+                                  ops + [cotangents], reps),
+            "gap": gap}), flush=True)
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--bh", type=int, default=192)
@@ -359,6 +469,7 @@ def main():
                     "ways whose name holds this")
     ap.add_argument("--latent", type=int, default=0)
     ap.add_argument("--kda", type=int, default=0)
+    ap.add_argument("--kda-mix", type=int, default=0)
     args = ap.parse_args()
     if jax.devices()[0].platform != "tpu":
         sys.exit("attention_table.py measures a chip; none is attached")
@@ -370,6 +481,8 @@ def main():
         return latent_table(args.reps)
     if args.kda:
         return kda_table(args.reps)
+    if args.kda_mix:
+        return kda_mix_table(args.reps)
     causal = bool(args.causal)
     if args.check:
         return errors(args.bh, causal)
