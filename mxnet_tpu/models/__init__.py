@@ -15,3 +15,7 @@ from . import ling  # noqa: F401
 from .ling import (  # noqa: F401
     LingKDA, LingLatentAttention, LingDecoderLayer, LingHybridLM,
 )
+from . import nemotron_h  # noqa: F401
+from .nemotron_h import (  # noqa: F401
+    NemotronHMamba, NemotronHLM,
+)

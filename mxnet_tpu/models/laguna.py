@@ -26,7 +26,7 @@ net from such a dict. Per layer ``x + attn(norm(x))`` then
   k largest renormalised, times the scaling factor, with a shared
   expert); ``"sigmoid"`` with a selection bias is ``models/lfm2.py``'s,
   the same limited to a token's best groups ``models/ling.py``'s.
-- ``DecoderLayer`` is the pre-norm residual skeleton the three decoder
+- ``DecoderLayer`` is the pre-norm residual skeleton the decoder
   families here build their layers from; ``RMSNorm``, ``LMHead`` and
   the rotation are shared with ``models/lfm2.py`` (``rotary_tables``
   too) and ``models/ling.py`` (the per-head gate too).
@@ -140,7 +140,8 @@ def _gated_attention(q, k, v, gate, cos, sin, turn, *, heads, kv_heads,
     """Projected ``q`` (B, T, H * D), ``k`` / ``v`` (B, T, Hkv * D) and
     gate logits (B, T, H) to the gated heads' outputs (B, T, H * D);
     ``backend`` as ``ops.banded_attention`` names them (None: its
-    rule)."""
+    rule). ``gate`` None: the heads ungated; ``cos`` None: no
+    positions."""
     b, t, _ = q.shape
     q = q.reshape(b, t, heads, head_dim)
     k = k.reshape(b, t, kv_heads, head_dim)
@@ -153,12 +154,15 @@ def _gated_attention(q, k, v, gate, cos, sin, turn, *, heads, kv_heads,
                 q, k, v, window=window, backend=backend,
                 rotary=(cos, sin, turn), gate=gate)
         return o.reshape(b, t, heads * head_dim)
-    q, k = _rotate(q, cos, sin, turn), _rotate(k, cos, sin, turn)
+    if cos is not None:
+        q, k = _rotate(q, cos, sin, turn), _rotate(k, cos, sin, turn)
     with jax.named_scope("full" if window is None else "window"):
         o = banded_attention(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
                              v.transpose(0, 2, 1, 3), window=window,
                              backend=backend)
-    o = _gate_heads(o.transpose(0, 2, 1, 3), gate)      # (B, T, H, D)
+    o = o.transpose(0, 2, 1, 3)                         # (B, T, H, D)
+    if gate is not None:
+        o = _gate_heads(o, gate)
     return o.reshape(b, t, heads * head_dim)
 
 
@@ -205,28 +209,32 @@ def _dense(units, in_units, prefix):
 
 class LagunaAttention(HybridBlock):
     """Grouped-query attention with ``num_heads`` query heads over
-    ``num_kv_heads`` key/value heads, rotary positions from ``rope``,
-    a sliding ``window`` (None: full causal attention) and a per-head
-    sigmoid gate on the output."""
+    ``num_kv_heads`` key/value heads, rotary positions from ``rope``
+    (None: no positions), a sliding ``window`` (None: full causal
+    attention) and, where ``gated``, a per-head sigmoid gate on the
+    output."""
 
     def __init__(self, units, num_heads, num_kv_heads, head_dim, rope,
-                 window=None, **kwargs):
+                 window=None, gated=True, **kwargs):
         super().__init__(**kwargs)
         if num_heads % num_kv_heads:
             raise ValueError("num_heads must be a multiple of "
                              "num_kv_heads")
         self._geometry = dict(heads=num_heads, kv_heads=num_kv_heads,
                               head_dim=head_dim, window=window)
-        self._rope, self._head_dim = dict(rope), head_dim
+        self._rope, self._head_dim = rope and dict(rope), head_dim
         self._tables = {}
         with self.name_scope():
             self.q_proj = _dense(num_heads * head_dim, units, "q_proj_")
             self.k_proj = _dense(num_kv_heads * head_dim, units, "k_proj_")
             self.v_proj = _dense(num_kv_heads * head_dim, units, "v_proj_")
-            self.g_proj = _dense(num_heads, units, "g_proj_")
+            self.g_proj = _dense(num_heads, units, "g_proj_") \
+                if gated else None
             self.o_proj = _dense(units, num_heads * head_dim, "o_proj_")
 
     def _rotary(self, t):
+        if self._rope is None:
+            return None, None, None
         if t not in self._tables:
             self._tables[t] = rotary_tables(t, self._head_dim, self._rope)
         return self._tables[t]
@@ -241,8 +249,12 @@ class LagunaAttention(HybridBlock):
                       "xla": "dense"}[backend.split("_")[0]])
         fn = functools.partial(_gated_attention, cos=cos, sin=sin,
                                turn=turn, backend=backend, **geometry)
-        out = invoke(fn, [self.q_proj(x), self.k_proj(x), self.v_proj(x),
-                          self.g_proj(x)])
+        if self.g_proj is None:
+            out = invoke(lambda q, k, v: fn(q, k, v, None),
+                         [self.q_proj(x), self.k_proj(x), self.v_proj(x)])
+        else:
+            out = invoke(fn, [self.q_proj(x), self.k_proj(x),
+                              self.v_proj(x), self.g_proj(x)])
         return self.o_proj(out)
 
 
@@ -264,18 +276,21 @@ class LMHead(HybridBlock):
 
 
 class DecoderLayer(HybridBlock):
-    """The pre-norm residual layer of the three decoder families here:
-    ``x + mixer(norm(x))`` then ``x + ffn(norm(x))``. ``mixer`` and
-    ``ffn`` are ``(attribute name, maker)`` pairs, built under the
-    layer's name scope and hung under those names (which name them in a
-    traced program too); ``norms`` names the two RMSNorms."""
+    """The pre-norm residual layer of the decoder families here:
+    ``x + mixer(norm(x))`` then ``x + ffn(norm(x))``, or ``x +
+    mixer(norm(x))`` alone where ``ffn`` is None (a layer of one block,
+    ``models/nemotron_h.py``). ``mixer`` and ``ffn`` are ``(attribute
+    name, maker)`` pairs, built under the layer's name scope and hung
+    under those names (which name them in a traced program too);
+    ``norms`` names the RMSNorms, one a block."""
 
-    def __init__(self, units, eps, mixer, ffn,
+    def __init__(self, units, eps, mixer, ffn=None,
                  norms=("attn_norm", "mlp_norm"), **kwargs):
         super().__init__(**kwargs)
-        self._halves = tuple(zip(norms, (mixer[0], ffn[0])))
+        blocks = (mixer,) if ffn is None else (mixer, ffn)
+        self._halves = tuple(zip(norms, (name for name, _ in blocks)))
         with self.name_scope():
-            for norm, (name, make) in zip(norms, (mixer, ffn)):
+            for norm, (name, make) in zip(norms, blocks):
                 setattr(self, norm, RMSNorm(units, eps, prefix=norm + "_"))
                 setattr(self, name, make())
 

@@ -76,7 +76,9 @@ bool index_records(Reader* r) {
 // ---------------------------------------------------------------------------
 // Prefetching batch server: worker threads pull shuffled index ranges and
 // pack payloads into contiguous buffers (lengths + offsets sidecar), the
-// analog of iter_batchloader.h + iter_prefetcher.h rolled together.
+// analog of iter_batchloader.h + iter_prefetcher.h rolled together. Batches
+// are handed over in the order of their ranges, whichever worker finishes
+// first.
 // ---------------------------------------------------------------------------
 
 struct Batch {
@@ -94,6 +96,8 @@ struct BatchServer {
 
   std::vector<uint32_t> order;
   size_t cursor = 0;
+  size_t issued = 0;     // ranges handed to workers (under cursor_mu)
+  size_t next_push = 0;  // the range whose batch goes on `ready` next (mu)
 
   std::deque<Batch*> ready;
   std::mutex mu;
@@ -114,11 +118,14 @@ struct BatchServer {
       std::shuffle(order.begin(), order.end(), rng);
     }
     cursor = 0;
+    issued = 0;
+    next_push = 0;
   }
 
-  bool next_indices(std::vector<uint32_t>* idx) {
+  bool next_indices(std::vector<uint32_t>* idx, size_t* seq) {
     std::lock_guard<std::mutex> lk(cursor_mu);
     if (cursor >= order.size()) return false;
+    *seq = issued++;
     size_t end = std::min(cursor + batch_size, order.size());
     idx->assign(order.begin() + cursor, order.begin() + end);
     cursor = end;
@@ -130,8 +137,9 @@ struct BatchServer {
 
   void worker_loop() {
     std::vector<uint32_t> idx;
+    size_t seq = 0;
     while (!stop.load()) {
-      if (!next_indices(&idx)) break;
+      if (!next_indices(&idx, &seq)) break;
       Batch* b = new Batch();
       size_t total = 0;
       for (uint32_t i : idx) total += reader->records[i].length;
@@ -148,8 +156,8 @@ struct BatchServer {
         at += rec.length;
       }
       std::unique_lock<std::mutex> lk(mu);
-      cv_space.wait(lk, [this] {
-        return ready.size() < max_ready || stop.load();
+      cv_space.wait(lk, [this, seq] {
+        return (ready.size() < max_ready && next_push == seq) || stop.load();
       });
       if (stop.load()) {
         delete b;
@@ -157,6 +165,8 @@ struct BatchServer {
         return;
       }
       ready.push_back(b);
+      ++next_push;
+      cv_space.notify_all();  // the worker holding the next range
       cv_ready.notify_one();
     }
     // only the LAST exiting worker marks end-of-epoch — an earlier
@@ -285,7 +295,7 @@ void* rio_batch_next(void* server) {
   if (s->ready.empty()) return nullptr;
   Batch* b = s->ready.front();
   s->ready.pop_front();
-  s->cv_space.notify_one();
+  s->cv_space.notify_all();
   return b;
 }
 

@@ -22,7 +22,9 @@ groups of consecutive ids, the ``topk_group`` groups with the largest
 sum of their two best biased scores stay, and the k are chosen inside
 them: ``models.LingHybridLM``); whatever the rule, it
 sorts the (token, expert) rows by expert, computes the held experts'
-SiLU-gated FFNs as grouped products over the sorted rows, and combines
+FFNs as grouped products over the sorted rows (SiLU-gated, or
+``down(relu(up x)^2)`` with ``activation="relu2"``:
+``models.NemotronHLM``), and combines
 by routing weight. Rows routed to experts held elsewhere are left out
 (their exchange belongs to the deployment, and nothing here stands in
 for it). The buffer of sorted rows holds twice the rows that uniform
@@ -65,8 +67,8 @@ from ..ndarray.ndarray import invoke
 from ..ops.registry import register_op
 
 __all__ = ["MoEFFN", "expert_parallel_shardings", "GatedFFN",
-           "RoutedExpertsFFN", "routed_experts", "route_top_k",
-           "routing_counts"]
+           "SquaredReluFFN", "RoutedExpertsFFN", "routed_experts",
+           "route_top_k", "routing_counts"]
 
 
 @register_op("_moe_ffn", input_names=("x", "gate_w", "w1", "b1", "w2",
@@ -400,6 +402,13 @@ def _silu_gate(gate, up):
             * up.astype(jnp.float32)).astype(gate.dtype)
 
 
+@jax.checkpoint
+def _relu_squared(up):
+    """``relu(up)^2`` in float32, the input's dtype out; rematerialised
+    as ``_silu_gate`` is."""
+    return jnp.square(jax.nn.relu(up.astype(jnp.float32))).astype(up.dtype)
+
+
 def _buffer_slots(order, starts, lo, rows, n, k):
     """The slots ``lo .. lo + rows`` of the sorted order: ``(row,
     slots, sizes)``. Slot *s* holds (token, choice) row ``row[s]``; it
@@ -427,7 +436,9 @@ def _buffer_pass(x, weights, w_gate, w_up, w_down, order, starts, lo, rows):
     group. ``order`` is the permutation that sorts them, ``starts``
     (E_held + 1,) every group's first slot and, last, the rows routed
     here; ``weights`` (N * k,) the routing weights in (token, choice)
-    order, 0 for a choice held elsewhere. Returns (N, C) in float32:
+    order, 0 for a choice held elsewhere; ``w_gate`` None: the experts
+    are ``down(relu(up x)^2)``, two products and no gate. Returns (N, C)
+    in float32:
     every token's weighted sum over its choices that lie in these
     slots. Jitted: the layers of a model, the first pass and the further
     ones, forward and recomputed, share one trace; and under a
@@ -440,9 +451,13 @@ def _buffer_pass(x, weights, w_gate, w_up, w_down, order, starts, lo, rows):
                                           weights.shape[0] // n)
         sorted_rows = _dispatch_rows(x, slots)
     with jax.named_scope("experts"):
-        gate = _grouped_product(sorted_rows, w_gate, sizes)
-        up = _grouped_product(sorted_rows, w_up, sizes)
-        out = _grouped_product(_silu_gate(gate, up), w_down, sizes)
+        if w_gate is None:
+            hidden = _relu_squared(_grouped_product(sorted_rows, w_up, sizes))
+        else:
+            gate = _grouped_product(sorted_rows, w_gate, sizes)
+            up = _grouped_product(sorted_rows, w_up, sizes)
+            hidden = _silu_gate(gate, up)
+        out = _grouped_product(hidden, w_down, sizes)
     with jax.named_scope("combine"):
         # a slot that is not valid holds a row held elsewhere or the
         # padding's row 0 of another group: its weight meets no run
@@ -523,7 +538,8 @@ _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
     "k", "num_held", "held_start", "scale", "groups"))
 def routed_experts(x, router_w, w_gate, w_up, w_down, bias=None, *, k,
                    held_start, num_held, scale, groups=None):
-    """The held experts' part of a top-k routed SiLU-gated FFN.
+    """The held experts' part of a top-k routed SiLU-gated FFN, or of a
+    squared-ReLU one where ``w_gate`` is None.
 
     x: (N, C); router_w: (E_all, C); w_gate / w_up: (E_held, C, F);
     w_down: (E_held, F, C); the layer holds experts ``held_start ..
@@ -604,6 +620,24 @@ class GatedFFN(HybridBlock):
         return self.down_proj(invoke(_silu_gate, [gate, up]))
 
 
+class SquaredReluFFN(HybridBlock):
+    """``down(relu(up(x))^2)``, no bias."""
+
+    def __init__(self, units, hidden_size, **kwargs):
+        super().__init__(**kwargs)
+        from ..gluon import nn
+        with self.name_scope():
+            self.up_proj = nn.Dense(hidden_size, flatten=False,
+                                    use_bias=False, in_units=units,
+                                    prefix="up_proj_")
+            self.down_proj = nn.Dense(units, flatten=False, use_bias=False,
+                                      in_units=hidden_size,
+                                      prefix="down_proj_")
+
+    def hybrid_forward(self, F, x):
+        return self.down_proj(invoke(_relu_squared, [self.up_proj(x)]))
+
+
 class RoutedExpertsFFN(HybridBlock):
     """One chip's share of a top-k routed expert layer (module
     docstring). ``experts_held`` is a contiguous ``range`` of the
@@ -613,7 +647,10 @@ class RoutedExpertsFFN(HybridBlock):
     ``grad_req`` ``"null"``: the selection bias, which no optimizer
     touches; the sigmoid rule takes ``groups = (n_group, topk_group)``
     for its group limit. ``shared_hidden`` builds a shared expert under
-    any rule. In an
+    any rule. ``activation`` is every expert's form, the shared one's
+    too: ``"silu"`` (``down(silu(gate x) * up x)``: ``w_gate``, ``w_up``,
+    ``w_down``, ``GatedFFN``) or ``"relu2"`` (``down(relu(up x)^2)``: no
+    ``w_gate``, ``SquaredReluFFN``). In an
     eager forward (not under a trace) the layer records, under
     ``label``, the telemetry gauges ``moe_rows_routed.<label>``,
     ``moe_load_max_over_mean.<label>``, ``moe_buffer_rows.<label>``
@@ -629,8 +666,12 @@ class RoutedExpertsFFN(HybridBlock):
     def __init__(self, units, hidden_size, num_experts,
                  num_experts_per_tok, experts_held=None,
                  routed_scaling=1.0, shared_hidden=0, label=None,
-                 scoring="softmax", groups=None, **kwargs):
+                 scoring="softmax", groups=None, activation="silu",
+                 **kwargs):
         super().__init__(**kwargs)
+        if activation not in ("silu", "relu2"):
+            raise ValueError("activation must be 'silu' or 'relu2', got "
+                             f"{activation!r}")
         held = range(num_experts) if experts_held is None \
             else experts_held
         held = list(held)
@@ -670,18 +711,20 @@ class RoutedExpertsFFN(HybridBlock):
                 self.expert_bias = self.params.get(
                     "expert_bias", shape=(num_experts,), init="zeros",
                     grad_req="null")
-            self.w_gate = self.params.get(
-                "w_gate", shape=(len(held), units, hidden_size), init=None)
-            self.w_up = self.params.get(
-                "w_up", shape=(len(held), units, hidden_size), init=None)
+            gated = activation == "silu"
+            names = ("w_gate", "w_up") if gated else ("w_up",)
+            for name in names:
+                setattr(self, name, self.params.get(
+                    name, shape=(len(held), units, hidden_size), init=None))
             self.w_down = self.params.get(
                 "w_down", shape=(len(held), hidden_size, units), init=None)
-            self.shared = GatedFFN(units, shared_hidden, prefix="shared_") \
+            shared = GatedFFN if gated else SquaredReluFFN
+            self.shared = shared(units, shared_hidden, prefix="shared_") \
                 if shared_hidden else None
-        for p in (self.w_gate, self.w_up, self.w_down):
-            p._expert_sharded = True
+        for name in names + ("w_down",):
+            getattr(self, name)._expert_sharded = True
 
-    def hybrid_forward(self, F, x, router_weight, w_gate, w_up, w_down,
+    def hybrid_forward(self, F, x, router_weight, w_up, w_down, w_gate=None,
                        expert_bias=None):
         shape = x.shape
         flat = x.reshape((-1, shape[-1]))
@@ -706,9 +749,14 @@ class RoutedExpertsFFN(HybridBlock):
                                           group_limit_changed_share(
                                               *raw, **choice)))
         # the bias goes last: ``routed_experts``' one optional operand
-        out = invoke(functools.partial(
-            routed_experts, scale=self._scale, **geometry),
-            [flat, router_weight, w_gate, w_up, w_down] + router[1:])
+        fn = functools.partial(routed_experts, scale=self._scale, **geometry)
+        if w_gate is None:
+            out = invoke(lambda x, r, up, down, *bias: fn(
+                x, r, None, up, down, *bias),
+                [flat, router_weight, w_up, w_down] + router[1:])
+        else:
+            out = invoke(fn, [flat, router_weight, w_gate, w_up, w_down]
+                         + router[1:])
         out = out.reshape(shape)
         if self.shared is not None:
             out = out + self.shared(x)

@@ -81,6 +81,21 @@ def layer_gauges_cleaned():
             metrics.unregister(name)
 
 
+@pytest.fixture(scope="module", autouse=True)
+def _traced_counters_cleaned():
+    """The traced-call counters a module's nets bump
+    (``attention_traced_total.band``, ``ssd_traced_total.chunked``) go
+    when it ends, as ``layer_gauges_cleaned``'s gauges do: a later module
+    on the same worker that reads every counter of a name finds only its
+    own."""
+    from mxnet_tpu.telemetry import metrics
+    before = set(metrics.all_metrics())
+    yield
+    for name in set(metrics.all_metrics()) - before:
+        if "_traced_total." in name:
+            metrics.unregister(name)
+
+
 @pytest.fixture
 def load_example():
     """``load_example("gan/dcgan.py")`` imports one script of examples/

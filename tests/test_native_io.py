@@ -99,3 +99,17 @@ def test_native_batch_server(rec_file):
         assert p in got
     srv.close()
     srv2.close()
+
+
+@pytest.mark.skipif(not native.available(), reason="no C++ toolchain")
+def test_native_batch_server_hands_batches_over_in_order(rec_file):
+    """Six workers on batches of one record: whichever finishes first,
+    the batches come in the order of their ranges, epoch after epoch."""
+    path, payloads = rec_file
+    for _ in range(20):
+        srv = native.NativeBatchServer(path, batch_size=1, shuffle=False,
+                                       num_workers=6)
+        assert [p for b in srv for p in b] == payloads
+        srv.reset()
+        assert [p for b in srv for p in b] == payloads
+        srv.close()

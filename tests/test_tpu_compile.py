@@ -310,6 +310,38 @@ def test_the_delta_rule_mixer_hands_its_operands_straight_on(one_chip):
             assert not under(op_name(line), "mix"), line[:300]
 
 
+def test_the_mamba_mixer_compiles_at_the_cells_widths(one_chip):
+    """The Nemotron cell's Mamba-2 mixer (8192 tokens of 2688, 64 heads
+    of 64, B and C in 8 groups of a 128-wide state, bfloat16) under the
+    scope ``mamba``, result and gradients: the SSD's chunk products lie
+    under ``mamba`` > ``scan`` in the metadata (what
+    ``ssd_scan_ms_per_step`` reads), and the mixer, one rematerialised
+    unit, needs under 3 GB of temporaries."""
+    from mxnet_tpu.models import nemotron_h
+    t, units, heads, groups, state = 8192, 2688, 64, 8, 128
+    inner, width = heads * 64, heads * 64 + 2 * groups * state
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    mixer = functools.partial(nemotron_h._mamba_mixer, heads=heads,
+                              groups=groups, state=state, eps=1e-5)
+
+    def both(*args):
+        with jax.named_scope("mamba"):
+            out, vjp = jax.vjp(mixer, *args[:-1])
+            return (out,) + vjp(args[-1])
+
+    args = _shapes(one_chip, ((1, t, units), bf16),
+                   ((inner + width + heads, units), bf16),
+                   ((width, 4), bf16), ((width,), bf16),
+                   *[((heads,), f32)] * 3, ((inner,), f32),
+                   ((units, inner), bf16), ((1, t, units), bf16))
+    compiled = jax.jit(both).lower(*args).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 3e9
+    paths = re.findall(r'op_name="([^"]*)"', compiled.as_text())
+    assert any("/mamba/" in p and "/scan/" in p and "jit(_ssd)" in p
+               and p.endswith("dot_general") for p in paths)
+    assert any("/mamba/" in p and "/conv/" in p for p in paths)
+
+
 def test_short_conv_compiles_without_a_convolution(one_chip):
     """The LFM2 cell's gated short convolution (8192 tokens, 2048
     channels, bfloat16): fusions of shifted adds, no convolution

@@ -8,6 +8,7 @@ backward: the measurement behind ``flash_attention_available``'s rule.
     python tools/attention_table.py --latent 1
     python tools/attention_table.py --kda 1
     python tools/attention_table.py --kda-mix 1
+    python tools/attention_table.py --ssd 1
 
 One JSON line a case: ``{"t", "d", "dtype", "causal", "kernel_ms",
 "dense_ms"}`` (a side that does not fit the device reads null), the
@@ -57,6 +58,14 @@ one JSON line a way (``{"way", "rows", "heads", "fwd_ms", "fwd_bwd_ms",
 its own, and the Pallas pair at several blocks of tokens and heads;
 ``gap`` is the largest distance of a way's results and gradients from
 the composition's, over each one's largest entry.
+``--ssd 1`` is the table behind ``ops/ssd.py SSD_CHUNK``: the state-space
+scan of the Nemotron-H cell's Mamba-2 mixer (8192 tokens, 64 heads of
+64, B and C in 8 groups of a 128-wide state, bfloat16 x, B, C, float32
+step sizes and rates) under ``jax.checkpoint``, as the mixer that holds
+the call rematerialises it, forward plus backward and forward alone, one
+JSON line a chunk size (``{"chunk", "fwd_bwd_ms", "fwd_ms", "gap"}``);
+``gap`` is the largest distance of the chunk's result from that at 128
+tokens a chunk, over its largest entry.
 Times are the device's own, from a profile of the calls; a CPU run
 refuses to start (its times would say nothing about the chip).
 """
@@ -456,6 +465,34 @@ def kda_mix_table(reps):
             "gap": gap}), flush=True)
 
 
+def ssd_table(reps):
+    """One JSON line a chunk size of the state-space scan (module
+    docstring)."""
+    from mxnet_tpu.ops.ssd import ssd
+    t, h, p, g, n = 8192, 64, 64, 8, 128
+    keys = jax.random.split(jax.random.key(2), 6)
+    bf16 = jnp.bfloat16
+    x, dy = (jax.random.normal(key, (1, t, h, p)).astype(bf16)
+             for key in keys[:2])
+    b, c = (jax.random.normal(key, (1, t, g, n)).astype(bf16)
+            for key in keys[2:4])
+    dt = jax.nn.softplus(jax.random.normal(keys[4], (1, t, h)) - 4.0)
+    a = -jnp.exp(jax.random.uniform(keys[5], (h,), maxval=jnp.log(16.0)))
+    ops = [x, dt, a, b, c]
+    want = jax.jit(functools.partial(ssd, chunk=128))(*ops).astype(
+        jnp.float32)
+    for chunk in (64, 128, 256):
+        fn = functools.partial(ssd, chunk=chunk)
+        got = jax.jit(fn)(*ops).astype(jnp.float32)
+        print(json.dumps({
+            "chunk": chunk,
+            "fwd_bwd_ms": time_ms(fwd_bwd(jax.checkpoint(fn)), ops + [dy],
+                                  reps),
+            "fwd_ms": time_ms(fn, ops, reps),
+            "gap": float(jnp.max(jnp.abs(got - want))
+                         / jnp.max(jnp.abs(want)))}), flush=True)
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--bh", type=int, default=192)
@@ -470,6 +507,7 @@ def main():
     ap.add_argument("--latent", type=int, default=0)
     ap.add_argument("--kda", type=int, default=0)
     ap.add_argument("--kda-mix", type=int, default=0)
+    ap.add_argument("--ssd", type=int, default=0)
     args = ap.parse_args()
     if jax.devices()[0].platform != "tpu":
         sys.exit("attention_table.py measures a chip; none is attached")
@@ -483,6 +521,8 @@ def main():
         return kda_table(args.reps)
     if args.kda_mix:
         return kda_mix_table(args.reps)
+    if args.ssd:
+        return ssd_table(args.reps)
     causal = bool(args.causal)
     if args.check:
         return errors(args.bh, causal)
