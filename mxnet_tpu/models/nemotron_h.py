@@ -35,8 +35,11 @@ mixer, ``.../mamba/conv`` around the filter, its bias and its SiLU,
 ``.../mamba/norm`` around the gated norm; ``layers/<i>/attn/full``
 around the attention products and the softmax; under
 ``layers/<i>/moe``: ``route``, ``dispatch``, ``experts``, ``combine``,
-``shared``. Every traced mixer bumps ``ssd_traced_total.chunked``, every
-traced attention block ``attention_traced_total.kernel`` or ``.dense``.
+``shared``. Every traced mixer asks ``ops.ssd.default_backend`` and
+bumps ``ssd_traced_total.kernel`` (on a TPU at heads of whole lane
+widths: the Pallas pair ``ssd_fwd`` / ``ssd_bwd``) or ``.chunked`` (the
+XLA scan), every traced attention block
+``attention_traced_total.kernel`` or ``.dense``.
 """
 from __future__ import annotations
 
@@ -49,7 +52,9 @@ from ..gluon import nn
 from ..gluon.block import HybridBlock
 from ..ndarray.ndarray import invoke
 from ..ops.short_conv import _filtered
-from ..ops.ssd import count_traced as count_ssd, ssd
+from ..ops.ssd import count_traced as count_ssd
+from ..ops.ssd import default_backend as ssd_backend
+from ..ops.ssd import ssd_packed
 from ..parallel.moe import RoutedExpertsFFN
 from .laguna import DecoderLayer, LagunaAttention, LMHead, RMSNorm
 
@@ -69,20 +74,22 @@ def _gated_group_norm(y, z, weight, groups, eps):
 
 
 @functools.partial(jax.jit, static_argnames=("heads", "groups", "state",
-                                             "eps"))
+                                             "eps", "backend"))
 def _mamba_mixer(x, in_proj, conv_weight, conv_bias, dt_bias, a_log, d,
-                 norm_weight, out_proj, *, heads, groups, state, eps):
+                 norm_weight, out_proj, *, heads, groups, state, eps,
+                 backend):
     """The whole mixer on its input ``x`` (B, T, C) and its leaves, ONE
     rematerialised unit: the backward pass keeps ``x`` alone and
-    rebuilds the projections, the filter and the SSD's chunks from it,
-    once. Kept instead, a layer's projections and the scan's chunk
-    products are about 1.5 GB at 8192 tokens."""
+    rebuilds the projections, the filter and the SSD from it, once.
+    Kept instead, a layer's projections and the XLA scan's chunk
+    products are about 1.5 GB at 8192 tokens. ``backend``: the SSD's
+    (``ops.ssd.ssd_packed``), which reads x, B and C where the filter
+    leaves them."""
 
     @jax.checkpoint
     def unit(x, in_proj, conv_weight, conv_bias, dt_bias, a_log, d,
              norm_weight, out_proj):
         f32 = jnp.float32
-        b, t, _ = x.shape
         inner = norm_weight.shape[0]
         width = conv_weight.shape[0]
         # the projection's three parts by the rows of its weight, which
@@ -94,13 +101,13 @@ def _mamba_mixer(x, in_proj, conv_weight, conv_bias, dt_bias, a_log, d,
             xbc = jax.nn.silu(_filtered(xbc.astype(f32),
                                         conv_weight.astype(f32))
                               + conv_bias.astype(f32)).astype(x.dtype)
-        xs = xbc[..., :inner].reshape(b, t, heads, -1)
-        bc = xbc[..., inner:].reshape(b, t, 2, groups, state)
         with jax.named_scope("scan"):
-            y = ssd(xs, jax.nn.softplus(dt.astype(f32) + dt_bias.astype(f32)),
-                    -jnp.exp(a_log.astype(f32)), bc[:, :, 0], bc[:, :, 1])
-            y = (y.astype(f32) + d.astype(f32)[:, None] * xs.astype(f32)
-                 ).astype(x.dtype).reshape(b, t, inner)
+            y = ssd_packed(
+                xbc, jax.nn.softplus(dt.astype(f32) + dt_bias.astype(f32)),
+                -jnp.exp(a_log.astype(f32)), groups, state, backend)
+            skip = jnp.repeat(d.astype(f32), inner // heads)
+            y = (y.astype(f32) + skip * xbc[..., :inner].astype(f32)
+                 ).astype(x.dtype)
         with jax.named_scope("norm"):
             y = _gated_group_norm(y, z, norm_weight, groups, eps)
         return jnp.matmul(y, out_proj.T)
@@ -125,6 +132,7 @@ class NemotronHMamba(HybridBlock):
         width = inner + 2 * groups * state_size
         self._static = dict(heads=num_heads, groups=groups, state=state_size,
                             eps=eps)
+        self._head_dim = head_dim
         shapes = {"in_proj_weight": ((inner + width + num_heads, units), None),
                   "conv_weight": ((width, kernel), None),
                   "conv_bias": ((width,), "zeros"),
@@ -139,9 +147,12 @@ class NemotronHMamba(HybridBlock):
                                                     init=init))
 
     def hybrid_forward(self, F, x, **leaves):
-        count_ssd("chunked")
+        static = self._static
+        backend = ssd_backend(static["heads"], self._head_dim,
+                              static["groups"], static["state"])
+        count_ssd(backend)
         return invoke(
-            functools.partial(_mamba_mixer, **self._static),
+            functools.partial(_mamba_mixer, backend=backend, **static),
             [x] + [leaves[name] for name in (
                 "in_proj_weight", "conv_weight", "conv_bias", "dt_bias",
                 "A_log", "D", "norm_weight", "out_proj_weight")])

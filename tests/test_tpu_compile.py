@@ -310,19 +310,18 @@ def test_the_delta_rule_mixer_hands_its_operands_straight_on(one_chip):
             assert not under(op_name(line), "mix"), line[:300]
 
 
-def test_the_mamba_mixer_compiles_at_the_cells_widths(one_chip):
+def _mamba_mixer_program(one_chip, backend):
     """The Nemotron cell's Mamba-2 mixer (8192 tokens of 2688, 64 heads
     of 64, B and C in 8 groups of a 128-wide state, bfloat16) under the
-    scope ``mamba``, result and gradients: the SSD's chunk products lie
-    under ``mamba`` > ``scan`` in the metadata (what
-    ``ssd_scan_ms_per_step`` reads), and the mixer, one rematerialised
-    unit, needs under 3 GB of temporaries."""
+    scope ``mamba``, result and gradients, compiled with the SSD's
+    ``backend``."""
     from mxnet_tpu.models import nemotron_h
     t, units, heads, groups, state = 8192, 2688, 64, 8, 128
     inner, width = heads * 64, heads * 64 + 2 * groups * state
     bf16, f32 = jnp.bfloat16, jnp.float32
     mixer = functools.partial(nemotron_h._mamba_mixer, heads=heads,
-                              groups=groups, state=state, eps=1e-5)
+                              groups=groups, state=state, eps=1e-5,
+                              backend=backend)
 
     def both(*args):
         with jax.named_scope("mamba"):
@@ -334,12 +333,47 @@ def test_the_mamba_mixer_compiles_at_the_cells_widths(one_chip):
                    ((width, 4), bf16), ((width,), bf16),
                    *[((heads,), f32)] * 3, ((inner,), f32),
                    ((units, inner), bf16), ((1, t, units), bf16))
-    compiled = jax.jit(both).lower(*args).compile()
+    return jax.jit(both).lower(*args).compile()
+
+
+def test_the_mamba_mixer_compiles_at_the_cells_widths(one_chip):
+    """The mixer on the XLA scan: the SSD's chunk products lie under
+    ``mamba`` > ``scan`` in the metadata (what ``ssd_scan_ms_per_step``
+    reads), and the mixer, one rematerialised unit, needs under 3 GB of
+    temporaries."""
+    compiled = _mamba_mixer_program(one_chip, "chunked")
     assert compiled.memory_analysis().temp_size_in_bytes < 3e9
     paths = re.findall(r'op_name="([^"]*)"', compiled.as_text())
-    assert any("/mamba/" in p and "/scan/" in p and "jit(_ssd)" in p
+    assert any("/mamba/" in p and "/scan/" in p and "jit(ssd_packed)" in p
                and p.endswith("dot_general") for p in paths)
     assert any("/mamba/" in p and "/conv/" in p for p in paths)
+
+
+def test_the_mamba_mixer_takes_the_ssd_kernel_pair(one_chip):
+    """The mixer on the kernel pair: the primal ``ssd_fwd``, the one that
+    keeps the states under the backward pass's rematerialisation and
+    ``ssd_bwd``, all three under ``mamba`` > ``scan`` in the metadata;
+    each reads x, B and C as the filter wrote them, one array (no slice
+    copied out of it); no array of a chunk's decays (L x L a head and
+    chunk) anywhere; and the mixer needs under 1.6 GB of temporaries
+    (2.07 GB on the XLA scan)."""
+    compiled = _mamba_mixer_program(one_chip, "kernel")
+    entry = compiled.as_text()
+    entry = entry[entry.index("ENTRY"):]
+    lines = {m.group(1): m.group(0) for m in re.finditer(
+        r"%([\w.\-]+) = [^\n]*", entry)}
+    calls = {kernel: [name for name in lines if name.startswith(kernel + ".")
+                      and "custom-call(" in lines[name]]
+             for kernel in ("ssd_fwd", "ssd_bwd")}
+    assert len(calls["ssd_fwd"]) == 2 and len(calls["ssd_bwd"]) == 1
+    for name in calls["ssd_fwd"] + calls["ssd_bwd"]:
+        path = re.search(r'op_name="([^"]*)"', lines[name]).group(1)
+        assert "/mamba/" in path and "/scan/" in path, path
+        operands = re.findall(r"%([\w.\-]+)", re.search(
+            r"custom-call\(([^)]*)\)", lines[name]).group(1))
+        assert operands[0] == operands[1] == operands[2], lines[name][:300]
+    assert not re.search(r"f32\[[\d,]*128,128\]", entry)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.6e9
 
 
 def test_short_conv_compiles_without_a_convolution(one_chip):

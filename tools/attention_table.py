@@ -58,14 +58,18 @@ one JSON line a way (``{"way", "rows", "heads", "fwd_ms", "fwd_bwd_ms",
 its own, and the Pallas pair at several blocks of tokens and heads;
 ``gap`` is the largest distance of a way's results and gradients from
 the composition's, over each one's largest entry.
-``--ssd 1`` is the table behind ``ops/ssd.py SSD_CHUNK``: the state-space
-scan of the Nemotron-H cell's Mamba-2 mixer (8192 tokens, 64 heads of
-64, B and C in 8 groups of a 128-wide state, bfloat16 x, B, C, float32
-step sizes and rates) under ``jax.checkpoint``, as the mixer that holds
-the call rematerialises it, forward plus backward and forward alone, one
-JSON line a chunk size (``{"chunk", "fwd_bwd_ms", "fwd_ms", "gap"}``);
-``gap`` is the largest distance of the chunk's result from that at 128
-tokens a chunk, over its largest entry.
+``--ssd 1`` is the table behind ``ops/ssd.py SSD_CHUNK`` and
+``SSD_KERNEL_CHUNK``: the state-space scan of the Nemotron-H cell's
+Mamba-2 mixer (8192 tokens, 64 heads of 64, B and C in 8 groups of a
+128-wide state, x, B and C side by side in bfloat16 as the filter leaves
+them, float32 step sizes and rates) under ``jax.checkpoint``, as the
+mixer that holds the call rematerialises it, forward plus backward and
+forward alone, one JSON line a way and chunk size (``{"way", "chunk",
+"fwd_bwd_ms", "fwd_ms", "gap", "grad_gap"}``): the XLA chunked scan at
+64, 128 and 256 tokens a chunk, the Pallas kernel pair at 128 and 256;
+``gap`` and ``grad_gap`` are the largest distance of the way's result
+and of each of its gradients from the XLA scan's at 128 tokens a chunk,
+over that one's largest entry.
 Times are the device's own, from a profile of the calls; a CPU run
 refuses to start (its times would say nothing about the chip).
 """
@@ -466,31 +470,44 @@ def kda_mix_table(reps):
 
 
 def ssd_table(reps):
-    """One JSON line a chunk size of the state-space scan (module
+    """One JSON line a way and chunk size of the state-space scan (module
     docstring)."""
-    from mxnet_tpu.ops.ssd import ssd
+    from mxnet_tpu.ops.ssd import ssd_packed
     t, h, p, g, n = 8192, 64, 64, 8, 128
-    keys = jax.random.split(jax.random.key(2), 6)
+    keys = jax.random.split(jax.random.key(2), 5)
     bf16 = jnp.bfloat16
-    x, dy = (jax.random.normal(key, (1, t, h, p)).astype(bf16)
-             for key in keys[:2])
-    b, c = (jax.random.normal(key, (1, t, g, n)).astype(bf16)
-            for key in keys[2:4])
-    dt = jax.nn.softplus(jax.random.normal(keys[4], (1, t, h)) - 4.0)
-    a = -jnp.exp(jax.random.uniform(keys[5], (h,), maxval=jnp.log(16.0)))
-    ops = [x, dt, a, b, c]
-    want = jax.jit(functools.partial(ssd, chunk=128))(*ops).astype(
-        jnp.float32)
-    for chunk in (64, 128, 256):
-        fn = functools.partial(ssd, chunk=chunk)
-        got = jax.jit(fn)(*ops).astype(jnp.float32)
-        print(json.dumps({
-            "chunk": chunk,
-            "fwd_bwd_ms": time_ms(fwd_bwd(jax.checkpoint(fn)), ops + [dy],
-                                  reps),
-            "fwd_ms": time_ms(fn, ops, reps),
-            "gap": float(jnp.max(jnp.abs(got - want))
-                         / jnp.max(jnp.abs(want)))}), flush=True)
+    xbc = jax.random.normal(keys[0], (1, t, h * p + 2 * g * n)).astype(bf16)
+    dy = jax.random.normal(keys[1], (1, t, h * p)).astype(bf16)
+    dt = jax.nn.softplus(jax.random.normal(keys[2], (1, t, h)) - 4.0)
+    a = -jnp.exp(jax.random.uniform(keys[3], (h,), maxval=jnp.log(16.0)))
+    ops = [xbc, dt, a]
+
+    def way(backend, chunk):
+        return functools.partial(ssd_packed, groups=g, state=n,
+                                 backend=backend, chunk=chunk)
+
+    def results(fn):
+        out = jax.jit(fwd_bwd(fn))(*ops, dy)
+        return [r.astype(jnp.float32) for r in out]
+
+    def gap(got, want):
+        return float(jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want)))
+
+    want = results(way("chunked", 128))
+    for backend, chunks in (("chunked", (64, 128, 256)),
+                            ("kernel", (128, 256))):
+        for chunk in chunks:
+            fn = way(backend, chunk)
+            got = results(fn)
+            print(json.dumps({
+                "way": backend, "chunk": chunk,
+                "fwd_bwd_ms": time_ms(fwd_bwd(jax.checkpoint(fn)), ops + [dy],
+                                      reps),
+                "fwd_ms": time_ms(fn, ops, reps),
+                "gap": gap(got[0], want[0]),
+                "grad_gap": max(gap(r, w) for r, w in zip(got[1:],
+                                                          want[1:]))}),
+                  flush=True)
 
 
 def main():
