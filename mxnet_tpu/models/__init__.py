@@ -19,3 +19,7 @@ from . import nemotron_h  # noqa: F401
 from .nemotron_h import (  # noqa: F401
     NemotronHMamba, NemotronHLM,
 )
+from . import keye  # noqa: F401
+from .keye import (  # noqa: F401
+    KeyeIndexer, KeyeAttention, KeyeLM,
+)

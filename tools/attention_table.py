@@ -9,6 +9,7 @@ backward: the measurement behind ``flash_attention_available``'s rule.
     python tools/attention_table.py --kda 1
     python tools/attention_table.py --kda-mix 1
     python tools/attention_table.py --ssd 1
+    python tools/attention_table.py --sparse 1
 
 One JSON line a case: ``{"t", "d", "dtype", "causal", "kernel_ms",
 "dense_ms"}`` (a side that does not fit the device reads null), the
@@ -70,6 +71,15 @@ forward alone, one JSON line a way and chunk size (``{"way", "chunk",
 ``gap`` and ``grad_gap`` are the largest distance of the way's result
 and of each of its gradients from the XLA scan's at 128 tokens a chunk,
 over that one's largest entry.
+``--sparse 1`` is the table behind ``ops/sparse_attention.py
+QUERY_BLOCK``: the Keye cell's attention layer over the keys its indexer
+selects (16384 tokens, 32 query heads over 4 key/value heads of 128, 16
+indexer heads of 64 over one key head, the top 2048 keys a query,
+bfloat16 operands, float32 head weights), forward plus backward and
+forward alone, one JSON line a block of queries (``{"way", "block",
+"fwd_bwd_ms", "fwd_ms"}``), then one line for a block of 128 queries
+over all 16384 keys: its index scores alone and its selection alone
+(``{"way": "parts", "scores_ms", "select_ms"}``).
 Times are the device's own, from a profile of the calls; a CPU run
 refuses to start (its times would say nothing about the chip).
 """
@@ -510,6 +520,35 @@ def ssd_table(reps):
                   flush=True)
 
 
+def sparse_table(reps):
+    """One JSON line a block of queries of the sparse attention, then
+    the parts of one block (module docstring)."""
+    from mxnet_tpu.ops import sparse_attention as sa
+    t, h, g, d, hi, di, topk = 16384, 32, 4, 128, 16, 64, 2048
+    keys = jax.random.split(jax.random.key(3), 7)
+    bf16 = jnp.bfloat16
+    ops = [jax.random.normal(keys[0], (1, t, h, d)).astype(bf16),
+           jax.random.normal(keys[1], (1, t, g, d)).astype(bf16),
+           jax.random.normal(keys[2], (1, t, g, d)).astype(bf16),
+           jax.random.normal(keys[3], (1, t, hi, di)).astype(bf16),
+           jax.random.normal(keys[4], (1, t, di)).astype(bf16),
+           jax.random.normal(keys[5], (1, t, hi)) / hi ** 0.5]
+    do = jax.random.normal(keys[6], (1, t, h, d)).astype(bf16)
+    for block in (64, 128, 256):
+        fn = functools.partial(sa.sparse_attention, topk=topk, block=block)
+        print(json.dumps({
+            "way": "xla", "block": block,
+            "fwd_bwd_ms": time_ms(fwd_bwd(fn), ops + [do], reps),
+            "fwd_ms": time_ms(fn, ops, reps)}), flush=True)
+    qi, ki, w = ops[3][:, :128], ops[4], ops[5][:, :128]
+    scores = sa.index_scores(qi, ki, w)
+    print(json.dumps({
+        "way": "parts", "block": 128,
+        "scores_ms": time_ms(sa.index_scores, [qi, ki, w], reps),
+        "select_ms": time_ms(lambda s: sa.select(s, topk, t - 128),
+                             [scores], reps)}), flush=True)
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--bh", type=int, default=192)
@@ -525,6 +564,7 @@ def main():
     ap.add_argument("--kda", type=int, default=0)
     ap.add_argument("--kda-mix", type=int, default=0)
     ap.add_argument("--ssd", type=int, default=0)
+    ap.add_argument("--sparse", type=int, default=0)
     args = ap.parse_args()
     if jax.devices()[0].platform != "tpu":
         sys.exit("attention_table.py measures a chip; none is attached")
@@ -540,6 +580,8 @@ def main():
         return kda_mix_table(args.reps)
     if args.ssd:
         return ssd_table(args.reps)
+    if args.sparse:
+        return sparse_table(args.reps)
     causal = bool(args.causal)
     if args.check:
         return errors(args.bh, causal)
